@@ -22,6 +22,7 @@ import time
 import numpy as np
 
 from benchmarks.common import emit
+from repro.compile_cache import enable_compile_cache
 from repro.core import codecs
 
 
@@ -110,4 +111,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

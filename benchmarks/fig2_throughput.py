@@ -3,6 +3,7 @@ compute nodes under the emulated DEFER chain (paper's CORE setting)."""
 from __future__ import annotations
 
 from benchmarks.common import emit, graph_and_params
+from repro.compile_cache import enable_compile_cache
 from repro.core.emulator import CodecConfig, emulate
 
 
@@ -31,4 +32,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -16,9 +16,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import emit
+from repro.compile_cache import enable_compile_cache
 from repro.configs.registry import ARCHS
 from repro.kernels import ops as kops
-from repro.launch.mesh import make_mesh_compat
+from repro.launch.mesh import make_auto_mesh
 from repro.launch.serve import build_pipeline_lm
 from repro.models import transformer as T
 from repro.runtime.wire import WireCodec
@@ -32,7 +33,7 @@ def run(archs=("phi3-mini-3.8b", "gemma3-4b", "dbrx-132b", "mamba2-2.7b"),
         cfg = get_smoke(arch)
         full = get_config(arch)
         params = T.init_lm(cfg, jax.random.PRNGKey(0))
-        mesh = make_mesh_compat((1,), ("stage",))
+        mesh = make_auto_mesh((1,), ("stage",))
         B, S, M = 4, 32, 2
         tokens = jax.random.randint(jax.random.PRNGKey(1), (B, S), 0,
                                     cfg.vocab)
@@ -76,4 +77,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -12,6 +12,8 @@ import argparse
 import sys
 import time
 
+from repro.compile_cache import enable_compile_cache
+
 
 def main() -> None:
     ap = argparse.ArgumentParser()
@@ -44,4 +46,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -98,6 +98,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import emit
+from repro.compile_cache import enable_compile_cache
 from repro.core.graph import LayerGraph
 from repro.runtime import ControllerConfig, InferenceEngine, TopologySpec
 from repro.runtime.dispatcher import DispatcherCodecs
@@ -1189,4 +1190,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -3,6 +3,7 @@ nodes, per payload type (architecture / weights / data) x codec config."""
 from __future__ import annotations
 
 from benchmarks.common import emit, graph_and_params
+from repro.compile_cache import enable_compile_cache
 from repro.core.emulator import CodecConfig, emulate, emulate_config_step
 
 
@@ -34,4 +35,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

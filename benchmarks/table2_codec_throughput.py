@@ -3,6 +3,7 @@ configuration (the steady-state pipeline rate including codec overhead)."""
 from __future__ import annotations
 
 from benchmarks.common import emit, graph_and_params
+from repro.compile_cache import enable_compile_cache
 from repro.core.emulator import CodecConfig, emulate
 
 
@@ -28,4 +29,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.registry import ARCHS, get_smoke
-from repro.launch.mesh import make_mesh_compat
+from repro.launch.mesh import make_auto_mesh
 from repro.launch.serve import build_pipeline_decoder
 from repro.models import transformer as T
 
@@ -35,7 +35,7 @@ def main():
 
     cfg = get_smoke(args.arch)
     params = T.init_lm(cfg, jax.random.PRNGKey(0))
-    mesh = make_mesh_compat((args.stages,), ("stage",))
+    mesh = make_auto_mesh((args.stages,), ("stage",))
     M, mb, steps = args.microbatches, args.mb, args.steps
     start = jax.random.randint(jax.random.PRNGKey(1), (M, mb, 1), 0,
                                cfg.vocab)

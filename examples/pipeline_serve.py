@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.registry import ARCHS, get_smoke
-from repro.launch.mesh import make_mesh_compat
+from repro.launch.mesh import make_auto_mesh
 from repro.launch.serve import build_pipeline_lm, wire_bytes_per_relay
 from repro.models import transformer as T
 
@@ -35,7 +35,7 @@ def main():
     S = args.stages
     if jax.device_count() < S:
         raise SystemExit("need XLA_FLAGS=--xla_force_host_platform_device_count>=4")
-    mesh = make_mesh_compat((S,), ("stage",))
+    mesh = make_auto_mesh((S,), ("stage",))
     params = T.init_lm(cfg, jax.random.PRNGKey(0))
     B = args.microbatches * 4
     tokens = jax.random.randint(jax.random.PRNGKey(1), (B, args.seq), 0,

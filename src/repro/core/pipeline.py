@@ -158,8 +158,6 @@ def make_pipeline(mesh: Mesh, cfg: PipelineConfig,
     ([S, M, ...]); the last-stage slice is taken outside shard_map so XLA
     moves only the finished microbatches.
     """
-    from jax.experimental.shard_map import shard_map
-
     pspec_w = P(cfg.axis)
     pspec_x = P(None, *data_axes)
     pspec_y = P(cfg.axis, None, *data_axes)
@@ -171,9 +169,9 @@ def make_pipeline(mesh: Mesh, cfg: PipelineConfig,
             out = pipeline_apply(w, x, extra, unit_fn=unit_fn, cfg=cfg)
             return tmap(lambda a: a[None], out)   # [1, M, ...] local
 
-        sharded = shard_map(per_device, mesh=mesh,
-                            in_specs=(pspec_w, pspec_x, P()),
-                            out_specs=pspec_y, check_rep=False)
+        sharded = jax.shard_map(per_device, mesh=mesh,
+                                in_specs=(pspec_w, pspec_x, P()),
+                                out_specs=pspec_y, check_vma=False)
 
         def fn(stage_params, x_mb, extra):
             return tmap(lambda a: a[-1], sharded(stage_params, x_mb, extra))
@@ -182,9 +180,9 @@ def make_pipeline(mesh: Mesh, cfg: PipelineConfig,
             out = pipeline_apply(w, x, unit_fn=unit_fn, cfg=cfg)
             return tmap(lambda a: a[None], out)   # [1, M, ...] local
 
-        sharded = shard_map(per_device, mesh=mesh,
-                            in_specs=(pspec_w, pspec_x),
-                            out_specs=pspec_y, check_rep=False)
+        sharded = jax.shard_map(per_device, mesh=mesh,
+                                in_specs=(pspec_w, pspec_x),
+                                out_specs=pspec_y, check_vma=False)
 
         def fn(stage_params, x_mb):
             # last stage's outputs

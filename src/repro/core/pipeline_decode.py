@@ -141,8 +141,6 @@ def make_pipeline_decoder(mesh: Mesh, cfg: PipelineConfig, *,
     stage_params leaves [S, u, ...]; caches leaves [S, u, M, ...] — both
     sharded over the stage axis.  ``head`` (embed/norm/unembed) replicated.
     """
-    from jax.experimental.shard_map import shard_map
-
     pspec_w = P(cfg.axis)
 
     def per_device(w, cach, tok, pos, head):
@@ -151,11 +149,11 @@ def make_pipeline_decoder(mesh: Mesh, cfg: PipelineConfig, *,
             embed_fn=embed_fn, head_fn=head_fn, steps=steps, cfg=cfg)
         return toks[None], new_c
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         per_device, mesh=mesh,
         in_specs=(pspec_w, pspec_w, P(), P(), P()),
         out_specs=(P(cfg.axis), pspec_w),
-        check_rep=False)
+        check_vma=False)
 
     def fn(stage_params, caches, start_tok, start_pos, head):
         toks, new_c = sharded(stage_params, caches, start_tok, start_pos,

@@ -44,8 +44,7 @@ def ep_unit_fn(cfg: ModelConfig, expert_axis: str = "expert",
     scale = 1.0 / np.sqrt(cfg.head_dim)
 
     def apply_layer(lp, x):
-        from repro.sharding import axis_size
-        ax = axis_size(expert_axis)
+        ax = jax.lax.axis_size(expert_axis)
         mb, S, d = x.shape
         pos = jnp.broadcast_to(jnp.arange(S)[None], (mb, S))
         # -- attention, heads sharded over the expert axis ---------------
@@ -122,8 +121,6 @@ def build_ep_pipeline(cfg: ModelConfig, mesh: Mesh, num_stages: int,
     must be sliced per EP shard by the in_specs below (sharded arrays in,
     local shards inside).
     """
-    from jax.experimental.shard_map import shard_map
-
     pipe_cfg = PipelineConfig(num_stages=num_stages,
                               num_microbatches=num_microbatches,
                               axis=stage_axis, compress=compress,
@@ -139,9 +136,9 @@ def build_ep_pipeline(cfg: ModelConfig, mesh: Mesh, num_stages: int,
                    P(stage_axis))
         pspec_x = P(None, data_axes)
         pspec_y = P(stage_axis, None, data_axes)
-        sharded = shard_map(per_device, mesh=mesh,
-                            in_specs=(w_specs, pspec_x),
-                            out_specs=pspec_y, check_rep=False)
+        sharded = jax.shard_map(per_device, mesh=mesh,
+                                in_specs=(w_specs, pspec_x),
+                                out_specs=pspec_y, check_vma=False)
 
         def fn(w, x_mb):
             return tmap(lambda a: a[-1], sharded(w, x_mb))

@@ -8,8 +8,8 @@ dequantizes after, cutting ICI bytes 2x (bf16) / 4x (f32) plus a 1/1024
 scale sidecar, with a fixed (rate-determined) error envelope exactly like ZFP.
 
 Tiling: the (8, 128) tile is the native VREG shape (8 sublanes x 128 lanes),
-so absmax-reduction and the scale broadcast stay register-local; blocks of
-``BLOCK_R x BLOCK_C`` tiles are staged through VMEM.
+so absmax-reduction and the scale broadcast stay register-local; full-width
+strips of ``BLOCK_VALUES`` values are staged through VMEM.
 """
 from __future__ import annotations
 
@@ -20,57 +20,61 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from repro.kernels import interpret_mode
+
 TILE_R, TILE_C = 8, 128
 
-# VMEM block: (BLOCK_R*8) x (BLOCK_C*128) values.  64x4 => 512x512 f32 = 1 MB
-# in + 0.25 MB out + scales — comfortably inside ~16 MB VMEM with double
-# buffering; rows-major grid keeps lanes contiguous.
-BLOCK_R = 64
-BLOCK_C = 4
+# VMEM block: a full-width strip of about 512x512 values (1 MB of f32 in,
+# 0.25 MB int8 out), comfortably inside VMEM with double buffering.  The
+# strip spans every column so the scale block [rows/8, C/128] is whole
+# along its lane dim, and its row count is a multiple of 64 (or all of R)
+# so the scale block's rows are a multiple of 8: Mosaic's tiling rule for
+# the last two block dims.
+BLOCK_VALUES = 512 * 512
+
+
+def _block_rows(R: int, C: int) -> int:
+    rows = max(64, BLOCK_VALUES // C // 64 * 64)
+    return R if rows >= R else rows
 
 
 def _quant_kernel(x_ref, q_ref, s_ref):
-    """x block [BR*8, BC*128] -> int8 block + scales [BR, BC]."""
-    br = x_ref.shape[0] // TILE_R
-    bc = x_ref.shape[1] // TILE_C
-    x = x_ref[...].astype(jnp.float32)
-    xt = x.reshape(br, TILE_R, bc, TILE_C)
-    absmax = jnp.abs(xt).max(axis=3).max(axis=1)                # [br, bc]
-    scale = jnp.where(absmax > 0, absmax / 127.0, 1.0)
-    q = xt / scale[:, None, :, None]
-    q = jnp.clip(jnp.round(q), -127.0, 127.0)
-    q_ref[...] = q.reshape(x_ref.shape).astype(jnp.int8)
-    s_ref[...] = scale
+    """x strip [rows, C] -> int8 strip + scales [rows/8, C/128], one
+    (8, 128) tile column at a time."""
+    rows, cols = x_ref.shape
+    for j in range(cols // TILE_C):
+        lanes = pl.ds(j * TILE_C, TILE_C)
+        xt = x_ref[:, lanes].astype(jnp.float32).reshape(
+            rows // TILE_R, TILE_R, TILE_C)
+        absmax = jnp.abs(xt).max(axis=2).max(axis=1, keepdims=True)
+        scale = jnp.where(absmax > 0, absmax / 127.0, 1.0)     # [rows/8, 1]
+        q = jnp.clip(jnp.round(xt / scale[:, :, None]), -127.0, 127.0)
+        q_ref[:, lanes] = q.reshape(rows, TILE_C).astype(jnp.int8)
+        s_ref[:, pl.ds(j, 1)] = scale
 
 
 def _dequant_kernel(q_ref, s_ref, x_ref):
-    br = q_ref.shape[0] // TILE_R
-    bc = q_ref.shape[1] // TILE_C
-    qt = q_ref[...].astype(jnp.float32).reshape(br, TILE_R, bc, TILE_C)
-    x = qt * s_ref[...][:, None, :, None]
-    x_ref[...] = x.reshape(q_ref.shape).astype(x_ref.dtype)
+    rows, cols = q_ref.shape
+    for j in range(cols // TILE_C):
+        lanes = pl.ds(j * TILE_C, TILE_C)
+        qt = q_ref[:, lanes].astype(jnp.float32).reshape(
+            rows // TILE_R, TILE_R, TILE_C)
+        x = qt * s_ref[:, pl.ds(j, 1)][:, :, None]
+        x_ref[:, lanes] = x.reshape(rows, TILE_C).astype(x_ref.dtype)
 
 
-def _grid(R, C, block_r, block_c):
-    return (R // (block_r * TILE_R), C // (block_c * TILE_C))
-
-
-@functools.partial(jax.jit, static_argnames=("block_r", "block_c", "interpret"))
-def quantize_blocks(x: jax.Array, block_r: int = BLOCK_R, block_c: int = BLOCK_C,
-                    interpret: bool = False):
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def quantize_blocks(x: jax.Array, interpret: bool = False):
     """x [R, C] (R % 8 == 0, C % 128 == 0) -> (q int8 [R,C], scales [R/8, C/128])."""
     R, C = x.shape
-    block_r = min(block_r, R // TILE_R)
-    block_c = min(block_c, C // TILE_C)
-    grid = _grid(R, C, block_r, block_c)
+    rows = _block_rows(R, C)
     return pl.pallas_call(
         _quant_kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((block_r * TILE_R, block_c * TILE_C),
-                               lambda i, j: (i, j))],
+        grid=(pl.cdiv(R, rows),),
+        in_specs=[pl.BlockSpec((rows, C), lambda i: (i, 0))],
         out_specs=[
-            pl.BlockSpec((block_r * TILE_R, block_c * TILE_C), lambda i, j: (i, j)),
-            pl.BlockSpec((block_r, block_c), lambda i, j: (i, j)),
+            pl.BlockSpec((rows, C), lambda i: (i, 0)),
+            pl.BlockSpec((rows // TILE_R, C // TILE_C), lambda i: (i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((R, C), jnp.int8),
@@ -80,24 +84,19 @@ def quantize_blocks(x: jax.Array, block_r: int = BLOCK_R, block_c: int = BLOCK_C
     )(x)
 
 
-@functools.partial(jax.jit, static_argnames=("dtype", "block_r", "block_c",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("dtype", "interpret"))
 def dequantize_blocks(q: jax.Array, scales: jax.Array, dtype=jnp.float32,
-                      block_r: int = BLOCK_R, block_c: int = BLOCK_C,
                       interpret: bool = False):
     R, C = q.shape
-    block_r = min(block_r, R // TILE_R)
-    block_c = min(block_c, C // TILE_C)
-    grid = _grid(R, C, block_r, block_c)
+    rows = _block_rows(R, C)
     return pl.pallas_call(
         _dequant_kernel,
-        grid=grid,
+        grid=(pl.cdiv(R, rows),),
         in_specs=[
-            pl.BlockSpec((block_r * TILE_R, block_c * TILE_C), lambda i, j: (i, j)),
-            pl.BlockSpec((block_r, block_c), lambda i, j: (i, j)),
+            pl.BlockSpec((rows, C), lambda i: (i, 0)),
+            pl.BlockSpec((rows // TILE_R, C // TILE_C), lambda i: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((block_r * TILE_R, block_c * TILE_C),
-                               lambda i, j: (i, j)),
+        out_specs=pl.BlockSpec((rows, C), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((R, C), dtype),
         interpret=interpret,
     )(q, scales)
@@ -107,16 +106,10 @@ def dequantize_blocks(q: jax.Array, scales: jax.Array, dtype=jnp.float32,
 #
 # The kernels above want an aligned 2D [R, C] grid; the wire sees arbitrary
 # activation pytree leaves.  These wrappers flatten, zero-pad to a whole
-# number of (8, 128) tiles, and run the kernel natively on TPU or in
-# interpret mode everywhere else (same numerics, still one jitted call).
+# number of (8, 128) tiles, and run the kernel compiled on a TPU or in
+# interpret mode on the CPU (same numerics, still one jitted call).
 
 WIRE_C = TILE_C
-
-
-def _wire_interpret(interpret: bool | None) -> bool:
-    if interpret is None:
-        return jax.default_backend() != "tpu"
-    return interpret
 
 
 def _pow2_tiles(n: int) -> int:
@@ -130,8 +123,7 @@ def _pow2_tiles(n: int) -> int:
     return p
 
 
-def quantize_wire(arr: np.ndarray,
-                  interpret: bool | None = None) -> tuple[np.ndarray, np.ndarray]:
+def quantize_wire(arr: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
     """Arbitrary-shape array -> (int8 payload [Np], float32 scales [Np/1024]).
 
     ``Np`` is ``arr.size`` zero-padded up to a power-of-two count of
@@ -147,14 +139,12 @@ def quantize_wire(arr: np.ndarray,
     if np_full > n:
         a = np.concatenate([a, np.zeros(np_full - n, np.float32)])
     x = a.reshape(-1, WIRE_C)
-    q, s = quantize_blocks(jnp.asarray(x),
-                           interpret=_wire_interpret(interpret))
+    q, s = quantize_blocks(jnp.asarray(x), interpret=interpret_mode())
     return np.asarray(q).ravel(), np.asarray(s, np.float32).ravel()
 
 
 def dequantize_wire(q: np.ndarray, scales: np.ndarray, n: int,
-                    shape: tuple[int, ...], dtype,
-                    interpret: bool | None = None) -> np.ndarray:
+                    shape: tuple[int, ...], dtype) -> np.ndarray:
     """Invert :func:`quantize_wire` back to ``shape``/``dtype``.  Accepts an
     int8 payload trimmed to ``n`` — the tail tiles quantized from zero
     padding are re-synthesized as zeros."""
@@ -167,5 +157,5 @@ def dequantize_wire(q: np.ndarray, scales: np.ndarray, n: int,
     s2 = np.ascontiguousarray(scales, dtype=np.float32).reshape(
         -1, WIRE_C // TILE_C)
     out = dequantize_blocks(jnp.asarray(q2), jnp.asarray(s2),
-                            interpret=_wire_interpret(interpret))
+                            interpret=interpret_mode())
     return np.asarray(out).ravel()[:n].reshape(shape).astype(dtype, copy=False)

@@ -31,7 +31,7 @@ BLOCK_C = 512
 
 def _decode_attn_kernel(pos_ref, q_ref, k_ref, v_ref, kpos_ref, o_ref,
                         m_ref, l_ref, acc_ref, *, scale, window, blocks):
-    c = pl.program_id(2)
+    b, c = pl.program_id(0), pl.program_id(2)
 
     @pl.when(c == 0)
     def _init():
@@ -40,10 +40,10 @@ def _decode_attn_kernel(pos_ref, q_ref, k_ref, v_ref, kpos_ref, o_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     q = q_ref[0, 0].astype(jnp.float32)                    # [G, hd]
-    k = k_ref[0, :, 0].astype(jnp.float32)                 # [BC, hd]
-    v = v_ref[0, :, 0].astype(jnp.float32)                 # [BC, hd]
-    kpos = kpos_ref[0]                                     # [BC] int32
-    pos = pos_ref[0]                                       # scalar int32
+    k = k_ref[0, 0].astype(jnp.float32)                    # [BC, hd]
+    v = v_ref[0, 0].astype(jnp.float32)                    # [BC, hd]
+    kpos = kpos_ref[0]                                     # [1, BC] int32
+    pos = pos_ref[b]                                       # SMEM scalar
 
     logits = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
@@ -52,7 +52,7 @@ def _decode_attn_kernel(pos_ref, q_ref, k_ref, v_ref, kpos_ref, o_ref,
     valid = (kpos >= 0) & (delta >= 0)
     if window is not None:
         valid &= delta < window
-    logits = jnp.where(valid[None, :], logits, NEG_INF)
+    logits = jnp.where(valid, logits, NEG_INF)
 
     m_prev = m_ref[...]                                    # [G, 1]
     m_cur = jnp.maximum(m_prev, logits.max(axis=1, keepdims=True))
@@ -77,6 +77,11 @@ def decode_attention(q, k, v, kpos, pos, window, scale,
 
     C must be a multiple of ``block_c`` (callers pad the cache; padded slots
     carry kpos = -1 and are masked out).
+
+    Mosaic tiles the last two dims of every block, so the kernel sees the
+    cache head-major (``[B, kv, C, hd]``: blocks of whole ``[BC, hd]``
+    rows) and ``kpos`` as ``[B, 1, C]``; ``pos`` rides scalar prefetch
+    into SMEM.
     """
     B, _, H, hd = q.shape
     C, kv = k.shape[1], k.shape[2]
@@ -85,26 +90,32 @@ def decode_attention(q, k, v, kpos, pos, window, scale,
     assert C % block_c == 0, f"cache len {C} % block {block_c} != 0"
     blocks = C // block_c
     qg = q.reshape(B, kv, g, hd)
+    kt = k.transpose(0, 2, 1, 3)
+    vt = v.transpose(0, 2, 1, 3)
 
     kernel = functools.partial(_decode_attn_kernel, scale=scale,
                                window=window, blocks=blocks)
     out = pl.pallas_call(
         kernel,
-        grid=(B, kv, blocks),
-        in_specs=[
-            pl.BlockSpec((1,), lambda b, h, c: (b,)),                    # pos
-            pl.BlockSpec((1, 1, g, hd), lambda b, h, c: (b, h, 0, 0)),   # q
-            pl.BlockSpec((1, block_c, 1, hd), lambda b, h, c: (b, c, h, 0)),
-            pl.BlockSpec((1, block_c, 1, hd), lambda b, h, c: (b, c, h, 0)),
-            pl.BlockSpec((1, block_c), lambda b, h, c: (b, c)),          # kpos
-        ],
-        out_specs=pl.BlockSpec((1, 1, g, hd), lambda b, h, c: (b, h, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,                                   # pos
+            grid=(B, kv, blocks),
+            in_specs=[
+                pl.BlockSpec((1, 1, g, hd), lambda b, h, c, _: (b, h, 0, 0)),
+                pl.BlockSpec((1, 1, block_c, hd),
+                             lambda b, h, c, _: (b, h, c, 0)),
+                pl.BlockSpec((1, 1, block_c, hd),
+                             lambda b, h, c, _: (b, h, c, 0)),
+                pl.BlockSpec((1, 1, block_c), lambda b, h, c, _: (b, 0, c)),
+            ],
+            out_specs=pl.BlockSpec((1, 1, g, hd),
+                                   lambda b, h, c, _: (b, h, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((g, 1), jnp.float32),     # running max
+                pltpu.VMEM((g, 1), jnp.float32),     # running denom
+                pltpu.VMEM((g, hd), jnp.float32),    # weighted-value acc
+            ]),
         out_shape=jax.ShapeDtypeStruct((B, kv, g, hd), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),     # running max
-            pltpu.VMEM((g, 1), jnp.float32),     # running denom
-            pltpu.VMEM((g, hd), jnp.float32),    # weighted-value acc
-        ],
         interpret=interpret,
-    )(pos, qg, k, v, kpos)
+    )(pos.astype(jnp.int32), qg, kt, vt, kpos[:, None, :])
     return out.reshape(B, 1, H, hd)

@@ -1,13 +1,12 @@
 """Public jit'd wrappers around the Pallas kernels.
 
-On CPU (this container) every wrapper runs the kernel in ``interpret=True``
-mode — the kernel body executes in Python for bit-faithful validation; on a
-real TPU backend the same calls lower to Mosaic.  Padding/reshaping to tile
-multiples lives here so kernel bodies stay shape-exact.
+On the CPU every wrapper runs the kernel in ``interpret=True`` mode — the
+kernel body executes in Python for bit-faithful validation; on a TPU the
+same calls lower to Mosaic (see :func:`repro.kernels.interpret_mode`).
+Padding/reshaping to tile multiples lives here so kernel bodies stay
+shape-exact.
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -15,11 +14,8 @@ import numpy as np
 
 from repro.kernels import block_quant as _bq
 from repro.kernels import decode_attention as _da
+from repro.kernels import interpret_mode
 from repro.kernels import ssd_scan as _ssd
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 # -- block quantization (wire compression for the DEFER pipeline) ---------------
@@ -32,13 +28,14 @@ def quantize_blocks(x: jax.Array):
     padr, padc = (-R) % _bq.TILE_R, (-C) % _bq.TILE_C
     if padr or padc:
         flat = jnp.pad(flat, ((0, padr), (0, padc)))
-    q, s = _bq.quantize_blocks(flat, interpret=_interpret())
+    q, s = _bq.quantize_blocks(flat, interpret=interpret_mode())
     return q, s, (shape, R, C)
 
 
 def dequantize_blocks(q: jax.Array, scales: jax.Array, meta, dtype=jnp.float32):
     shape, R, C = meta
-    x = _bq.dequantize_blocks(q, scales, dtype=dtype, interpret=_interpret())
+    x = _bq.dequantize_blocks(q, scales, dtype=dtype,
+                              interpret=interpret_mode())
     return x[:R, :C].reshape(shape)
 
 
@@ -62,7 +59,7 @@ def decode_attention(q, k, v, kpos, pos, window, scale):
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
         kpos = jnp.pad(kpos, ((0, 0), (0, pad)), constant_values=-1)
     return _da.decode_attention(q, k, v, kpos, pos, window, scale,
-                                block_c=block, interpret=_interpret())
+                                block_c=block, interpret=interpret_mode())
 
 
 # -- SSD scan ----------------------------------------------------------------------
@@ -75,5 +72,5 @@ def ssd_scan(xc, dtc, A, Bc, Cc, init_state):
     """
     B, nc, Q, H, P = xc.shape
     y, fin = _ssd.ssd_scan(xc, dtc, A, Bc, Cc, init_state,
-                           interpret=_interpret())
+                           interpret=interpret_mode())
     return y.reshape(B, nc * Q, H, P), fin

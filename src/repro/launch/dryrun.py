@@ -118,8 +118,6 @@ def _mem_dict(compiled) -> dict:
 
 def _cost_dict(compiled) -> dict:
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):    # JAX <= 0.4.x: one dict per device
-        cost = cost[0] if cost else {}
     out = {"flops": float(cost.get("flops", 0.0)),
            "bytes_accessed": float(cost.get("bytes accessed", 0.0)),
            "transcendentals": float(cost.get("transcendentals", 0.0))}

@@ -14,31 +14,25 @@ from __future__ import annotations
 import jax
 
 
-def make_mesh_compat(shape: tuple[int, ...], axes: tuple[str, ...], **kw):
-    """``jax.make_mesh`` across JAX versions.
-
-    ``axis_types`` only exists on newer JAX (>= 0.5); the pinned 0.4.37
-    raises ``AttributeError`` on ``jax.sharding.AxisType``.  Every mesh in
-    this repo wants plain Auto axes, so simply omit the argument when the
-    enum is unavailable — Auto is the default there anyway.
-    """
-    if hasattr(jax.sharding, "AxisType"):
-        kw.setdefault(
-            "axis_types", (jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes, **kw)
+def make_auto_mesh(shape: tuple[int, ...], axes: tuple[str, ...], **kw):
+    """``jax.make_mesh`` with every axis ``Auto``: the meshes here leave
+    sharding propagation to GSPMD inside ``jit`` (``jax.make_mesh``
+    defaults to ``Explicit`` axes)."""
+    auto = (jax.sharding.AxisType.Auto,) * len(axes)
+    return jax.make_mesh(shape, axes, auto, **kw)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return make_auto_mesh(shape, axes)
 
 
 def make_host_mesh(model: int | None = None):
     """Mesh over whatever devices exist (CPU tests / smoke runs)."""
     n = jax.device_count()
     model = model or 1
-    return make_mesh_compat((n // model, model), ("data", "model"))
+    return make_auto_mesh((n // model, model), ("data", "model"))
 
 
 def make_pipeline_mesh(num_stages: int, *, multi_pod: bool = False):
@@ -50,4 +44,4 @@ def make_pipeline_mesh(num_stages: int, *, multi_pod: bool = False):
     else:
         shape = (256 // num_stages, num_stages)
         axes = ("data", "stage")
-    return make_mesh_compat(shape, axes)
+    return make_auto_mesh(shape, axes)

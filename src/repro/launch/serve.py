@@ -21,12 +21,14 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs.base import ModelConfig
 from repro.configs.registry import ARCHS, get_config, get_smoke
 from repro.core.pipeline import PipelineConfig, make_pipeline, stack_stages
 from repro.kernels import ops as kops
-from repro.launch.mesh import make_host_mesh, make_mesh_compat
+from repro.launch.mesh import make_auto_mesh
 from repro.models import layers as L
 from repro.models import transformer as T
 
@@ -37,7 +39,7 @@ class PipelineLM:
     pipe_cfg: PipelineConfig
     stage_params: Any            # (stacked units, valid mask), stage-sharded
     extra: Any                   # replicated pytree (shared block) or None
-    params: Any                  # full params (embed/head/rem live outside)
+    params: Any                  # embed/head/rem (they live outside the chain)
     fn: Callable                 # the sharded pipeline callable
 
     def __call__(self, tokens: jax.Array, prefix_embeds=None,
@@ -113,14 +115,44 @@ def make_unit_fn(cfg: ModelConfig, with_extra: bool, unroll: bool = False):
     return lambda local, x: stage_fn(local, x, None)
 
 
+def init_stage_params(cfg: ModelConfig, key: jax.Array, mesh,
+                      num_stages: int, dtype=jnp.float32):
+    """Initialise an LM for the stage pipeline with its unit stack created
+    already stacked per stage and sharded over ``"stage"``: each device
+    materialises only its own stage's units, so no device ever holds the
+    whole model (nor a second, stacked copy of it).
+
+    Returns ``(params, stage_params)``: ``params`` is everything but
+    ``"units"``, replicated; ``stage_params`` is ``stack_stages``' pair,
+    for :func:`build_pipeline_lm`.  The values equal
+    ``T.init_lm(cfg, key, dtype)``'s: JAX's random bits do not depend on
+    how the output is sharded.
+    """
+    n_units = cfg.num_layers // cfg.unit_layers
+
+    def init(k):
+        params = T.init_lm(cfg, k, dtype)
+        return params, stack_stages(params.pop("units"), n_units, num_stages)
+
+    rest, staged = jax.eval_shape(init, key)
+    tmap = jax.tree_util.tree_map
+    shardings = (tmap(lambda _: NamedSharding(mesh, P()), rest),
+                 tmap(lambda _: NamedSharding(mesh, P("stage")), staged))
+    return jax.jit(init, out_shardings=shardings)(key)
+
+
 def build_pipeline_lm(cfg: ModelConfig, params: Any, mesh,
                       num_stages: int, num_microbatches: int,
                       compress: bool = False, quant_impl: str = "jnp",
                       axis: str = "stage",
                       data_axes: tuple[str, ...] = (),
-                      unroll: bool = False) -> PipelineLM:
-    n_units = cfg.num_layers // cfg.unit_layers
-    stacked, valid = stack_stages(params["units"], n_units, num_stages)
+                      unroll: bool = False,
+                      stage_params: Any = None) -> PipelineLM:
+    """``stage_params`` comes from :func:`init_stage_params`; without it
+    the stages are stacked from ``params["units"]``."""
+    if stage_params is None:
+        n_units = cfg.num_layers // cfg.unit_layers
+        stage_params = stack_stages(params["units"], n_units, num_stages)
     extra = {"shared": params["shared"]} if "shared" in params else None
     pipe_cfg = PipelineConfig(num_stages=num_stages,
                               num_microbatches=num_microbatches,
@@ -129,7 +161,7 @@ def build_pipeline_lm(cfg: ModelConfig, params: Any, mesh,
     fn = make_pipeline(mesh, pipe_cfg,
                        make_unit_fn(cfg, extra is not None, unroll=unroll),
                        data_axes=data_axes, with_extra=extra is not None)
-    return PipelineLM(cfg, pipe_cfg, (stacked, valid), extra, params, fn)
+    return PipelineLM(cfg, pipe_cfg, stage_params, extra, params, fn)
 
 
 # -- autoregressive decode THROUGH the pipeline (beyond-paper) -------------------
@@ -235,15 +267,18 @@ def main() -> None:
     ap.add_argument("--full", action="store_true")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_config(args.arch) if args.full else get_smoke(args.arch)
     if jax.device_count() < args.stages:
-        raise SystemExit(f"need >= {args.stages} devices "
-                         f"(run under XLA_FLAGS=--xla_force_host_platform_"
-                         f"device_count={args.stages})")
-    mesh = make_mesh_compat((args.stages,), ("stage",))
-    params = T.init_lm(cfg, jax.random.PRNGKey(0))
+        raise SystemExit(
+            f"--stages {args.stages} needs one device per stage; "
+            f"{jax.device_count()} {jax.default_backend()} device(s) found "
+            f"(pass --stages {jax.device_count()} or fewer)")
+    mesh = make_auto_mesh((args.stages,), ("stage",))
+    params, stage_params = init_stage_params(cfg, jax.random.PRNGKey(0),
+                                             mesh, args.stages)
     lm = build_pipeline_lm(cfg, params, mesh, args.stages, args.microbatches,
-                           compress=args.compress)
+                           compress=args.compress, stage_params=stage_params)
     B = args.requests
     tokens = jax.random.randint(jax.random.PRNGKey(1), (B, args.seq), 0,
                                 cfg.vocab)

@@ -180,8 +180,7 @@ def moe_block_local(p: dict, s: MoESpec, x_l: jax.Array, axis_name: str,
     dispatch locally -> all_to_all tokens to expert owners -> dense expert
     FFN on local experts -> all_to_all back -> combine.
     """
-    from repro.sharding import axis_size
-    ax = axis_size(axis_name)
+    ax = jax.lax.axis_size(axis_name)
     B_l, S_l, d = x_l.shape
     T_l = B_l * S_l
     E = s.moe.num_experts

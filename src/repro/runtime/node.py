@@ -41,6 +41,7 @@ serving ones (per-stage utilization, queue depth, batch occupancy) from
 from __future__ import annotations
 
 import dataclasses
+import functools
 import queue
 import threading
 import time
@@ -306,17 +307,22 @@ class ComputeNode:
             plan.wire_bytes, 0.0, time.perf_counter() - t0))
 
     def _make_apply(self):
+        # the stage's weights are an argument of every jitted function
+        # (bound with partial), never a closed-over constant: constants
+        # are baked into each compiled specialization, so host memory,
+        # compile time and device memory would grow with the model once
+        # per batch size and prompt length
         nodes, params = self._nodes, self._params
         exported = self._exported
 
-        def apply_fn(boundary: dict[str, Any]) -> dict[str, Any]:
+        def apply_fn(params, boundary: dict[str, Any]) -> dict[str, Any]:
             acts = dict(boundary)
             for node in nodes:
                 args = [acts[i] for i in node.inputs]
                 acts[node.name] = node.fn(params.get(node.name, {}), *args)
             return {n: acts[n] for n in exported}
 
-        self._apply = jax.jit(apply_fn)
+        self._apply = functools.partial(jax.jit(apply_fn), params)
 
         # autoregressive view of the same slice: prefill walks the chain
         # once over a full prompt collecting each stateful layer's KV
@@ -331,7 +337,7 @@ class ComputeNode:
                 or len(self._required) != 1 or len(exported) != 1):
             return
 
-        def prefill_fn(x):
+        def prefill_fn(params, x):
             acts = x
             caches = {}
             for node in nodes:
@@ -342,7 +348,7 @@ class ComputeNode:
                     acts = node.fn(p, acts)
             return acts, caches
 
-        def step_fn(caches, x, pos):
+        def step_fn(params, caches, x, pos):
             acts = x
             new = {}
             for node in nodes:
@@ -354,8 +360,8 @@ class ComputeNode:
                     acts = node.fn(p, acts)
             return acts, new
 
-        self._prefill_apply = jax.jit(prefill_fn)
-        self._decode_apply = jax.jit(step_fn)
+        self._prefill_apply = functools.partial(jax.jit(prefill_fn), params)
+        self._decode_apply = functools.partial(jax.jit(step_fn), params)
 
     def precompile(self) -> None:
         """Trace/compile every power-of-two padded batch specialization this

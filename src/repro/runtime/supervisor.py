@@ -51,11 +51,18 @@ import sys
 import threading
 import time
 
+import jax
+
 from repro.runtime.node import BatchTrace
 from repro.runtime.transport import (ChannelClosed, TcpTransport,
                                      recv_framed, send_framed)
 from repro.runtime.wire import (_RETIRE, _STOP, BatchEnvelope, ControlFrame,
                                 NodePlan, ReconfigMarker, WireFormatError)
+
+
+class WorkerRefused(RuntimeError):
+    """A spawned worker came up on another JAX platform than its
+    supervisor and was stopped before serving."""
 
 
 @dataclasses.dataclass
@@ -133,6 +140,7 @@ class WorkerHandle:
         self._send_lock = threading.Lock()
         self._hello = threading.Event()
         self._ready = threading.Event()
+        self.platform: str | None = None    # the worker's, from "ready"
         self._creader: threading.Thread | None = None
         # telemetry, synthesized from heartbeat snapshot deltas so the
         # engine report and the controller read a worker exactly like an
@@ -216,6 +224,7 @@ class WorkerHandle:
                 self._on_hb(item.payload)
             elif item.kind == "ready":
                 self._hb_at = time.monotonic()
+                self.platform = item.payload.get("platform")
                 self._ready.set()
             elif item.kind == "bye":
                 self.bye = True
@@ -342,6 +351,22 @@ class WorkerHandle:
             raise ChannelClosed(
                 f"worker stage {self.index} replica {self.replica} not "
                 f"ready within {self._sup._cfg.spawn_timeout_s}s")
+        if self.platform != self._sup.platform:
+            # JAX falls back to the CPU when it cannot open the chip, and
+            # the chip belongs to one process at a time: a worker beside a
+            # supervisor that already holds it would serve, slowly, on
+            # the wrong device.  Stop it before it takes a request.
+            self.dead = True
+            self.retiring = True
+            self.kill_links()
+            self.proc.kill()
+            self.reap(1.0)
+            raise WorkerRefused(
+                f"worker stage {self.index} replica {self.replica} (pid "
+                f"{self.proc.pid}) runs JAX on {self.platform!r}, the "
+                f"supervisor on {self._sup.platform!r}: the accelerator is "
+                f"held by another process (one process per chip), so the "
+                f"worker cannot use it; refusing to serve on it")
         self._started_flag = True
 
     def retire(self) -> None:
@@ -449,6 +474,9 @@ class Supervisor:
 
     def __init__(self, config: SupervisorConfig):
         self._cfg = config
+        # every worker must come up on this JAX platform (see
+        # WorkerHandle.start)
+        self.platform = jax.default_backend()
         self._transport = TcpTransport()    # private data-plane listener
         self._lock = threading.Lock()
         self._handles: list[WorkerHandle] = []

@@ -19,7 +19,8 @@ that socket:
 * ``"precompile"`` / ``"start"`` / ``"knobs"`` — lifecycle and tuning,
   applied in order (the loop is serial, so a ``"start"`` can never
   overtake the config that precedes it).  After ``"start"`` the worker
-  acks ``"ready"`` and begins heartbeating.
+  acks ``"ready"`` with its JAX platform (the supervisor refuses a worker
+  whose platform differs from its own) and begins heartbeating.
 * ``"chaos"`` — fault injection (hang the compute stage), honored only
   when the process was launched with ``--chaos``; production spawns
   ignore it.
@@ -47,6 +48,9 @@ import socket
 import sys
 import threading
 
+import jax
+
+from repro.compile_cache import enable_compile_cache
 from repro.runtime.node import ComputeNode
 from repro.runtime.transport import dial_channel, recv_framed, send_framed
 from repro.runtime.wire import (ControlFrame, ReconfigMarker, WireCodec,
@@ -168,7 +172,8 @@ class Worker:
         self._node.start()
         threading.Thread(target=self._heartbeat_loop, daemon=True).start()
         threading.Thread(target=self.drain, daemon=True).start()
-        self._send(ControlFrame("ready", {"pid": os.getpid()}))
+        self._send(ControlFrame("ready", {"pid": os.getpid(),
+                                          "platform": jax.default_backend()}))
 
     # -- background threads ---------------------------------------------------
     def _heartbeat_loop(self) -> None:
@@ -239,6 +244,7 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("--chaos", action="store_true",
                     help="honor ControlFrame('chaos') fault injection")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     host, _, port = args.connect.rpartition(":")
     sock = socket.create_connection((host, int(port)), timeout=10.0)
     try:

@@ -22,14 +22,6 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-def axis_size(name: str) -> int:
-    """Size of a named mesh axis from inside shard_map, across JAX
-    versions: ``jax.lax.axis_size`` is missing on 0.4.x, where
-    ``psum(1, axis)`` constant-folds to the same static int."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(name)
-    return jax.lax.psum(1, name)
-
 
 # leaf name -> (which matrix dim gets "model")
 _SHARD_LAST = {"wq", "wk", "wv", "up", "gate", "in_proj"}   # d_in x d_out: out

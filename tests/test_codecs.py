@@ -5,11 +5,7 @@ strings (the invariant DEFER's weights socket depends on).
 """
 import numpy as np
 import pytest
-
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:      # network-less CI image: degrade to fixed examples
-    from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import codecs
 

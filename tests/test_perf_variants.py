@@ -102,7 +102,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp, importlib, dataclasses
 from repro.core.pipeline import stack_stages
 from repro.core.pipeline_ep import build_ep_pipeline
-from repro.launch.mesh import make_mesh_compat
+from repro.launch.mesh import make_auto_mesh
 from repro.models import transformer as T
 from repro.models import layers as L
 
@@ -113,7 +113,7 @@ params = T.init_lm(cfg, jax.random.PRNGKey(0))
 B, S, M = 4, 16, 2
 tokens = jax.random.randint(jax.random.PRNGKey(1), (B, S), 0, cfg.vocab)
 ref, _ = T.forward(params, cfg, tokens)
-mesh = make_mesh_compat((1, 2, 2), ("data", "expert", "stage"))
+mesh = make_auto_mesh((1, 2, 2), ("data", "expert", "stage"))
 n_units = cfg.num_layers // cfg.unit_layers
 factory = build_ep_pipeline(cfg, mesh, num_stages=2, num_microbatches=M)
 def step(params, tokens):

@@ -11,7 +11,7 @@ import pytest
 from repro.core.pipeline import (PipelineConfig, make_pipeline,
                                  make_stage_unit_fn, pipeline_apply,
                                  stack_stages)
-from repro.launch.mesh import make_mesh_compat
+from repro.launch.mesh import make_auto_mesh
 
 
 def test_stack_stages_padding_and_mask():
@@ -37,7 +37,7 @@ def test_single_stage_pipeline_equals_sequential():
     def apply_unit(up, x):
         return x + jnp.tanh(x @ up)
 
-    mesh = make_mesh_compat((1,), ("stage",))
+    mesh = make_auto_mesh((1,), ("stage",))
     stacked, valid = stack_stages(w, 3, 1)
     fn = make_pipeline(mesh, PipelineConfig(1, 4),
                        make_stage_unit_fn(apply_unit))
@@ -65,7 +65,7 @@ _MULTIDEV_SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp, numpy as np, importlib
-from repro.launch.mesh import make_mesh_compat
+from repro.launch.mesh import make_auto_mesh
 from repro.launch.serve import build_pipeline_lm
 from repro.models import transformer as T
 
@@ -73,7 +73,7 @@ failures = []
 for a in ["phi3_mini_3_8b", "zamba2_2_7b", "seamless_m4t_large_v2"]:
     cfg = importlib.import_module(f"repro.configs.{a}").smoke_config()
     params = T.init_lm(cfg, jax.random.PRNGKey(0))
-    mesh = make_mesh_compat((4,), ("stage",))
+    mesh = make_auto_mesh((4,), ("stage",))
     B, S, M = 8, 16, 4
     tokens = jax.random.randint(jax.random.PRNGKey(1), (B, S), 0, cfg.vocab)
     kw = {}

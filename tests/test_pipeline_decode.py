@@ -9,7 +9,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.launch.mesh import make_mesh_compat
+from repro.launch.mesh import make_auto_mesh
 from repro.launch.serve import build_pipeline_decoder
 from repro.models import transformer as T
 
@@ -32,7 +32,7 @@ def _ref_greedy(cfg, params, start_m, mb, steps, max_len):
 def test_pipeline_decode_matches_greedy_single_stage(arch, M):
     cfg = importlib.import_module(f"repro.configs.{arch}").smoke_config()
     params = T.init_lm(cfg, jax.random.PRNGKey(0))
-    mesh = make_mesh_compat((1,), ("stage",))
+    mesh = make_auto_mesh((1,), ("stage",))
     mb, steps, max_len = 2, 4, 16
     start = jax.random.randint(jax.random.PRNGKey(1), (M, mb, 1), 0,
                                cfg.vocab)
@@ -50,13 +50,13 @@ _MULTISTAGE = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp, importlib
-from repro.launch.mesh import make_mesh_compat
+from repro.launch.mesh import make_auto_mesh
 from repro.launch.serve import build_pipeline_decoder
 from repro.models import transformer as T
 
 cfg = importlib.import_module("repro.configs.phi3_mini_3_8b").smoke_config()
 params = T.init_lm(cfg, jax.random.PRNGKey(0))
-mesh = make_mesh_compat((4,), ("stage",))
+mesh = make_auto_mesh((4,), ("stage",))
 M, mb, steps, max_len = 6, 2, 5, 16
 start = jax.random.randint(jax.random.PRNGKey(1), (M, mb, 1), 0, cfg.vocab)
 start_pos = jnp.zeros((M, mb), jnp.int32)

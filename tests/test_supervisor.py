@@ -16,7 +16,7 @@ import pytest
 from repro.runtime import NodeError, TopologySpec
 from repro.runtime.dispatcher import DispatcherCodecs
 from repro.runtime.supervisor import (Supervisor, SupervisorConfig,
-                                      supervised_engine)
+                                      WorkerRefused, supervised_engine)
 from repro.runtime.transport import (ChannelClosed, TcpTransport,
                                      dial_channel, recv_framed, send_framed)
 from repro.runtime.wire import (FRAME_VERSION, BatchEnvelope, ControlFrame,
@@ -187,6 +187,28 @@ def test_procs_end_to_end_numerics_and_clean_drain():
         eng.shutdown()
         sup.close()
     assert not [e for e in sup.events if e["kind"] == "death"]
+
+
+def test_worker_on_another_platform_is_refused():
+    """A worker whose JAX came up on another platform than its
+    supervisor's (on a chip host: the parent holds the chip, the child
+    fell back to the CPU) is refused at start, with the reason, and
+    stopped before it takes a request."""
+    g = mlp_graph()
+    params = g.init(jax.random.PRNGKey(0))
+    eng, sup = supervised_engine(g, params, TopologySpec.chain(g, 1),
+                                 _cfg(), codecs=RAW)
+    sup.platform = "tpu"        # as if this process held the chip
+    try:
+        with pytest.raises(WorkerRefused,
+                           match="'cpu'.*held by another process"):
+            eng.start()
+        (h,) = sup._handles
+        assert h.platform == "cpu" and h.dead
+        assert h.proc.poll() is not None
+    finally:
+        eng.shutdown()
+        sup.close()
 
 
 def test_procs_kill_heals_and_respawns_fast():

@@ -6,11 +6,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:      # network-less CI image: degrade to fixed examples
-    from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.train import checkpoint as ckpt
 from repro.train.optimizer import (OptConfig, apply_updates, global_norm,
