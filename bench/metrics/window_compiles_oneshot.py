@@ -1,0 +1,9 @@
+"""window_compiles.oneshot: backend compiles (persistent-cache loads
+included) inside the window, counted from JAX's monitoring events.
+Should read 0."""
+
+
+def read(win):
+    if win.traffic["kind"] != "oneshot":
+        return None
+    return len(win.compiles)

@@ -1,0 +1,18 @@
+"""The benchmark's own percentile arithmetic, taken from the client's
+side (the program's `LatencySummary` is the dispatcher's)."""
+from __future__ import annotations
+
+import math
+from typing import Sequence
+
+
+def percentile(values: Sequence[float], q: float) -> float:
+    """The ``q``-th percentile (0-100) by linear interpolation between
+    order statistics (numpy's default rule), over every value given."""
+    xs = sorted(values)
+    if not xs:
+        raise ValueError("percentile of no values")
+    pos = (len(xs) - 1) * q / 100.0
+    lo = math.floor(pos)
+    hi = min(lo + 1, len(xs) - 1)
+    return xs[lo] + (xs[hi] - xs[lo]) * (pos - lo)
