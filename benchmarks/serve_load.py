@@ -272,12 +272,12 @@ def run(nodes: int = 4, clients: int = 8, samples: int = 16,
             "throughput_rps": rep.throughput_cps,
             "p50_ms": rep.p50_latency_s * 1e3,
             "p99_ms": rep.p99_latency_s * 1e3,
-            "util_compute": float(np.mean([pn["util_compute"]
-                                           for pn in rep.per_node])),
-            "util_decode": float(np.mean([pn["util_decode"]
-                                          for pn in rep.per_node])),
-            "util_encode": float(np.mean([pn["util_encode"]
-                                          for pn in rep.per_node])),
+            "util_compute_raw": float(np.mean([pn["util_compute_raw"]
+                                               for pn in rep.per_node])),
+            "util_decode_raw": float(np.mean([pn["util_decode_raw"]
+                                              for pn in rep.per_node])),
+            "util_encode_raw": float(np.mean([pn["util_encode_raw"]
+                                              for pn in rep.per_node])),
             "batch_mean": float(np.mean([pn["batch_mean"]
                                          for pn in rep.per_node])),
             "encodes_per_batch": float(np.mean([pn["encodes_per_batch"]

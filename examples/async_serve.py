@@ -151,8 +151,9 @@ print(f"\n{report.samples} requests over {report.num_nodes} replicas "
       f"p99 {report.p99_latency_s*1e3:.0f} ms")
 for pn in report.per_node:
     print(f"  stage {pn['stage']} replica {pn['replica']}: "
-          f"util dec/cmp/enc {pn['util_decode']*100:4.1f}/"
-          f"{pn['util_compute']*100:4.1f}/{pn['util_encode']*100:4.1f}%  "
+          f"util dec/cmp/enc {pn['util_decode_raw']*100:4.1f}/"
+          f"{pn['util_compute_raw']*100:4.1f}/"
+          f"{pn['util_encode_raw']*100:4.1f}%  "
           f"mean batch {pn['batch_mean']:.2f}  "
           f"service {pn['service_s']*1e3:.2f} ms  "
           f"knobs mb={pn['max_batch']} co={pn['coalesce_s']*1e3:.1f}ms")

@@ -16,8 +16,8 @@ cost summaries that the emulator / pipeline runtime consume.
 
 Online recalibration (the serving-time feedback loop) plans on *measured*
 costs instead of the static models: :class:`CalibratedCosts` carries
-per-layer compute seconds plus per-byte codec/wire rates learned from real
-``BatchTrace`` telemetry, :func:`calibrated_partition` re-runs the DP on
+per-layer compute seconds plus per-byte codec/wire rates learned from the
+replicas' measured running totals, :func:`calibrated_partition` re-runs the DP on
 them (optionally warm-started in a window around the current cuts, which
 also bounds how many layers a live migration has to ship), and
 :func:`bounds_bottleneck` is the cost-delta API — it prices *any* candidate

@@ -5,8 +5,8 @@ DEFER's steady-state throughput is ``1 / max_i service_i`` — it is set by
 the slowest stage.  The dispatcher plans the chain ONCE, offline, from
 static :class:`~repro.core.partitioner.ComputeModel` /
 :class:`~repro.core.partitioner.LinkModel` guesses; meanwhile every node
-already *measures* its real per-stage decode / compute / encode time per
-batch (:class:`~repro.runtime.node.BatchTrace` + per-stage busy counters).
+already *measures* its real per-stage decode / compute / encode time
+(the running totals of :meth:`~repro.runtime.node.ComputeNode.snapshot`).
 This module feeds those measurements back into the plan while the chain is
 serving:
 

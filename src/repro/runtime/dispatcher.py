@@ -48,10 +48,11 @@ from repro.core.graph import LayerGraph
 from repro.core.partitioner import LinkModel, Partition, partition
 from repro.runtime.node import _STOP, ComputeNode
 from repro.runtime.router import FenceTally, StageGroup
+from repro.runtime.spans import Spans
 from repro.runtime.topology import TopologySpec
 from repro.runtime.transport import Channel, ChannelClosed, get_transport
 from repro.runtime.wire import (BatchEnvelope, NodePlan, ReconfigMarker,
-                                RowExtent, WireCodec, WireRecord,
+                                RowExtent, WireCodec, WireRecord, first_id,
                                 slice_parts, validate_client_id)
 
 
@@ -260,12 +261,22 @@ class Dispatcher:
             for s in topology.stages]
         self.result_channel: Channel = self._open_channel(
             topology.stages[-1].transport, 0)
+        # window totals: the input encode in submit and the tail's collect
+        # (spans), the waits at the admission queue, each stage router's
+        # input and the result channel; and, for the client-side decode
+        # loop (generate_tokens), each token's argmax and next submit
+        self.stats = Spans(
+            "dispatcher", ("serialize", "collect"),
+            ("admission",
+             *(f"route{i}" for i in range(topology.num_stages)), "result"))
+        self.session_stats = Spans("session", ("next",))
         self.stages: list[StageGroup] = []
         for i, spec in enumerate(topology.stages):
             replicas = [self._make_replica(i, r)
                         for r in range(spec.replicas)]
             group = StageGroup(i, spec, replicas, self._stage_inputs[i],
                                upstream=self.stages[i - 1] if i else None,
+                               stats=self.stats,
                                fail_batch=self._finish_batch,
                                note_displaced=self._note_displaced)
             self.stages.append(group)
@@ -464,6 +475,9 @@ class Dispatcher:
                 except (ChannelClosed, OSError):
                     pass                # head link dead: nothing to stop
                 return
+            now = time.perf_counter()
+            self.stats.waited("admission", env.t_enq, env.n, now)
+            env.t_enq = now
             try:
                 head.send(env)
             except (ChannelClosed, OSError):
@@ -500,6 +514,8 @@ class Dispatcher:
                 self._fail_all_pending(
                     "result channel closed: the chain's tail link died")
                 return
+            if isinstance(item, BatchEnvelope):
+                self.stats.waited("result", item.t_enq, item.n)
             if item is _STOP:
                 if self._tail.on_stop():
                     if not self._closed:
@@ -539,16 +555,19 @@ class Dispatcher:
                 self._finish_batch(env.extents, error=env.error,
                                    retryable=env.retryable)
                 continue
-            try:
-                flat, _ = self.codecs.data.decode_tree(env.blob)
-                flat = {k: np.asarray(v) for k, v in flat.items()}
-                parts = slice_parts(flat, env.extents)
-            except Exception:               # codec failure at the tail
-                self._finish_batch(env.extents, error=traceback.format_exc())
-                continue
-            results = [(next(iter(p.values())) if len(p) == 1 else p)
-                       for p in parts]
-            self._finish_batch(env.extents, results=results)
+            with self.stats.span("collect", rid=first_id(env.extents),
+                                 rows=env.n):
+                try:
+                    flat, _ = self.codecs.data.decode_tree(env.blob)
+                    flat = {k: np.asarray(v) for k, v in flat.items()}
+                    parts = slice_parts(flat, env.extents)
+                except Exception:               # codec failure at the tail
+                    self._finish_batch(env.extents,
+                                       error=traceback.format_exc())
+                    continue
+                results = [(next(iter(p.values())) if len(p) == 1 else p)
+                           for p in parts]
+                self._finish_batch(env.extents, results=results)
 
     def _release_locked(self, client: Any, now: float) -> list[tuple]:
         """Pop every in-order (by seq) completed result for ``client``.
@@ -943,8 +962,9 @@ class Dispatcher:
             self._admitting += 1
         try:
             arr = np.asarray(x)
-            blob, rec = self.codecs.data.encode_tree(
-                {"": arr}, "data", request_id=rid, client_id=client_id)
+            with self.stats.span("serialize", rid=rid):
+                blob, rec = self.codecs.data.encode_tree(
+                    {"": arr}, "data", request_id=rid, client_id=client_id)
             rows = int(arr.shape[0]) if arr.ndim else 1
             t_sub = time.perf_counter()
             env = BatchEnvelope(
@@ -973,6 +993,7 @@ class Dispatcher:
                                        (ret.deadline, 0, rid, 0))
                         self._ensure_reaper_locked()
                         self._timer_cv.notify()
+            env.t_enq = time.perf_counter()
             self.admission.put(env, block=block, timeout=timeout,
                                priority=priority)
         except queue.Full:
@@ -1254,6 +1275,8 @@ class Dispatcher:
         with self._lock:
             self.latencies = []
             self.feed_records = []
+        self.stats.reset()
+        self.session_stats.reset()
         for node in self.nodes:
             node.reset_stats()
 

@@ -29,12 +29,16 @@ overlap is visible), queue depth, batch occupancy, and p50/p99 request
 latency, so the paper's ``1/max_i service_i`` law — amortized by replica
 counts — is observable under real multi-client load.
 
-Utilizations come in two flavors per stage: the clamped ``util_*`` (a
-fraction of the window, capped at 1.0 for dashboard sanity) and the raw
-``util_*_raw`` (busy / wall, uncapped).  On an oversubscribed host a busy
-counter can legitimately exceed the wall clock — stage threads count
-runnable-but-descheduled time — and the serving controller needs to SEE
-that oversubscription honestly to avoid tuning against a saturated lie.
+Utilizations are ``util_*_raw`` per stage (busy / wall, uncapped).  On an
+oversubscribed host a busy counter can legitimately exceed the wall clock
+— stage threads count runnable-but-descheduled time — and the serving
+controller needs to SEE that oversubscription honestly to avoid tuning
+against a saturated lie.
+
+Every replica's window running totals (``snapshot()``: the spans, hand-off
+waits and counters of :mod:`repro.runtime.spans`) ride its ``per_node``
+entry under ``totals``; the dispatcher's and the decode loop's ride
+:attr:`EngineReport.dispatcher` and :attr:`EngineReport.session`.
 
 With ``controller=ControllerConfig(...)`` the engine runs the serving-time
 feedback loop (:mod:`repro.runtime.controller`): online cost calibration
@@ -85,6 +89,11 @@ class EngineReport:
     cuts: tuple = ()                   # live partition cut indices
     replicas: tuple = ()               # live per-stage replica counts
     epoch: int = 0                     # committed live fences so far
+    # window totals of the dispatcher (spans ``serialize``/``collect``,
+    # waits ``admission``/``route<i>``/``result``) and of the client-side
+    # decode loop (span ``next``)
+    dispatcher: dict = dataclasses.field(default_factory=dict)
+    session: dict = dataclasses.field(default_factory=dict)
 
 
 class InferenceEngine:
@@ -282,18 +291,16 @@ class InferenceEngine:
             live = group.live_replicas()
             for node in live:
                 num_nodes += 1
-                with node._stats_lock:
-                    tr = list(node.traces)
-                    depths = list(node.queue_depths)
-                    busy_dec = node.busy_decode_s
-                    busy_cmp = node.busy_compute_s
-                    busy_enc = node.busy_encode_s
-                n_req_raw = sum(t.n for t in tr)
+                snap = node.snapshot()
+                busy_dec = snap["busy_decode_s"]
+                busy_cmp = snap["busy_compute_s"]
+                busy_enc = snap["busy_encode_s"]
+                n_req_raw = snap["n"]
                 n_req = n_req_raw or 1
-                compute = sum(t.compute_s for t in tr) / n_req
-                ser = sum(t.serialize_s for t in tr) / n_req
-                des = sum(t.deserialize_s for t in tr) / n_req
-                payload = sum(t.payload_bytes for t in tr) / n_req
+                compute = snap["compute_s"] / n_req
+                ser = snap["serialize_s"] / n_req
+                des = snap["deserialize_s"] / n_req
+                payload = snap["payload_bytes"] / n_req
                 chunks = max(1.0, np.ceil(payload / CHUNK_BYTES))
                 wire_s = self.link.latency_s * chunks \
                     + payload / self.link.bandwidth_bytes_per_s
@@ -330,20 +337,10 @@ class InferenceEngine:
                     "payload_bytes": payload, "energy_j": energy,
                     "idle_energy_j": idle_energy,
                     "requests": n_req_raw,
-                    # the replica's saturation = its busiest stage's
-                    # fraction of the window (stages overlap, so summing
-                    # them would let the old total-busy metric exceed 1.0
-                    # and get clamped)
-                    "utilization": min(1.0, max(busy_dec, busy_cmp, busy_enc)
-                                       / util_wall),
-                    "util_decode": min(1.0, busy_dec / util_wall),
-                    "util_compute": min(1.0, busy_cmp / util_wall),
-                    "util_encode": min(1.0, busy_enc / util_wall),
-                    # raw (unclamped) busy fractions: can exceed 1.0 on an
-                    # oversubscribed host (runnable-but-descheduled time
-                    # books as busy) — the controller and BENCH notes read
-                    # these to see oversubscription honestly; the clamped
-                    # ones above stay for dashboards
+                    # busy fractions of the window, unclamped: they can
+                    # exceed 1.0 on an oversubscribed host (runnable-but-
+                    # descheduled time books as busy), which the controller
+                    # must see
                     "util_decode_raw": busy_dec / util_wall,
                     "util_compute_raw": busy_cmp / util_wall,
                     "util_encode_raw": busy_enc / util_wall,
@@ -353,13 +350,12 @@ class InferenceEngine:
                     "max_batch": node.max_batch,
                     "coalesce_s": node.coalesce_s,
                     "layers": [ln.name for ln in node._nodes],
-                    "queue_depth_mean": (float(np.mean(depths)) if depths
-                                         else 0.0),
-                    "queue_depth_max": max(depths) if depths else 0,
-                    "batch_mean": (float(np.mean([t.n for t in tr])) if tr
-                                   else 0.0),
-                    "encodes_per_batch": (float(np.mean(
-                        [t.encodes for t in tr])) if tr else 0.0),
+                    "queue_depth_mean": snap["queue_depth_mean"],
+                    "queue_depth_max": snap["depth_max"],
+                    "batch_mean": snap["batch_mean"],
+                    "encodes_per_batch": (snap["encodes"] / snap["waves"]
+                                          if snap["waves"] else 0.0),
+                    "totals": snap,
                 })
                 stage_service = max(stage_service, service)
                 total_payload += payload
@@ -394,4 +390,6 @@ class InferenceEngine:
             cuts=tuple(d.partition.cuts),
             replicas=d.replicas,
             epoch=d.epoch,
+            dispatcher=d.stats.snapshot(),
+            session=d.session_stats.snapshot(),
         )

@@ -32,11 +32,17 @@ envelope (formatted traceback) that downstream stages relay untouched, the
 collector fails exactly those futures, and the node keeps serving
 subsequent batches.
 
-Timings are recorded per batch (``BatchTrace``) and per stage
-(``busy_decode_s`` / ``busy_compute_s`` / ``busy_encode_s``), so the engine
-can report the paper's metrics (compute, overhead, payload) plus the
-serving ones (per-stage utilization, queue depth, batch occupancy) from
-*real* execution — and so the codec/compute overlap is directly measurable.
+Each replica keeps one set of window running totals (``stats``, a
+:class:`~repro.runtime.spans.Spans` owned as ``stage<i>``): spans where the
+work happens (``deserialize``; ``h2d``, ``apply``, ``d2h`` of a stacked
+batch or a decode step; a session's whole ``prefill``; the decode wave's
+``kv_gather`` and ``kv_scatter``; ``serialize``), the waits at its three
+hand-offs (``inbox`` up to the end of the ingress's coalescing window,
+``compute``, ``egress``) and counters (requests, waves, rows, bytes each
+way).  The engine reports the paper's metrics (compute, overhead,
+payload) plus the serving ones (per-stage utilization, queue depth, batch
+occupancy) from them — and so the codec/compute overlap is directly
+measurable.  ``compute_s`` is ``prefill + apply + d2h``.
 """
 from __future__ import annotations
 
@@ -53,6 +59,7 @@ import numpy as np
 
 from repro.core.graph import LayerGraph, LayerNode
 from repro.runtime.session import SessionStore
+from repro.runtime.spans import Spans
 from repro.runtime.transport import Channel, ChannelClosed, InprocChannel
 # _STOP / _RETIRE live in wire.py so the byte framing can map them to
 # dedicated frame types (a socket transport must carry them too); they are
@@ -65,22 +72,39 @@ from repro.runtime.transport import Channel, ChannelClosed, InprocChannel
 from repro.runtime.wire import (_RETIRE, _STOP, K_CLOSE,  # noqa: F401
                                 K_OPEN, K_PLAIN, K_STEP, BatchEnvelope,
                                 ReconfigMarker, RowExtent, WireCodec,
-                                WireRecord, slice_parts,
+                                WireRecord, first_id, slice_parts,
                                 tree_unflatten_paths)
 
 
-@dataclasses.dataclass
-class BatchTrace:
-    """Timings for one merged batch (n requests computed together)."""
+# one replica's running totals (see repro.runtime.spans): spans, waits at
+# its hand-offs, and counters.  ``n`` counts requests computed (extents),
+# ``rows`` the rows they carried, ``step_rows`` decode-step rows; bytes are
+# host<->device copies of activations (a prefill's included) and KV
+# gathered plus scattered.
+def stage_stats(index: int, lock: threading.Lock | None = None) -> Spans:
+    return Spans(f"stage{index}",
+                 ("deserialize", "h2d", "apply", "d2h", "prefill",
+                  "kv_gather", "kv_scatter", "serialize"),
+                 ("inbox", "compute", "egress"),
+                 ("n", "waves", "rows", "prefills", "step_rows", "h2d_bytes",
+                  "d2h_bytes", "kv_bytes", "payload_bytes", "encodes",
+                  "busy_compute_s", "depth_sum", "depth_count"),
+                 lock)
 
-    node: int
-    n: int                       # requests in the batch
-    padded: int                  # rows actually computed (after padding)
-    deserialize_s: float         # summed over the batch's inbound envelopes
-    compute_s: float             # apply over the stacked buckets
-    serialize_s: float           # summed over the batch's outbound encodes
-    payload_bytes: int           # summed outbound wire bytes
-    encodes: int = 0             # outbound codec passes (== buckets, not n)
+
+def stage_view(totals: dict) -> dict:
+    """A replica's window totals plus what the controller and the report
+    derive from them: ``compute_s`` (prefill + apply + d2h), the stages'
+    busy seconds, ``batch_mean`` and ``queue_depth_mean``."""
+    waves, depths = totals["waves"], totals["depth_count"]
+    return {**totals,
+            "compute_s": totals["prefill_s"] + totals["apply_s"]
+            + totals["d2h_s"],
+            "busy_decode_s": totals["deserialize_s"],
+            "busy_encode_s": totals["serialize_s"],
+            "batch_mean": totals["n"] / waves if waves else 0.0,
+            "queue_depth_mean": (totals["depth_sum"] / depths if depths
+                                 else 0.0)}
 
 
 @dataclasses.dataclass
@@ -89,15 +113,16 @@ class _Decoded:
 
     extents: list[RowExtent]
     boundary: dict[str, np.ndarray]      # stacked over the envelope's extents
-    deserialize_s: float
+    t_enq: float = 0.0                   # put on the compute queue
 
 
 @dataclasses.dataclass
 class _Computed:
-    """Compute -> egress: one merged batch's bucket outputs + its trace."""
+    """Compute -> egress: one merged batch's bucket outputs."""
 
     buckets: list[tuple[list[RowExtent], dict[str, np.ndarray]]]
-    trace: BatchTrace
+    n: int                               # requests in the merged batch
+    t_enq: float = 0.0                   # put on the egress queue
 
 
 def _bucket_rows(n: int) -> int:
@@ -171,21 +196,10 @@ class ComputeNode:
         # stashed here and leads the next wave (queues can't push back)
         self._ingress_pending = None
         self._compute_pending = None
-        self.traces: list[BatchTrace] = []
-        self.queue_depths: list[int] = []
-        # running totals over the window (kept alongside the trace list so
-        # the controller's periodic snapshot() is O(1), not O(waves))
-        self._depth_sum = 0
-        self._depth_count = 0
-        self._trace_n = 0
-        self._trace_compute_s = 0.0
-        self._trace_serialize_s = 0.0
-        self._trace_deserialize_s = 0.0
-        self._trace_payload_bytes = 0
-        self._trace_encodes = 0
-        self.busy_decode_s: float = 0.0
-        self.busy_compute_s: float = 0.0
-        self.busy_encode_s: float = 0.0
+        self._stats_lock = threading.Lock()
+        # the window's running totals, under the stats lock
+        self.stats = stage_stats(index, self._stats_lock)
+        self._depth_max = 0         # the window's deepest merge, a max
         self.config_records: list[WireRecord] = []
         self._graph: LayerGraph | None = None
         self._nodes: list[LayerNode] = []
@@ -202,19 +216,12 @@ class ComputeNode:
         self._decode_apply = None
         self._is_tail = False
         self._threads: list[threading.Thread] = []
-        self._stats_lock = threading.Lock()
         # live gauge (NOT a window counter — reset_stats leaves it):
         # requests consumed off the inbox but not yet emitted downstream.
         # A wedged compute thread that swallowed its whole backlog shows
         # inbox qsize 0 (credits returned on consume), so stall detection
         # needs this to see work trapped inside the pipeline.
         self._inflight_n = 0
-
-    @property
-    def busy_s(self) -> float:
-        """Total busy time summed over stages (can exceed wall time when
-        stages overlap — report per-stage utilization, not this / wall)."""
-        return self.busy_decode_s + self.busy_compute_s + self.busy_encode_s
 
     # -- configuration step (paper §III-B) ----------------------------------
     def configure(self, graph: LayerGraph, lo: int, hi: int,
@@ -446,73 +453,31 @@ class ComputeNode:
             t.join()
 
     def reset_stats(self) -> None:
+        self.stats.reset()
         with self._stats_lock:
-            self.traces = []
-            self.queue_depths = []
-            self._depth_sum = 0
-            self._depth_count = 0
-            self._trace_n = 0
-            self._trace_compute_s = 0.0
-            self._trace_serialize_s = 0.0
-            self._trace_deserialize_s = 0.0
-            self._trace_payload_bytes = 0
-            self._trace_encodes = 0
-            self.busy_decode_s = 0.0
-            self.busy_compute_s = 0.0
-            self.busy_encode_s = 0.0
+            self._depth_max = 0
 
     def _record_depth(self, depth: int) -> None:
         """Record one merge's queue-depth sample.  Caller holds
         ``_stats_lock``."""
-        self.queue_depths.append(depth)
-        self._depth_sum += depth
-        self._depth_count += 1
-
-    def _record_trace(self, trace: BatchTrace) -> None:
-        """Append a finished batch's trace and fold it into the running
-        totals.  Caller must hold ``_stats_lock``."""
-        self.traces.append(trace)
-        self._trace_n += trace.n
-        self._trace_compute_s += trace.compute_s
-        self._trace_serialize_s += trace.serialize_s
-        self._trace_deserialize_s += trace.deserialize_s
-        self._trace_payload_bytes += trace.payload_bytes
-        self._trace_encodes += trace.encodes
+        self.stats.add_locked(depth_sum=depth, depth_count=1)
+        self._depth_max = max(self._depth_max, depth)
 
     def snapshot(self) -> dict:
         """One consistent view of the current measurement window's
         telemetry — what the serving controller calibrates costs and
-        adapts knobs from.  All time fields are window totals; ``n`` is
-        requests computed this window.  O(1): reads the running totals,
-        not the trace list."""
+        adapts knobs from, and what a worker ships in its heartbeats.
+        Every total (spans, waits, counters; ``n`` is requests computed
+        this window) plus the derived fields of :func:`stage_view`, the
+        knobs, the epoch and the in-flight gauge.  O(1)."""
         with self._stats_lock:
-            waves = len(self.traces)
-            return {
-                "node": self.index,
-                "replica": self.replica,
-                "n": self._trace_n,
-                "compute_s": self._trace_compute_s,
-                "serialize_s": self._trace_serialize_s,
-                "deserialize_s": self._trace_deserialize_s,
-                "payload_bytes": self._trace_payload_bytes,
-                "encodes": self._trace_encodes,
-                "busy_decode_s": self.busy_decode_s,
-                "busy_compute_s": self.busy_compute_s,
-                "busy_encode_s": self.busy_encode_s,
-                "queue_depth_mean": (self._depth_sum / self._depth_count
-                                     if self._depth_count else 0.0),
-                "batch_mean": (self._trace_n / waves if waves else 0.0),
-                # raw accumulators, so a delta-ing consumer (the
-                # controller) can rebuild per-interval means instead of
-                # mixing interval counters with window-cumulative gauges
-                "waves": waves,
-                "depth_sum": self._depth_sum,
-                "depth_count": self._depth_count,
-                "max_batch": self.max_batch,
-                "coalesce_s": self.coalesce_s,
-                "epoch": self.epoch,
-                "inflight_n": self._inflight_n,
-            }
+            snap = stage_view(self.stats.totals)
+            snap["depth_max"] = self._depth_max
+            inflight = self._inflight_n
+        snap.update(node=self.index, replica=self.replica,
+                    max_batch=self.max_batch, coalesce_s=self.coalesce_s,
+                    epoch=self.epoch, inflight_n=inflight)
+        return snap
 
     # -- stage 1: ingress (decode) --------------------------------------------
     def _ingress_loop(self) -> None:
@@ -590,33 +555,36 @@ class ComputeNode:
                 wave.append(nxt)
                 if nxt.error is None:
                     n_parts += nxt.n
-            # book only codec time as decode busy — the queue puts below can
-            # block on backpressure, which is waiting, not stage work
-            des_busy = 0.0
+            # the wave is closed: its envelopes' inbox wait ends here.  Only
+            # codec time is decode busy (the deserialize span) — the queue
+            # puts below can block on backpressure, which is waiting
+            now = time.perf_counter()
+            for env in wave:
+                self.stats.waited("inbox", env.t_enq, env.n, now)
             decoded: list[_Decoded] = []
             relay: list[BatchEnvelope] = []
             for env in wave:
                 if env.error is not None:       # relay failures untouched
                     relay.append(env)
                     continue
-                t1 = time.perf_counter()
-                try:
-                    flat, _ = self.data_codec.decode_tree(env.blob)
-                    dt = time.perf_counter() - t1
-                    decoded.append(_Decoded(
-                        env.extents,
-                        {k: np.asarray(v) for k, v in flat.items()}, dt))
-                except Exception:
-                    dt = time.perf_counter() - t1
-                    relay.append(BatchEnvelope(
-                        env.extents, b"", error=traceback.format_exc()))
-                des_busy += dt
+                with self.stats.span("deserialize", rid=first_id(env.extents),
+                                     rows=env.n):
+                    try:
+                        flat, _ = self.data_codec.decode_tree(env.blob)
+                        decoded.append(_Decoded(
+                            env.extents,
+                            {k: np.asarray(v) for k, v in flat.items()}))
+                    except Exception:
+                        relay.append(BatchEnvelope(
+                            env.extents, b"", error=traceback.format_exc()))
             with self._stats_lock:
-                self.busy_decode_s += des_busy
                 self._inflight_n += sum(len(e.extents) for e in wave)
             for env in relay:
                 self._to_compute.put(env)
             if decoded:
+                now = time.perf_counter()
+                for d in decoded:
+                    d.t_enq = now
                 self._to_compute.put(decoded)
             if saw_stop is not None:
                 self._to_compute.put(saw_stop)
@@ -666,16 +634,18 @@ class ComputeNode:
                     break
                 group.extend(nxt)
                 n_parts += add
+            t0 = time.perf_counter()
+            for d in group:
+                self.stats.waited("compute", d.t_enq, len(d.extents), t0)
             with self._stats_lock:
                 self._record_depth(n_parts + self.inbox.qsize()
                                    + self._to_compute.qsize())
-            t0 = time.perf_counter()
             out, failures = self._compute_group(group)
-            with self._stats_lock:
-                self.busy_compute_s += time.perf_counter() - t0
+            self.stats.add(busy_compute_s=time.perf_counter() - t0)
             for env in failures:
                 self._to_encode.put(env)
             if out is not None:
+                out.t_enq = time.perf_counter()
                 self._to_encode.put(out)
             if saw_stop is not None:
                 self._to_encode.put(saw_stop)
@@ -703,25 +673,37 @@ class ComputeNode:
         extents = [e if e.pad_trim is not None
                    else dataclasses.replace(e, pad_trim=orig_mid)
                    for e in d.extents]
-        return _Decoded(extents, padded, d.deserialize_s)
+        return _Decoded(extents, padded, d.t_enq)
 
     def _stack_apply(self, segments: list[dict[str, np.ndarray]],
-                     total: int, target: int) -> tuple[dict[str, np.ndarray], float]:
+                     total: int, target: int,
+                     rid: int) -> dict[str, np.ndarray]:
         """Concatenate per-leaf segments along axis 0, zero-pad to ``target``
         rows, run the jitted partition apply once, trim back to ``total``.
-        Shared by the staged compute stage and the legacy per-request path."""
+        Shared by the staged compute stage and the legacy per-request path.
+        ``rid`` (the first request's id) rides the spans' metadata."""
+        stats = self.stats
         stacked: dict[str, jax.Array] = {}
-        for key in segments[0]:
-            arrs = [s[key] for s in segments]
-            cat = np.concatenate(arrs, axis=0) if len(arrs) > 1 else arrs[0]
-            if target > total:
-                pad = np.zeros((target - total,) + cat.shape[1:], cat.dtype)
-                cat = np.concatenate([cat, pad], axis=0)
-            stacked[key] = jax.numpy.asarray(cat)
-        t0 = time.perf_counter()
-        res = self._apply(stacked)
-        res = {k: np.asarray(v)[:total] for k, v in res.items()}  # block
-        return res, time.perf_counter() - t0
+        up = 0
+        with stats.span("h2d", rid=rid, rows=total):
+            for key in segments[0]:
+                arrs = [s[key] for s in segments]
+                cat = np.concatenate(arrs, axis=0) if len(arrs) > 1 else arrs[0]
+                if target > total:
+                    pad = np.zeros((target - total,) + cat.shape[1:],
+                                   cat.dtype)
+                    cat = np.concatenate([cat, pad], axis=0)
+                up += cat.nbytes
+                stacked[key] = jax.numpy.asarray(cat)
+        # no span waits on the device: ``apply`` is the call, and ``d2h``'s
+        # np.asarray waits for the upload, the computation and the copy out
+        with stats.span("apply", rid=rid, rows=total):
+            res = self._apply(stacked)
+        with stats.span("d2h", rid=rid, rows=total):
+            res = {k: np.asarray(v) for k, v in res.items()}
+        stats.add(rows=total, h2d_bytes=up,
+                  d2h_bytes=sum(v.nbytes for v in res.values()))
+        return {k: v[:total] for k, v in res.items()}
 
     def _compute_group(self, group: list[_Decoded]
                        ) -> tuple[_Computed | None, list[BatchEnvelope]]:
@@ -737,7 +719,6 @@ class ComputeNode:
         of one bucket each; the original sizes ride the extents
         (``pad_trim``) and the tail collector trims them back out."""
         n = sum(len(d.extents) for d in group)
-        des_s = sum(d.deserialize_s for d in group)
         # session frames (kind != K_PLAIN) take the decode path; plain
         # traffic keeps the stacked-apply path.  Both run inside the same
         # merged wave, so a chain can serve single-shot and decode traffic
@@ -749,14 +730,10 @@ class ComputeNode:
              else plain).append(d)
         outs: list[tuple[list[RowExtent], dict[str, np.ndarray]]] = []
         failures: list[BatchEnvelope] = []
-        compute_total = 0.0
-        padded_rows = 0
         if sess:
-            s_out, s_fail, s_compute, s_padded = self._decode_group(sess)
+            s_out, s_fail = self._decode_group(sess)
             outs.extend(s_out)
             failures.extend(s_fail)
-            compute_total += s_compute
-            padded_rows += s_padded
         if self.shape_buckets == "pow2" and self._pad_safe:
             # only when every layer in this replica's slice is pad_safe:
             # a segment containing e.g. attention over the middle axis
@@ -771,24 +748,20 @@ class ComputeNode:
             total = sum(next(iter(d.boundary.values())).shape[0]
                         for d in segs)
             target = _bucket_rows(total) if self.pad_batches else total
-            padded_rows += target
             try:
-                res, apply_s = self._stack_apply(
-                    [d.boundary for d in segs], total, target)
+                res = self._stack_apply([d.boundary for d in segs], total,
+                                        target, first_id(extents))
             except Exception:
                 failures.append(BatchEnvelope(extents, b"",
                                               error=traceback.format_exc()))
                 continue
-            compute_total += apply_s
             outs.append((extents, res))
         if not outs:
             return None, failures
-        trace = BatchTrace(self.index, n, padded_rows, des_s, compute_total,
-                           0.0, 0, encodes=0)
-        return _Computed(outs, trace), failures
+        return _Computed(outs, n), failures
 
     def _decode_group(self, group: list[_Decoded]
-                      ) -> tuple[list, list[BatchEnvelope], float, int]:
+                      ) -> tuple[list, list[BatchEnvelope]]:
         """Serve one merged wave's session traffic (kind != K_PLAIN).
 
         Closes evict the session's resident caches and pass their payload
@@ -809,13 +782,11 @@ class ComputeNode:
         pin whole envelopes; a multi-session envelope could not route
         sticky), enforced here.
 
-        Returns ``(outs, failures, compute_s, padded_rows)`` for the
-        caller's trace accounting.
+        Returns ``(outs, failures)``.
         """
+        stats = self.stats
         outs: list[tuple[list[RowExtent], dict[str, np.ndarray]]] = []
         failures: list[BatchEnvelope] = []
-        compute_s = 0.0
-        padded = 0
         out_name = self._exported[0] if self._exported else ""
         steps: list[tuple[RowExtent, np.ndarray, Any]] = []
         for d in group:
@@ -840,23 +811,23 @@ class ComputeNode:
                 continue
             x = next(iter(d.boundary.values()))
             if e.kind == K_OPEN:
-                t0 = time.perf_counter()
-                try:
-                    y, caches = self._prefill_apply(jax.numpy.asarray(x))
-                    y = np.asarray(y)
-                except Exception:
-                    failures.append(BatchEnvelope(
-                        [e], b"", error=traceback.format_exc()))
-                    continue
-                finally:
-                    compute_s += time.perf_counter() - t0
+                with stats.span("prefill", rid=e.request_id,
+                                rows=x.shape[0]):
+                    try:
+                        y, caches = self._prefill_apply(jax.numpy.asarray(x))
+                        y = np.asarray(y)
+                    except Exception:
+                        failures.append(BatchEnvelope(
+                            [e], b"", error=traceback.format_exc()))
+                        continue
+                stats.add(prefills=1, rows=x.shape[0], h2d_bytes=x.nbytes,
+                          d2h_bytes=y.nbytes)
                 # park the caches even when the slice holds no stateful
                 # layer (caches == {}): residency doubles as the routing
                 # check a later step validates against
                 self.sessions.put(e.session, caches)
                 if self._is_tail:
                     y = y[:, -1:]
-                padded += x.shape[0]
                 outs.append(([e], {out_name: y}))
             elif e.kind == K_STEP:
                 cache = self.sessions.get(e.session)
@@ -868,7 +839,7 @@ class ComputeNode:
                         "replica restarted); re-open the session from "
                         "its retained history")))
                     continue
-                steps.append((e, np.asarray(x), cache))
+                steps.append((e, x, cache))
             else:
                 failures.append(BatchEnvelope(
                     [e], b"",
@@ -881,30 +852,38 @@ class ComputeNode:
             # rows are bit-identical to an unpadded apply and the padded
             # duplicates' outputs/caches are simply dropped
             rows = steps + [steps[-1]] * (target - b)
-            xs = jax.numpy.asarray(
-                np.concatenate([x for _, x, _ in rows], axis=0))
-            pos = jax.numpy.asarray(
-                np.asarray([e.pos for e, _, _ in rows], np.int32))
-            caches = jax.tree_util.tree_map(
-                lambda *leaves: jax.numpy.concatenate(leaves, axis=0),
-                *[c for _, _, c in rows])
-            t0 = time.perf_counter()
+            rid = steps[0][0].request_id
+            with stats.span("h2d", rid=rid, rows=b):
+                xs = jax.numpy.asarray(
+                    np.concatenate([x for _, x, _ in rows], axis=0))
+                pos = jax.numpy.asarray(
+                    np.asarray([e.pos for e, _, _ in rows], np.int32))
+            with stats.span("kv_gather", rid=rid, rows=b):
+                caches = jax.tree_util.tree_map(
+                    lambda *leaves: jax.numpy.concatenate(leaves, axis=0),
+                    *[c for _, _, c in rows])
             try:
-                y, new = self._decode_apply(caches, xs, pos)
-                y = np.asarray(y)
+                with stats.span("apply", rid=rid, rows=b):
+                    y, new = self._decode_apply(caches, xs, pos)
+                with stats.span("d2h", rid=rid, rows=b):    # as in _stack_apply
+                    y = np.asarray(y)
             except Exception:
-                compute_s += time.perf_counter() - t0
                 tb = traceback.format_exc()
                 failures.extend(BatchEnvelope([e], b"", error=tb)
                                 for e, _, _ in steps)
-                return outs, failures, compute_s, padded
-            compute_s += time.perf_counter() - t0
-            padded += target
+                return outs, failures
+            with stats.span("kv_scatter", rid=rid, rows=b):
+                for i, (e, _, _) in enumerate(steps):
+                    self.sessions.put(e.session, jax.tree_util.tree_map(
+                        lambda a, i=i: a[i:i + 1], new))
+            # leaves' nbytes are metadata: no sync.  Gathered: the whole
+            # stacked cache; scattered: one row of it per session
+            kv = sum(a.nbytes for a in jax.tree_util.tree_leaves(caches))
+            stats.add(rows=b, step_rows=b, h2d_bytes=xs.nbytes + pos.nbytes,
+                      d2h_bytes=y.nbytes, kv_bytes=kv + kv * b // target)
             for i, (e, _, _) in enumerate(steps):
-                self.sessions.put(e.session, jax.tree_util.tree_map(
-                    lambda a, i=i: a[i:i + 1], new))
                 outs.append(([e], {out_name: y[i:i + 1]}))
-        return outs, failures, compute_s, padded
+        return outs, failures
 
     # -- stage 3: egress (encode once per bucket, relay) ----------------------
     def _relay(self, item: Any) -> None:
@@ -920,6 +899,8 @@ class ComputeNode:
         instead of the request silently hanging."""
         if self.next_inbox is None:
             return
+        if isinstance(item, BatchEnvelope):
+            item.t_enq = time.perf_counter()
         try:
             self.next_inbox.send(item)
         except (ChannelClosed, OSError):
@@ -958,30 +939,30 @@ class ComputeNode:
                     self._inflight_n -= len(item.extents)
                 self._relay(item)
                 continue
-            # book only codec time as encode busy; the relay puts can block
-            # on the next node's bounded inbox (backpressure, not work)
-            enc_busy = 0.0
+            # only codec time is encode busy (the serialize span); the relay
+            # puts can block on the next node's bounded inbox (backpressure)
+            self.stats.waited("egress", item.t_enq, item.n)
+            payload = encodes = 0
             out_envs: list[BatchEnvelope] = []
             for extents, res in item.buckets:
-                t0 = time.perf_counter()
-                try:
-                    blob, rec = self.data_codec.encode_tree(
-                        res, "data", request_id=extents[0].request_id,
-                        client_id=extents[0].client_id)
-                    env = BatchEnvelope(extents, blob,
-                                        epoch=self._egress_epoch)
-                    item.trace.serialize_s += rec.encode_s
-                    item.trace.payload_bytes += rec.wire_bytes
-                    item.trace.encodes += 1
-                except Exception:
-                    env = BatchEnvelope(extents, b"",
-                                        error=traceback.format_exc(),
-                                        epoch=self._egress_epoch)
-                enc_busy += time.perf_counter() - t0
+                with self.stats.span("serialize", rid=extents[0].request_id,
+                                     rows=len(extents)):
+                    try:
+                        blob, rec = self.data_codec.encode_tree(
+                            res, "data", request_id=extents[0].request_id,
+                            client_id=extents[0].client_id)
+                        env = BatchEnvelope(extents, blob,
+                                            epoch=self._egress_epoch)
+                        payload += rec.wire_bytes
+                        encodes += 1
+                    except Exception:
+                        env = BatchEnvelope(extents, b"",
+                                            error=traceback.format_exc(),
+                                            epoch=self._egress_epoch)
                 out_envs.append(env)
             with self._stats_lock:
-                self.busy_encode_s += enc_busy
-                self._record_trace(item.trace)
+                self.stats.add_locked(n=item.n, waves=1,
+                                      payload_bytes=payload, encodes=encodes)
                 self._inflight_n -= sum(len(e.extents) for e in out_envs)
             for env in out_envs:
                 self._relay(env)
@@ -1053,9 +1034,9 @@ class ComputeNode:
     def process_batch(self, envs: list[BatchEnvelope]) -> list[BatchEnvelope]:
         """Decode, bucket-by-shape, pad, compute once, split, re-encode each
         request separately (per-request wire, PR 1 semantics)."""
+        stats = self.stats
         passthrough = [e for e in envs if e.error is not None]
         work = [e for e in envs if e.error is None]
-        des_total = 0.0
         samples: list[tuple[RowExtent, dict[str, np.ndarray]]] = []
         failed: list[BatchEnvelope] = []
         for env in work:
@@ -1067,65 +1048,55 @@ class ComputeNode:
                     error="decode sessions require the staged runtime "
                           "(ComputeNode(staged=True))"))
                 continue
-            t0 = time.perf_counter()
-            try:
-                flat, _ = self.data_codec.decode_tree(env.blob)
-                flat = {k: np.asarray(v) for k, v in flat.items()}
-            except Exception:
-                failed.append(BatchEnvelope(env.extents, b"",
-                                            error=traceback.format_exc()))
-                continue
-            des_total += time.perf_counter() - t0
+            with stats.span("deserialize", rid=first_id(env.extents),
+                            rows=env.n):
+                try:
+                    flat, _ = self.data_codec.decode_tree(env.blob)
+                    flat = {k: np.asarray(v) for k, v in flat.items()}
+                except Exception:
+                    failed.append(BatchEnvelope(env.extents, b"",
+                                                error=traceback.format_exc()))
+                    continue
             for ext, part in zip(env.extents, slice_parts(flat, env.extents)):
                 samples.append((ext, part))
-        with self._stats_lock:
-            self.busy_decode_s += des_total
 
         buckets: dict[tuple, list[tuple[RowExtent, dict]]] = {}
         for ext, boundary in samples:
             buckets.setdefault(_signature(boundary), []).append((ext, boundary))
 
         out_envs: list[BatchEnvelope] = list(passthrough) + failed
-        compute_total = 0.0
-        ser_total = 0.0
         payload_total = 0
-        padded_rows = 0
         encodes = 0
         for bucket in buckets.values():
             rows = [next(iter(b.values())).shape[0] for _, b in bucket]
             total = sum(rows)
             target = _bucket_rows(total) if self.pad_batches else total
-            padded_rows += target
+            t0 = time.perf_counter()
             try:
-                outs, apply_s = self._stack_apply(
-                    [b for _, b in bucket], total, target)
-                compute_total += apply_s
+                outs = self._stack_apply([b for _, b in bucket], total,
+                                         target, bucket[0][0].request_id)
             except Exception:
                 tb = traceback.format_exc()
                 out_envs.extend(BatchEnvelope([ext], b"", error=tb)
                                 for ext, _ in bucket)
                 continue
+            finally:
+                stats.add(busy_compute_s=time.perf_counter() - t0)
             off = 0
             for (ext, _), b_rows in zip(bucket, rows):
                 piece = {k: v[off:off + b_rows] for k, v in outs.items()}
                 off += b_rows
-                try:
-                    t0 = time.perf_counter()
-                    blob, rec = self.data_codec.encode_tree(
-                        piece, "data", request_id=ext.request_id,
-                        client_id=ext.client_id)
-                    ser_total += time.perf_counter() - t0
-                    payload_total += rec.wire_bytes
-                    encodes += 1
-                    out_envs.append(BatchEnvelope([ext], blob))
-                except Exception:
-                    out_envs.append(BatchEnvelope([ext], b"",
-                                                  error=traceback.format_exc()))
-
-        with self._stats_lock:
-            self.busy_compute_s += compute_total
-            self.busy_encode_s += ser_total
-            self._record_trace(BatchTrace(
-                self.index, len(samples), padded_rows, des_total,
-                compute_total, ser_total, payload_total, encodes=encodes))
+                with stats.span("serialize", rid=ext.request_id, rows=1):
+                    try:
+                        blob, rec = self.data_codec.encode_tree(
+                            piece, "data", request_id=ext.request_id,
+                            client_id=ext.client_id)
+                        payload_total += rec.wire_bytes
+                        encodes += 1
+                        out_envs.append(BatchEnvelope([ext], blob))
+                    except Exception:
+                        out_envs.append(BatchEnvelope(
+                            [ext], b"", error=traceback.format_exc()))
+        stats.add(n=len(samples), waves=1, payload_bytes=payload_total,
+                  encodes=encodes)
         return out_envs

@@ -58,6 +58,7 @@ shutdown still joins cleanly.
 from __future__ import annotations
 
 import threading
+import time
 from collections import deque
 from typing import TYPE_CHECKING
 
@@ -67,6 +68,7 @@ from repro.runtime.wire import (K_CLOSE, K_OPEN, K_STEP, BatchEnvelope,
                                 ReconfigMarker)
 
 if TYPE_CHECKING:
+    from repro.runtime.spans import Spans
     from repro.runtime.topology import StageSpec
 
 
@@ -123,7 +125,7 @@ class StageGroup:
 
     def __init__(self, index: int, spec: "StageSpec",
                  replicas: list[ComputeNode], input_channel: Channel,
-                 upstream: "StageGroup | None",
+                 upstream: "StageGroup | None", stats: "Spans",
                  fail_batch=None, note_displaced=None):
         self.index = index
         self.spec = spec
@@ -141,6 +143,11 @@ class StageGroup:
         # died), so their KV caches at this stage are gone — the
         # dispatcher flags them for session-layer re-prefill
         self.note_displaced = note_displaced
+        # the dispatcher's Spans: the wait of each envelope on this
+        # stage's input channel, stamped by its sender (the pump or the
+        # previous stage's egress)
+        self.stats = stats
+        self._wait_key = f"route{index}"
         # epoch -> (markers the DOWNSTREAM barrier must count, members
         # remaining after the fence).  Written before the broadcast, read
         # by the next router / the collector when its barrier trips.
@@ -333,6 +340,8 @@ class StageGroup:
             framing refuses) propagates so the caller fails exactly that
             batch WITHOUT retiring a healthy replica; for control tokens
             (always frameable) any failure is link-shaped."""
+            if isinstance(item, BatchEnvelope):
+                item.t_enq = time.perf_counter()
             try:
                 m.inbox.send(item)
             except (ChannelClosed, OSError):
@@ -456,6 +465,8 @@ class StageGroup:
                         retryable=True)
                 broadcast(_STOP)
                 return
+            if isinstance(item, BatchEnvelope):
+                self.stats.waited(self._wait_key, item.t_enq, item.n)
             if item is _STOP:
                 if not tally.on_stop():
                     continue

@@ -171,6 +171,9 @@ def generate_tokens(dispatcher, prompt: Sequence[int],
     sid = session_id if session_id is not None \
         else f"sess-{uuid.uuid4().hex[:16]}"
     cid = client_id if client_id is not None else sid
+    # each token's client-side work, two ``next`` spans: its argmax, then
+    # (after the yield hands it to the caller) the next step's submit
+    stats = dispatcher.session_stats
 
     def _open() -> np.ndarray:
         """(Re-)prefill the full retained history; the tail trims to the
@@ -183,11 +186,12 @@ def generate_tokens(dispatcher, prompt: Sequence[int],
         return np.asarray(fut.result(step_timeout))
 
     def _step(tok: int) -> np.ndarray:
-        x = np.asarray([[tok]], np.int32)
-        fut = dispatcher.submit(x, client_id=cid, session=sid,
-                                session_pos=len(history) - 1,
-                                session_kind=K_STEP,
-                                deadline_s=deadline_s)
+        with stats.span("next", pos=len(history) - 1):
+            x = np.asarray([[tok]], np.int32)
+            fut = dispatcher.submit(x, client_id=cid, session=sid,
+                                    session_pos=len(history) - 1,
+                                    session_kind=K_STEP,
+                                    deadline_s=deadline_s)
         return np.asarray(fut.result(step_timeout))
 
     def _advance(tok: int | None) -> np.ndarray:
@@ -215,7 +219,8 @@ def generate_tokens(dispatcher, prompt: Sequence[int],
         logits = _advance(None)
         made = 0
         while True:
-            tok = int(np.argmax(logits[0, -1]))
+            with stats.span("next", pos=len(history)):
+                tok = int(np.argmax(logits[0, -1]))
             yield tok
             history.append(tok)
             made += 1

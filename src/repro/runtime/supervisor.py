@@ -6,7 +6,7 @@ hook: every replica the engine builds — at construction AND through the
 :class:`WorkerHandle` fronting a real OS process running
 ``python -m repro.runtime.worker``.  The handle duck-types
 :class:`~repro.runtime.node.ComputeNode` completely (configure /
-precompile / start / retire / join, knobs, snapshot, trace telemetry), so
+precompile / start / retire / join, knobs, snapshot telemetry), so
 the dispatcher, routers, controller, and engine report code are unchanged:
 a stage may be process-backed or in-process and nothing upstream can tell.
 
@@ -53,7 +53,7 @@ import time
 
 import jax
 
-from repro.runtime.node import BatchTrace
+from repro.runtime.node import stage_stats, stage_view
 from repro.runtime.transport import (ChannelClosed, TcpTransport,
                                      recv_framed, send_framed)
 from repro.runtime.wire import (_RETIRE, _STOP, BatchEnvelope, ControlFrame,
@@ -142,15 +142,13 @@ class WorkerHandle:
         self._ready = threading.Event()
         self.platform: str | None = None    # the worker's, from "ready"
         self._creader: threading.Thread | None = None
-        # telemetry, synthesized from heartbeat snapshot deltas so the
+        # telemetry: the worker's node ships its running totals in every
+        # heartbeat; the window is their delta from the last reset, so the
         # engine report and the controller read a worker exactly like an
         # in-process node
         self._stats_lock = threading.Lock()
-        self.traces: list[BatchTrace] = []
-        self.queue_depths: list[float] = []
-        self.busy_decode_s = 0.0
-        self.busy_compute_s = 0.0
-        self.busy_encode_s = 0.0
+        self._zero = stage_stats(stage).zero
+        self._depth_max = 0.0
         self.config_records: list = []
         self._nodes: list = []
         self._last_snap: dict = {}
@@ -179,6 +177,8 @@ class WorkerHandle:
                 item = self._outbox.recv()
             except ChannelClosed:
                 return
+            if isinstance(item, BatchEnvelope):
+                item.t_enq = time.perf_counter()
             try:
                 if self.next_inbox is not None:
                     self.next_inbox.send(item)
@@ -251,31 +251,14 @@ class WorkerHandle:
         with self._stats_lock:
             self._hb_at = time.monotonic()
             prev, self._last_snap = self._last_snap, snap
-            dn = int(g(snap, "n") - g(prev, "n"))
-            if dn > 0:
-                # one synthetic trace per heartbeat interval: totals
-                # (requests, stage seconds, payload) aggregate exactly;
-                # only per-wave shape (batch_mean) coarsens to per-interval
-                self.traces.append(BatchTrace(
-                    self.index, dn, 0,
-                    g(snap, "deserialize_s") - g(prev, "deserialize_s"),
-                    g(snap, "compute_s") - g(prev, "compute_s"),
-                    g(snap, "serialize_s") - g(prev, "serialize_s"),
-                    int(g(snap, "payload_bytes") - g(prev, "payload_bytes")),
-                    encodes=int(g(snap, "encodes") - g(prev, "encodes"))))
+            # queue depth's window max: the largest mean depth of any
+            # heartbeat interval (a max does not difference)
             dc = g(snap, "depth_count") - g(prev, "depth_count")
             if dc > 0:
-                self.queue_depths.append(
-                    (g(snap, "depth_sum") - g(prev, "depth_sum")) / dc)
-            base = self._base_snap
-            self.busy_decode_s = g(snap, "busy_decode_s") \
-                - g(base, "busy_decode_s")
-            self.busy_compute_s = g(snap, "busy_compute_s") \
-                - g(base, "busy_compute_s")
-            self.busy_encode_s = g(snap, "busy_encode_s") \
-                - g(base, "busy_encode_s")
+                self._depth_max = max(self._depth_max, (
+                    g(snap, "depth_sum") - g(prev, "depth_sum")) / dc)
             self.epoch = int(g(snap, "epoch"))
-            if dn != 0:
+            if g(snap, "n") != g(prev, "n"):
                 self._progress_n = int(g(snap, "n"))
                 self._progress_at = self._hb_at
 
@@ -377,45 +360,24 @@ class WorkerHandle:
         # instead of round-tripping a reset (windowing stays exact)
         with self._stats_lock:
             self._base_snap = self._last_snap
-            self.traces = []
-            self.queue_depths = []
-            self.busy_decode_s = 0.0
-            self.busy_compute_s = 0.0
-            self.busy_encode_s = 0.0
+            self._depth_max = 0.0
 
     def snapshot(self) -> dict:
-        """Window telemetry (same keys as ComputeNode.snapshot), rebuilt
-        from the last heartbeat relative to the reset baseline."""
+        """Window telemetry (same keys as ComputeNode.snapshot): every
+        running total of the last heartbeat less the reset baseline, and
+        the means derived from those deltas."""
         with self._stats_lock:
             last, base = self._last_snap, self._base_snap
-
-            def d(k: str):
-                return (last.get(k, 0) or 0) - (base.get(k, 0) or 0)
-
-            waves = d("waves")
-            depth_count = d("depth_count")
-            return {
-                "node": self.index, "replica": self.replica,
-                "n": d("n"), "compute_s": d("compute_s"),
-                "serialize_s": d("serialize_s"),
-                "deserialize_s": d("deserialize_s"),
-                "payload_bytes": d("payload_bytes"),
-                "encodes": d("encodes"),
-                "busy_decode_s": self.busy_decode_s,
-                "busy_compute_s": self.busy_compute_s,
-                "busy_encode_s": self.busy_encode_s,
-                "queue_depth_mean": (d("depth_sum") / depth_count
-                                     if depth_count else 0.0),
-                "batch_mean": (d("n") / waves if waves else 0.0),
-                "waves": waves,
-                "depth_sum": d("depth_sum"),
-                "depth_count": depth_count,
-                "max_batch": self._max_batch,
-                "coalesce_s": self._coalesce_s,
-                "epoch": self.epoch,
-                # a gauge, not a window counter: report it as-is
-                "inflight_n": last.get("inflight_n", 0) or 0,
-            }
+            totals = {k: (last.get(k, 0) or 0) - (base.get(k, 0) or 0)
+                      for k in self._zero}
+            snap = stage_view(totals)
+            snap["depth_max"] = self._depth_max
+            snap.update(node=self.index, replica=self.replica,
+                        max_batch=self._max_batch,
+                        coalesce_s=self._coalesce_s, epoch=self.epoch,
+                        # a gauge, not a window counter: report it as-is
+                        inflight_n=last.get("inflight_n", 0) or 0)
+            return snap
 
     def kill_links(self) -> None:
         """Sever both data channels (the router's ``probe_members`` then

@@ -156,6 +156,12 @@ K_STEP = 2
 K_CLOSE = 3
 
 
+def first_id(extents: list[RowExtent]) -> int:
+    """The first request id of a batch (-1 for none): what a span's
+    annotation carries to follow a wave across threads."""
+    return extents[0].request_id if extents else -1
+
+
 @dataclasses.dataclass
 class BatchEnvelope:
     """A whole continuous batch on the wire: ONE encoded stacked payload
@@ -181,6 +187,11 @@ class BatchEnvelope:
     # envelope stamped ahead of its own epoch until the fence barrier
     # completes — no request ever sees a mixed-epoch chain.
     epoch: int = 0
+    # perf_counter stamp of the last in-process enqueue, read at dequeue
+    # for the hand-off's wait (repro.runtime.spans).  Never framed: an
+    # envelope that crossed a process boundary arrives without one.
+    t_enq: float | None = dataclasses.field(default=None, compare=False,
+                                            repr=False)
 
     @property
     def n(self) -> int:

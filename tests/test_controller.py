@@ -221,8 +221,9 @@ def test_pow2_buckets_merge_near_miss_shapes():
         assert out.shape == x.shape            # trimmed back, not padded
         ref = np.asarray(g.apply(params, jnp.asarray(x)))
         np.testing.assert_allclose(out, ref, atol=1e-5)
-    merged = max(node0.traces, key=lambda t: t.n)
-    assert merged.n == 2 and merged.encodes == 1   # one bucket, one pass
+    snap = node0.snapshot()
+    assert snap["n"] == 2 and snap["waves"] == 1
+    assert snap["encodes"] == 1                    # one bucket, one pass
 
 
 def test_exact_buckets_keep_shapes_separate():
@@ -241,7 +242,8 @@ def test_exact_buckets_keep_shapes_separate():
     for f in futs:
         f.result(timeout=60)
     eng.shutdown()
-    assert all(t.encodes == t.n or t.n == 1 for t in node0.traces)
+    snap = node0.snapshot()
+    assert snap["n"] == 2 and snap["encodes"] == snap["n"]
 
 
 # -- live repartition: zero loss, FIFO preserved -----------------------------
@@ -406,8 +408,9 @@ def test_controller_holds_on_balanced_chain():
 
 
 def test_report_raw_utilization_unclamped():
-    """util_*_raw report busy/wall honestly (can exceed the clamped 1.0
-    ceiling); clamped fields stay within [0, 1]."""
+    """util_*_raw report busy/wall honestly, unclamped (they can exceed
+    1.0 on an oversubscribed host): every stage of a replica divides its
+    busy seconds by the same window."""
     g = mlp_graph(6)
     params = g.init(jax.random.PRNGKey(0))
     eng = InferenceEngine(g, 2, RAW, max_batch=4)
@@ -415,8 +418,11 @@ def test_report_raw_utilization_unclamped():
     _, rep = eng.run([sample(i) for i in range(6)])
     eng.shutdown()
     for pn in rep.per_node:
+        walls = set()
         for stage in ("decode", "compute", "encode"):
-            raw, clamped = pn[f"util_{stage}_raw"], pn[f"util_{stage}"]
-            assert raw >= 0.0 and 0.0 <= clamped <= 1.0
-            assert clamped == min(1.0, raw)
+            raw, busy = pn[f"util_{stage}_raw"], pn[f"busy_{stage}_s"]
+            assert busy > 0.0 and raw > 0.0
+            walls.add(round(busy / raw, 9))
+        assert len(walls) == 1
+        assert "utilization" not in pn and "util_compute" not in pn
         assert pn["max_batch"] >= 1 and pn["coalesce_s"] >= 0.0

@@ -152,7 +152,8 @@ def test_clean_shutdown_with_inflight_requests():
 
 def test_continuous_batching_actually_batches():
     """Stall the head node's compute stage, pile requests up, release: the
-    next merge must compute >1 request in one apply (BatchTrace.n > 1), and
+    next merge must compute >1 request in one apply (fewer waves than
+    requests), and
     the staged egress must encode the merged batch in fewer codec passes
     than it has requests (batch-level wire encoding)."""
     g, params, eng = make_engine(num_nodes=2, max_batch=8,
@@ -171,9 +172,11 @@ def test_continuous_batching_actually_batches():
     gate.set()
     outs = [f.result(timeout=60) for f in futs]
     eng.shutdown()
-    big = max(node0.traces, key=lambda t: t.n)
-    assert big.n > 1
-    assert big.encodes < big.n          # one encode per bucket, not per req
+    snap = node0.snapshot()
+    assert snap["n"] == 6
+    assert snap["waves"] < snap["n"]          # some wave merged >1 request
+    assert snap["encodes"] == snap["waves"]   # one encode per bucket, not
+                                              # per request
     for i, out in enumerate(outs):
         ref = np.asarray(g.apply(params, jnp.asarray(sample(i))))
         np.testing.assert_allclose(out, ref, atol=1e-5)
@@ -191,12 +194,12 @@ def test_report_serving_metrics():
     assert rep.samples == 8 and len(outs) == 8
     assert rep.p50_latency_s > 0 and rep.p99_latency_s >= rep.p50_latency_s
     for pn in rep.per_node:
-        for key in ("utilization", "util_decode", "util_compute",
-                    "util_encode"):
+        for key in ("util_decode_raw", "util_compute_raw",
+                    "util_encode_raw"):
             assert 0.0 <= pn[key] <= 1.0
         assert pn["queue_depth_max"] >= 1
         assert pn["batch_mean"] >= 1.0
-    assert any(pn["utilization"] > 0 for pn in rep.per_node)
+    assert any(pn["util_compute_raw"] > 0 for pn in rep.per_node)
 
 
 def test_stage_overlap_observable():
@@ -213,9 +216,10 @@ def test_stage_overlap_observable():
     outs, rep = eng.run([sample(i) for i in range(12)])
     eng.shutdown()
     for node in eng.dispatcher.nodes:
-        assert node.busy_decode_s > 0
-        assert node.busy_compute_s > 0
-        assert node.busy_encode_s > 0
+        snap = node.snapshot()
+        assert snap["busy_decode_s"] > 0
+        assert snap["busy_compute_s"] > 0
+        assert snap["busy_encode_s"] > 0
     assert len(outs) == 12
 
 
@@ -326,8 +330,8 @@ def test_unstaged_mode_parity():
         ref = np.asarray(g.apply(params, jnp.asarray(sample(i))))
         np.testing.assert_allclose(out, ref, atol=1e-5)
     # per-request wire: one encode per request, not per bucket
-    tr = [t for n in eng.dispatcher.nodes for t in n.traces if t.n]
-    assert all(t.encodes == t.n for t in tr if t.encodes)
+    snaps = [n.snapshot() for n in eng.dispatcher.nodes]
+    assert all(s["encodes"] == s["n"] == 8 for s in snaps)
 
 
 # -- per-request deadlines (the reliability layer's reaper) -------------------
