@@ -14,7 +14,7 @@ paper's dispatcher plans partitions before shipping them.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, Mapping, Sequence
+from typing import Any, Callable, Mapping, NamedTuple, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -46,16 +46,31 @@ class LayerDecode:
     ``[B, S, ...]`` and returns ``(y, cache)`` — the cache pytree holds
     everything the layer needs to continue from position ``S`` (e.g.
     K/V buffers of fixed capacity plus a slot-position vector), with a
-    leading batch axis so per-session caches (``B=1``) stack into one
-    decode batch.  ``step_fn(params, cache, x, pos)`` consumes ONE new
-    token per row (``x: [B, 1, ...]``, ``pos: [B] int32`` — rows may sit
-    at *different* sequence positions) and returns ``(y, new_cache)``.
-    Both must be jit-traceable; cache leaves must keep a fixed shape so a
-    stacked decode batch specializes once per batch size, not per step.
+    leading batch axis: a session's caches (``B=1``) become one row of
+    the serving replica's KV slab.  ``step_fn(params, cache, x, pos)``
+    consumes ONE new token per row (``x: [B, 1, ...]``, ``pos: [B]
+    int32`` — rows may sit at *different* sequence positions) and returns
+    ``(y, new_cache)``.  It takes its cache in two forms: a batch of
+    caches (the single-device reference), and a :class:`SlabRows` — the
+    serving replica's whole slab and the wave's slots — from which it
+    reads each row's cache in place, writing only what the step changes
+    and returning ``(y, SlabRows)`` with the new slab; the values are the
+    same either way.  Both functions must be jit-traceable; cache leaves
+    must keep a fixed shape so the slab's rows are the same for every
+    prompt and a decode wave specializes once per batch size.
     """
 
     prefill_fn: Callable[..., Any]         # (params, x) -> (y, cache)
     step_fn: Callable[..., Any]            # (params, cache, x, pos) -> (y, new_cache)
+
+
+class SlabRows(NamedTuple):
+    """A decode wave's rows of a serving replica's KV slab: one layer's
+    slab pytree (every leaf ``[capacity + 1, ...]``) and ``slots`` (int32
+    ``[B]``), the slab row each of the wave's rows continues."""
+
+    slab: Any
+    slots: Any
 
 
 @dataclasses.dataclass

@@ -49,8 +49,11 @@ def quant_bytes(shape, dtype=jnp.bfloat16) -> tuple[int, int]:
 
 # -- decode attention ------------------------------------------------------------
 
-def decode_attention(q, k, v, kpos, pos, window, scale):
-    """q [B,1,H,hd]; k/v [B,C,kv,hd]; kpos [B,C]; pos [B] -> [B,1,H,hd]."""
+def decode_attention(q, k, v, kpos, pos, window, scale, slots=None):
+    """q [B,1,H,hd]; k/v [N,C,kv,hd]; kpos [N,C]; pos [B]; slots [B] (the
+    cache row each query row reads, default its own) -> [B,1,H,hd].  A
+    cache whose C is not a multiple of the kernel's block is padded here,
+    a copy of the whole of it."""
     C = k.shape[1]
     block = min(_da.BLOCK_C, C)
     pad = (-C) % block
@@ -58,7 +61,7 @@ def decode_attention(q, k, v, kpos, pos, window, scale):
         k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
         kpos = jnp.pad(kpos, ((0, 0), (0, pad)), constant_values=-1)
-    return _da.decode_attention(q, k, v, kpos, pos, window, scale,
+    return _da.decode_attention(q, k, v, kpos, pos, window, scale, slots,
                                 block_c=block, interpret=interpret_mode())
 
 

@@ -211,6 +211,18 @@ def decode_attention_ref(q, cache_k, cache_v, kpos, pos, window, scale):
     return _sdpa(q, cache_k, cache_v, valid[:, None, :], scale)
 
 
+def _decode_qkv(p: dict, s: AttnSpec, x: jax.Array, pos: jax.Array,
+                eps: float):
+    """The new token's rotated q [B,1,H,hd] and k, v [B,1,kv,hd]."""
+    B = x.shape[0]
+    h = rmsnorm(p["ln"], x, eps)
+    q = linear(p["wq"], h).reshape(B, 1, s.num_heads, s.head_dim)
+    k = linear(p["wk"], h).reshape(B, 1, s.kv_heads, s.head_dim)
+    v = linear(p["wv"], h).reshape(B, 1, s.kv_heads, s.head_dim)
+    return (apply_rope(q, pos[:, None], s.rope_theta),
+            apply_rope(k, pos[:, None], s.rope_theta), v)
+
+
 def attention_decode(p: dict, s: AttnSpec, x: jax.Array, pos: jax.Array,
                      cache: dict, kpos: jax.Array, eps: float = 1e-5,
                      use_kernel: bool = False):
@@ -220,12 +232,7 @@ def attention_decode(p: dict, s: AttnSpec, x: jax.Array, pos: jax.Array,
     buffers indexed by pos % window.
     """
     B = x.shape[0]
-    h = rmsnorm(p["ln"], x, eps)
-    q = linear(p["wq"], h).reshape(B, 1, s.num_heads, s.head_dim)
-    k = linear(p["wk"], h).reshape(B, 1, s.kv_heads, s.head_dim)
-    v = linear(p["wv"], h).reshape(B, 1, s.kv_heads, s.head_dim)
-    q = apply_rope(q, pos[:, None], s.rope_theta)
-    k = apply_rope(k, pos[:, None], s.rope_theta)
+    q, k, v = _decode_qkv(p, s, x, pos, eps)
 
     C = cache["k"].shape[1]
     slot = (pos % C).astype(jnp.int32)                 # ring for window layers
@@ -256,6 +263,45 @@ def attention_decode(p: dict, s: AttnSpec, x: jax.Array, pos: jax.Array,
         out = decode_attention_ref(q, ck_f, cv_f, nkpos, pos, s.window, scale)
     out = x + linear(p["wo"], out.reshape(B, 1, -1))
     return out, new_cache, nkpos
+
+
+def attention_decode_slots(p: dict, s: AttnSpec, x: jax.Array,
+                           pos: jax.Array, slab: dict, slots: jax.Array,
+                           eps: float = 1e-5, use_kernel: bool = False):
+    """One decode step against many sessions' caches held in one buffer.
+
+    ``slab`` holds ``k``/``v`` ``[N,C,kv,hd]`` and ``kpos`` ``[N,C]`` (f32
+    caches); row ``b`` of ``x [B,1,d]`` continues the session in cache
+    row ``slots[b]``.  Only each row's new position is written, one
+    dynamic update per row in row order (rows that share a slot, such as
+    padding, leave the last row's write): on a TPU, XLA may lay a
+    scatter's operand out anew, converting the whole slab on every step,
+    where a dynamic update keeps it in place.  The kernel reads the rows
+    by slot; the reference path gathers them.  Returns (out, new_slab),
+    with the same values as :func:`attention_decode` on the gathered
+    rows.
+    """
+    B = x.shape[0]
+    q, k, v = _decode_qkv(p, s, x, pos, eps)
+    ck, cv, kp = slab["k"], slab["v"], slab["kpos"]
+    slot = (pos % ck.shape[1]).astype(jnp.int32)     # ring for window layers
+    for i in range(B):
+        ck = jax.lax.dynamic_update_slice(ck, k[i:i + 1],
+                                          (slots[i], slot[i], 0, 0))
+        cv = jax.lax.dynamic_update_slice(cv, v[i:i + 1],
+                                          (slots[i], slot[i], 0, 0))
+        kp = jax.lax.dynamic_update_slice(kp, pos[i:i + 1, None],
+                                          (slots[i], slot[i]))
+    scale = 1.0 / np.sqrt(s.head_dim)
+    if use_kernel:
+        from repro.kernels import ops as kops
+        out = kops.decode_attention(q, ck, cv, kp, pos, s.window, scale,
+                                    slots)
+    else:
+        out = decode_attention_ref(q, ck[slots], cv[slots], kp[slots], pos,
+                                   s.window, scale)
+    out = x + linear(p["wo"], out.reshape(B, 1, -1))
+    return out, {"k": ck, "v": cv, "kpos": kp}
 
 
 def attn_flops(s: AttnSpec, tokens: int, kv_len: int) -> float:

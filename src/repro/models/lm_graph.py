@@ -11,8 +11,10 @@ full sequence.
 
 Greedy decode through the distributed chain is bit-identical to
 :func:`pipeline_decode_reference` below because both run the very same
-``prefill_fn``/``step_fn`` per layer; batching sessions along axis 0 does
-not change per-row arithmetic.
+``prefill_fn``/``step_fn`` per layer — the reference on one session's
+cache, the serving node on the session's row of its KV slab (a
+:class:`~repro.core.graph.SlabRows`), the same values either way;
+batching sessions along axis 0 does not change per-row arithmetic.
 """
 from __future__ import annotations
 
@@ -20,9 +22,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.graph import LayerDecode, LayerGraph
+from repro.core.graph import LayerDecode, LayerGraph, SlabRows
 from repro.models.attention import (AttnSpec, attention, attention_decode,
-                                    attn_flops)
+                                    attention_decode_slots, attn_flops)
 from repro.models.layers import apply_rope, linear, mlp, mlp_flops, rmsnorm
 
 
@@ -53,6 +55,11 @@ def _attn_nodes(spec: AttnSpec, cache_len: int, use_kernel: bool):
         return y, {"k": ck, "v": cv, "kpos": kpos}
 
     def step(p, cache, x, pos):
+        if isinstance(cache, SlabRows):
+            out, slab = attention_decode_slots(p, spec, x, pos, cache.slab,
+                                               cache.slots,
+                                               use_kernel=use_kernel)
+            return out, SlabRows(slab, cache.slots)
         out, kv, kpos = attention_decode(
             p, spec, x, pos, {"k": cache["k"], "v": cache["v"]},
             cache["kpos"], use_kernel=use_kernel)
@@ -68,10 +75,10 @@ def decode_lm_graph(vocab: int = 64, d_model: int = 32, n_layers: int = 2,
     """Build a small decoder-only transformer LayerGraph.
 
     ``cache_len`` is the per-session KV capacity every attention block
-    allocates at prefill — a graph-level constant so per-session caches
-    (leading axis 1) stack into one decode batch with a single jit
-    specialization per batch size.  ``seq_hint`` only sizes the nominal
-    out_specs the partitioner costs cuts with.
+    allocates at prefill — a graph-level constant, so every session's
+    caches (leading axis 1) fit one row of a serving replica's KV slab and
+    a decode wave specializes once per batch size.  ``seq_hint`` only
+    sizes the nominal out_specs the partitioner costs cuts with.
     """
     spec = AttnSpec(d_model=d_model, num_heads=num_heads, kv_heads=kv_heads,
                     head_dim=head_dim)

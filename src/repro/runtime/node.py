@@ -36,13 +36,15 @@ Each replica keeps one set of window running totals (``stats``, a
 :class:`~repro.runtime.spans.Spans` owned as ``stage<i>``): spans where the
 work happens (``deserialize``; ``h2d``, ``apply``, ``d2h`` of a stacked
 batch or a decode step; a session's whole ``prefill``; the decode wave's
-``kv_gather`` and ``kv_scatter``; ``serialize``), the waits at its three
-hand-offs (``inbox`` up to the end of the ingress's coalescing window,
-``compute``, ``egress``) and counters (requests, waves, rows, bytes each
-way).  The engine reports the paper's metrics (compute, overhead,
-payload) plus the serving ones (per-stage utilization, queue depth, batch
-occupancy) from them — and so the codec/compute overlap is directly
-measurable.  ``compute_s`` is ``prefill + apply + d2h``.
+``kv_gather`` (slot lookup and upload) and ``kv_scatter`` (adopting the
+written-back slab); ``serialize``), the waits at its three hand-offs
+(``inbox`` up to the end of the ingress's coalescing window, ``compute``,
+``egress``) and counters (requests, waves, rows, bytes each way, KV slot
+occupancy and evictions).  The engine reports the paper's metrics
+(compute, overhead, payload) plus the serving ones (per-stage
+utilization, queue depth, batch occupancy) from them — and so the
+codec/compute overlap is directly measurable.  ``compute_s`` is
+``prefill + apply + d2h``.
 """
 from __future__ import annotations
 
@@ -57,7 +59,7 @@ from typing import Any
 import jax
 import numpy as np
 
-from repro.core.graph import LayerGraph, LayerNode
+from repro.core.graph import LayerGraph, LayerNode, SlabRows
 from repro.runtime.session import SessionStore
 from repro.runtime.spans import Spans
 from repro.runtime.transport import Channel, ChannelClosed, InprocChannel
@@ -79,16 +81,21 @@ from repro.runtime.wire import (_RETIRE, _STOP, K_CLOSE,  # noqa: F401
 # one replica's running totals (see repro.runtime.spans): spans, waits at
 # its hand-offs, and counters.  ``n`` counts requests computed (extents),
 # ``rows`` the rows they carried, ``step_rows`` decode-step rows; bytes are
-# host<->device copies of activations (a prefill's included) and KV
-# gathered plus scattered.
+# host<->device copies of activations (a prefill's included) and the step
+# waves' KV slab rows, counted once read and once written (the step
+# reads them in place and writes only their new position);
+# ``kv_slots_sum`` is the live slots summed over step waves (occupancy =
+# kv_slots_sum / apply_n) and ``kv_evictions`` the slots LRU reclaimed
+# from a live session.
 def stage_stats(index: int, lock: threading.Lock | None = None) -> Spans:
     return Spans(f"stage{index}",
                  ("deserialize", "h2d", "apply", "d2h", "prefill",
                   "kv_gather", "kv_scatter", "serialize"),
                  ("inbox", "compute", "egress"),
                  ("n", "waves", "rows", "prefills", "step_rows", "h2d_bytes",
-                  "d2h_bytes", "kv_bytes", "payload_bytes", "encodes",
-                  "busy_compute_s", "depth_sum", "depth_count"),
+                  "d2h_bytes", "kv_bytes", "kv_slots_sum", "kv_evictions",
+                  "payload_bytes", "encodes", "busy_compute_s", "depth_sum",
+                  "depth_count"),
                  lock)
 
 
@@ -208,11 +215,17 @@ class ComputeNode:
         self._required: list[str] = []
         self._exported: list[str] = []
         self._apply = None
-        # decode-session state: resident KV caches for sessions pinned to
-        # this replica (LRU-bounded — see SessionStore), plus the jitted
-        # prefill/step applies built only when the graph is decode-capable
+        # decode-session state: the KV slab (per decode layer, one pytree of
+        # the prefill cache's structure with ``session_capacity + 1`` rows:
+        # a slot per resident session and a scratch row for padded wave
+        # rows), allocated at the first open and owned by the compute
+        # thread; the session -> slot map (LRU-bounded, see SessionStore);
+        # the jitted prefill / slot write / step built only when the graph
+        # is decode-capable
         self.sessions = SessionStore(session_capacity)
+        self._slab: dict | None = None
         self._prefill_apply = None
+        self._write_slot = None
         self._decode_apply = None
         self._is_tail = False
         self._threads: list[threading.Thread] = []
@@ -303,10 +316,11 @@ class ComputeNode:
         assert not missing, f"reconfig weights diff is missing {missing}"
         self._params = params
         # the layer slice moved: every resident KV cache is keyed to the
-        # OLD slice and is now meaningless — drop them all.  The dispatcher
-        # displaces every active session at the same fence, so their
-        # generate loops re-prefill instead of stepping into SessionLost.
-        self.sessions.clear()
+        # OLD slice and is now meaningless — drop them all, slab included.
+        # The dispatcher displaces every active session at the same fence,
+        # so their generate loops re-prefill instead of stepping into
+        # SessionLost.
+        self._release_kv()
         self._make_apply()
         self.config_records.append(WireRecord(
             "reconfig", sum(np.asarray(l).nbytes for l in
@@ -333,11 +347,15 @@ class ComputeNode:
 
         # autoregressive view of the same slice: prefill walks the chain
         # once over a full prompt collecting each stateful layer's KV
-        # cache; step consumes one token per row against stacked caches
-        # (rows may sit at different sequence positions).  Only built for
-        # decode-capable graphs — a pure chain, so the slice has exactly
-        # one inbound and one outbound boundary activation.
+        # cache; write_slot parks those caches in one slab row; step
+        # consumes one token per row (rows may sit at different sequence
+        # positions) against the rows' slots of the slab.  The slab is
+        # donated to both, so XLA updates it in place, and the slot is a
+        # traced argument, so one program serves every slot.  Only built
+        # for decode-capable graphs — a pure chain, so the slice has
+        # exactly one inbound and one outbound boundary activation.
         self._prefill_apply = None
+        self._write_slot = None
         self._decode_apply = None
         graph = self._graph
         if (graph is None or not graph.decode_capable or not nodes
@@ -355,20 +373,45 @@ class ComputeNode:
                     acts = node.fn(p, acts)
             return acts, caches
 
-        def step_fn(params, caches, x, pos):
+        def write_fn(slab, caches, slot):
+            return jax.tree_util.tree_map(
+                lambda s, c: jax.lax.dynamic_update_slice_in_dim(s, c, slot,
+                                                                 0),
+                slab, caches)
+
+        def step_fn(params, slab, slots, x, pos):
             acts = x
             new = {}
             for node in nodes:
                 p = params.get(node.name, {})
                 if node.decode is not None:
-                    acts, new[node.name] = node.decode.step_fn(
-                        p, caches[node.name], acts, pos)
+                    acts, rows = node.decode.step_fn(
+                        p, SlabRows(slab[node.name], slots), acts, pos)
+                    new[node.name] = rows.slab
                 else:
                     acts = node.fn(p, acts)
             return acts, new
 
         self._prefill_apply = functools.partial(jax.jit(prefill_fn), params)
-        self._decode_apply = functools.partial(jax.jit(step_fn), params)
+        self._write_slot = jax.jit(write_fn, donate_argnums=0)
+        self._decode_apply = functools.partial(
+            jax.jit(step_fn, donate_argnums=1), params)
+
+    def _release_kv(self) -> None:
+        """Drop every session's residency and the slab with it (a fence,
+        a thread exit, a step or slot write that may have consumed the
+        donated slab): those sessions' next steps fail ``SessionLost``
+        and re-prefill, and the slab's device memory can be freed."""
+        self.sessions.clear()
+        self._slab = None
+
+    def kv_slot_bytes(self) -> int:
+        """Device bytes of one slab row: one session's caches across this
+        slice's decode layers (0 before the first open)."""
+        if self._slab is None:
+            return 0
+        return sum(a.nbytes for a in jax.tree_util.tree_leaves(self._slab)
+                   ) // (self.sessions.capacity + 1)
 
     def precompile(self) -> None:
         """Trace/compile every power-of-two padded batch specialization this
@@ -407,6 +450,32 @@ class ComputeNode:
             outs = {k: np.asarray(v) for k, v in outs.items()}
             blob, _ = self.data_codec.encode_tree(outs, "data")
             self.data_codec.decode_tree(blob)
+        if self._decode_apply is not None:
+            self._precompile_decode()
+
+    def _precompile_decode(self) -> None:
+        """Compile the slot write and the decode step for every wave size
+        the node can form (pow2-padded up to ``max_batch_cap``) from
+        shapes alone: the prefill caches' fixed capacity makes the slab's
+        rows the same for any prompt length, and nothing is allocated or
+        run.  Per-prompt-length prefills still compile at first use."""
+        sds = jax.ShapeDtypeStruct
+        name = self._required[0]
+        spec = (self._graph.input_spec if name == ""
+                else self._graph[name].out_spec)
+        _, caches = jax.eval_shape(self._prefill_apply,
+                                   sds(spec.shape, spec.dtype))
+        n = self.sessions.capacity + 1
+        slab = jax.tree_util.tree_map(
+            lambda c: sds((n,) + c.shape[1:], c.dtype), caches)
+        self._write_slot.lower(slab, caches, sds((), np.int32)).compile()
+        step = self._decode_apply          # partial(jit(step_fn), params)
+        for t in sorted({_bucket_rows(b) if self.pad_batches else b
+                         for b in range(1, self.max_batch_cap + 1)}):
+            rows = sds((t,), np.int32)
+            step.func.lower(*step.args, slab, rows,
+                            sds((t, 1) + spec.shape[2:], spec.dtype),
+                            rows).compile()
 
     # -- inference step (paper §III-C) ----------------------------------------
     def start(self) -> None:
@@ -428,14 +497,15 @@ class ComputeNode:
 
     def _exit_clearing(self, loop):
         """Wrap a replica's final pipeline stage so its exit — stop,
-        retire, drain, or a dead link — releases the resident KV caches:
-        an exited replica serves no further steps, and session recovery
-        is re-prefill elsewhere, so the memory must not linger."""
+        retire, drain, or a dead link — releases the resident KV slab
+        (the compute thread has stopped by then): an exited replica
+        serves no further steps, and session recovery is re-prefill
+        elsewhere, so the memory must not linger."""
         def run():
             try:
                 loop()
             finally:
-                self.sessions.clear()
+                self._release_kv()
         return run
 
     def stop(self) -> None:
@@ -764,19 +834,27 @@ class ComputeNode:
                       ) -> tuple[list, list[BatchEnvelope]]:
         """Serve one merged wave's session traffic (kind != K_PLAIN).
 
-        Closes evict the session's resident caches and pass their payload
-        through untouched (each stage on the way to the tail evicts in
-        turn).  Opens run the slice's prefill individually (B=1 — jit
-        specializes per prompt length) and park the resulting caches in
-        this replica's :class:`SessionStore`; the tail stage trims its
-        output to the last position so only one row of logits ships.
-        Steps batch ACROSS sessions: per-session caches stack along the
-        leading axis, positions ride per row, and ONE jitted step apply
-        serves every session in the wave — continuous batching of decode
-        at *different* sequence positions.  A step whose session has no
-        resident cache here (evicted, repartitioned, replica restarted)
-        fails with a ``SessionLost`` error envelope; recovery is the
-        generate loop's re-prefill, never a replay.
+        A resident session is one slot of this replica's KV slab, named by
+        its :class:`SessionStore`.  Closes free the session's slot and
+        pass their payload through untouched (each stage on the way to the
+        tail frees in turn).  Opens run the slice's prefill individually
+        (B=1 — jit specializes per prompt length) and write the caches
+        into the session's slot with one jitted call that donates the slab
+        (allocated at the replica's first open from those caches' shapes);
+        the tail stage trims its output to the last position so only one
+        row of logits ships.  Steps batch ACROSS sessions: ONE jitted call
+        takes the slab (donated), the wave's slots, tokens and positions
+        and runs the slice's step on those rows: each layer reads its rows
+        in place (``decode_attention`` picks them by slot) and writes only
+        their new position — continuous batching of decode at *different*
+        sequence positions, whose host-side KV work is one small upload of
+        slot indices.  Padding rows read and write the slab's scratch row,
+        never a live slot.  A step whose session holds no slot here (evicted,
+        repartitioned, replica restarted) fails with a ``SessionLost``
+        error envelope; recovery is the generate loop's re-prefill, never
+        a replay.  A slot write or step that raises may have consumed the
+        donated slab, so it drops every session of the replica, and each
+        re-prefills.
 
         Session envelopes carry exactly one extent by protocol (routers
         pin whole envelopes; a multi-session envelope could not route
@@ -788,7 +866,7 @@ class ComputeNode:
         outs: list[tuple[list[RowExtent], dict[str, np.ndarray]]] = []
         failures: list[BatchEnvelope] = []
         out_name = self._exported[0] if self._exported else ""
-        steps: list[tuple[RowExtent, np.ndarray, Any]] = []
+        steps: list[tuple[RowExtent, np.ndarray]] = []
         for d in group:
             if len(d.extents) != 1:
                 failures.append(BatchEnvelope(
@@ -820,70 +898,97 @@ class ComputeNode:
                         failures.append(BatchEnvelope(
                             [e], b"", error=traceback.format_exc()))
                         continue
+                    # a slot even when the slice holds no stateful layer
+                    # (caches == {}): residency doubles as the routing
+                    # check a later step validates against
+                    try:
+                        evicted = self._park(e.session, caches)
+                    except Exception:
+                        self._release_kv()
+                        failures.append(BatchEnvelope(
+                            [e], b"", error=traceback.format_exc()))
+                        continue
                 stats.add(prefills=1, rows=x.shape[0], h2d_bytes=x.nbytes,
-                          d2h_bytes=y.nbytes)
-                # park the caches even when the slice holds no stateful
-                # layer (caches == {}): residency doubles as the routing
-                # check a later step validates against
-                self.sessions.put(e.session, caches)
+                          d2h_bytes=y.nbytes, kv_evictions=int(evicted))
                 if self._is_tail:
                     y = y[:, -1:]
                 outs.append(([e], {out_name: y}))
             elif e.kind == K_STEP:
-                cache = self.sessions.get(e.session)
-                if cache is None:
-                    failures.append(BatchEnvelope([e], b"", error=(
-                        f"SessionLost: stage {self.index} replica "
-                        f"{self.replica} holds no KV cache for session "
-                        f"{e.session!r} (evicted, repartitioned, or the "
-                        "replica restarted); re-open the session from "
-                        "its retained history")))
-                    continue
-                steps.append((e, x, cache))
+                steps.append((e, x))
             else:
                 failures.append(BatchEnvelope(
                     [e], b"",
                     error=f"unknown session frame kind {e.kind}"))
-        if steps:
-            b = len(steps)
-            target = _bucket_rows(b) if self.pad_batches else b
-            # pad the batch by repeating the last row (token, position AND
-            # caches): decode arithmetic is row-independent, so the real
-            # rows are bit-identical to an unpadded apply and the padded
-            # duplicates' outputs/caches are simply dropped
-            rows = steps + [steps[-1]] * (target - b)
-            rid = steps[0][0].request_id
-            with stats.span("h2d", rid=rid, rows=b):
-                xs = jax.numpy.asarray(
-                    np.concatenate([x for _, x, _ in rows], axis=0))
-                pos = jax.numpy.asarray(
-                    np.asarray([e.pos for e, _, _ in rows], np.int32))
-            with stats.span("kv_gather", rid=rid, rows=b):
-                caches = jax.tree_util.tree_map(
-                    lambda *leaves: jax.numpy.concatenate(leaves, axis=0),
-                    *[c for _, _, c in rows])
-            try:
-                with stats.span("apply", rid=rid, rows=b):
-                    y, new = self._decode_apply(caches, xs, pos)
-                with stats.span("d2h", rid=rid, rows=b):    # as in _stack_apply
-                    y = np.asarray(y)
-            except Exception:
-                tb = traceback.format_exc()
-                failures.extend(BatchEnvelope([e], b"", error=tb)
-                                for e, _, _ in steps)
+        if not steps:
+            return outs, failures
+        rid = steps[0][0].request_id
+        with stats.span("kv_gather", rid=rid, rows=len(steps)):
+            live: list[tuple[RowExtent, np.ndarray, int]] = []
+            for e, x in steps:
+                slot = self.sessions.get(e.session)
+                if slot is None:
+                    failures.append(BatchEnvelope([e], b"", error=(
+                        f"SessionLost: stage {self.index} replica "
+                        f"{self.replica} holds no KV slot for session "
+                        f"{e.session!r} (evicted, repartitioned, or the "
+                        "replica restarted); re-open the session from "
+                        "its retained history")))
+                else:
+                    live.append((e, x, slot))
+            if not live:
                 return outs, failures
-            with stats.span("kv_scatter", rid=rid, rows=b):
-                for i, (e, _, _) in enumerate(steps):
-                    self.sessions.put(e.session, jax.tree_util.tree_map(
-                        lambda a, i=i: a[i:i + 1], new))
-            # leaves' nbytes are metadata: no sync.  Gathered: the whole
-            # stacked cache; scattered: one row of it per session
-            kv = sum(a.nbytes for a in jax.tree_util.tree_leaves(caches))
-            stats.add(rows=b, step_rows=b, h2d_bytes=xs.nbytes + pos.nbytes,
-                      d2h_bytes=y.nbytes, kv_bytes=kv + kv * b // target)
-            for i, (e, _, _) in enumerate(steps):
-                outs.append(([e], {out_name: y[i:i + 1]}))
+            b = len(live)
+            target = _bucket_rows(b) if self.pad_batches else b
+            slots = jax.numpy.asarray(np.asarray(
+                [s for _, _, s in live]
+                + [self.sessions.capacity] * (target - b), np.int32))
+        # padding rows repeat the last row's token and position against the
+        # scratch row: decode arithmetic is row-independent, so the real
+        # rows are bit-identical to an unpadded apply
+        rows = live + [live[-1]] * (target - b)
+        with stats.span("h2d", rid=rid, rows=b):
+            xs = jax.numpy.asarray(
+                np.concatenate([x for _, x, _ in rows], axis=0))
+            pos = jax.numpy.asarray(
+                np.asarray([e.pos for e, _, _ in rows], np.int32))
+        try:
+            with stats.span("apply", rid=rid, rows=b):
+                y, slab = self._decode_apply(self._slab, slots, xs, pos)
+            with stats.span("d2h", rid=rid, rows=b):    # as in _stack_apply
+                y = np.asarray(y)
+        except Exception:
+            # the call may have consumed the donated slab: no step may run
+            # on it again, so every session here re-prefills
+            self._release_kv()
+            tb = traceback.format_exc()
+            failures.extend(BatchEnvelope([e], b"", error=tb)
+                            for e, _, _ in live)
+            return outs, failures
+        with stats.span("kv_scatter", rid=rid, rows=b):
+            self._slab = slab
+            resident = len(self.sessions)
+        # device bytes: every row's slot, counted read and written
+        stats.add(rows=b, step_rows=b, h2d_bytes=xs.nbytes + pos.nbytes,
+                  d2h_bytes=y.nbytes,
+                  kv_bytes=2 * target * self.kv_slot_bytes(),
+                  kv_slots_sum=resident)
+        for i, (e, _, _) in enumerate(live):
+            outs.append(([e], {out_name: y[i:i + 1]}))
         return outs, failures
+
+    def _park(self, session: Any, caches: Any) -> bool:
+        """Write a session's prefill caches into its slot, allocating the
+        slab at the replica's first open (the caches' fixed capacity makes
+        every prompt length give the same rows).  Returns whether LRU
+        evicted a live session for the slot."""
+        if self._slab is None:
+            n = self.sessions.capacity + 1
+            self._slab = jax.tree_util.tree_map(
+                lambda c: jax.numpy.zeros((n,) + c.shape[1:], c.dtype),
+                caches)
+        slot, evicted = self.sessions.claim(session)
+        self._slab = self._write_slot(self._slab, caches, np.int32(slot))
+        return evicted
 
     # -- stage 3: egress (encode once per bucket, relay) ----------------------
     def _relay(self, item: Any) -> None:

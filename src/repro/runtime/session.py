@@ -2,8 +2,9 @@
 
 A decode *session* is one autoregressive generation: a prompt is prefilled
 ONCE through the chain (``kind=K_OPEN``, full ``[1, S]`` token frame), every
-attention layer's KV cache stays RESIDENT on the replica that computed it,
-and each subsequent step ships only the newest token (``kind=K_STEP``,
+attention layer's KV cache stays RESIDENT on the replica that computed it —
+in one slot of that replica's device-resident KV slab — and each
+subsequent step ships only the newest token (``kind=K_STEP``,
 ``[1, 1]`` — plus its sequence position in the extent header), not the
 growing sequence.  The per-hop payload is therefore O(d_model), independent
 of how long the sequence has grown — the whole point of distributing decode.
@@ -11,12 +12,15 @@ of how long the sequence has grown — the whole point of distributing decode.
 Residency makes replicas stateful, which this module pays for in three
 places:
 
-* :class:`SessionStore` — the per-replica cache map (LRU-bounded so a
-  leaked session cannot pin memory forever; an evicted session is NOT an
-  error, its next step fails with ``SessionLost`` and the generate loop
-  re-prefills).  Every live store registers in a module-level WeakSet so
-  the test harness can assert session-keyed state is actually evicted on
-  session end (the per-client-GC precedent from the admission merge).
+* :class:`SessionStore` — the per-replica residency map: session id -> slot
+  of the replica's KV slab (one device-resident buffer per decode layer,
+  ``capacity + 1`` rows, owned by the compute node).  LRU-bounded, so a
+  leaked session cannot pin a slot forever: a new session past capacity
+  takes the least recently stepped session's slot.  An evicted session is
+  NOT an error, its next step fails with ``SessionLost`` and the generate
+  loop re-prefills.  Every live store registers in a module-level WeakSet
+  so the test harness can assert session-keyed state is actually evicted
+  on session end (the per-client-GC precedent from the admission merge).
 * sticky routing — the stage routers pin a session to the replica holding
   its cache (:mod:`repro.runtime.router`); this module only *names* the
   session in each submit.
@@ -66,51 +70,87 @@ class SessionLost(RuntimeError):
 
 
 class SessionStore:
-    """Per-replica resident KV caches, keyed by session id.
+    """Per-replica KV residency: session id -> slot of the replica's slab.
 
-    LRU-bounded: inserting past ``capacity`` evicts the least-recently
-    *stepped* session.  Eviction is safe by protocol — the evicted
-    session's next step gets a ``SessionLost`` error envelope and its
-    generate loop re-prefills — so capacity is a memory ceiling, not a
-    correctness knob.  All methods are thread-safe (the compute stage
-    writes; fences and thread exits clear)."""
+    Slots are ``0 .. capacity - 1``; the slab's extra row ``capacity`` is
+    the node's scratch row for padded wave rows, never a session's.
+    :meth:`claim` gives a new session a free slot or, at capacity, the
+    least-recently *stepped* session's slot (that session is evicted).
+    Eviction is safe by protocol — the evicted session's next step gets a
+    ``SessionLost`` error envelope and its generate loop re-prefills — so
+    capacity is not a correctness knob; it is a reservation of device
+    memory: the node allocates the slab's ``capacity + 1`` rows, each one
+    session's caches, at the replica's first open, however few sessions
+    then live (ROADMAP speed 2 leaves budgeting the slab by bytes open).
+    All methods are thread-safe (the compute stage claims and steps;
+    fences and thread exits clear)."""
 
     def __init__(self, capacity: int = 64):
         self.capacity = max(1, int(capacity))
         self._lock = threading.Lock()
-        self._caches: OrderedDict[Any, Any] = OrderedDict()
+        self._slots: OrderedDict[Any, int] = OrderedDict()
         _LIVE_STORES.add(self)
 
-    def put(self, session: Any, cache: Any) -> None:
-        with self._lock:
-            self._caches.pop(session, None)
-            self._caches[session] = cache
-            while len(self._caches) > self.capacity:
-                self._caches.popitem(last=False)
+    def _assign(self, session: Any, slot: int) -> Any | None:
+        # the lock is held: make ``slot`` the session's, most recent, and
+        # past capacity evict the least recent session, returning its id
+        self._slots.pop(session, None)
+        self._slots[session] = slot
+        if len(self._slots) > self.capacity:
+            return self._slots.popitem(last=False)[0]
+        return None
 
-    def get(self, session: Any) -> Any | None:
-        """Fetch a session's caches (refreshing its LRU slot), or None."""
+    def put(self, session: Any, slot: int) -> Any | None:
+        """Make ``slot`` the session's, most recent; past capacity the
+        least recent session is evicted.  Returns the evicted session's
+        id, or None.  A compute node takes its slots from :meth:`claim`
+        alone; this refuses a negative slot or one another session
+        holds, which would share a slab row."""
         with self._lock:
-            cache = self._caches.get(session)
-            if cache is not None:
-                self._caches.move_to_end(session)
-            return cache
+            if slot < 0 or any(s == slot and k != session
+                               for k, s in self._slots.items()):
+                raise ValueError(f"slot {slot} is not free for {session!r}")
+            return self._assign(session, slot)
 
-    def pop(self, session: Any) -> Any | None:
+    def claim(self, session: Any) -> tuple[int, bool]:
+        """The slot a session's (re-)open writes: its own if resident,
+        else the lowest free one, else the least recent session's, which
+        is evicted.  Returns ``(slot, evicted)``."""
         with self._lock:
-            return self._caches.pop(session, None)
+            slot = self._slots.get(session)
+            if slot is None:
+                if len(self._slots) < self.capacity:
+                    used = set(self._slots.values())
+                    slot = next(s for s in range(self.capacity)
+                                if s not in used)
+                else:
+                    slot = next(iter(self._slots.values()))
+            return slot, self._assign(session, slot) is not None
+
+    def get(self, session: Any) -> int | None:
+        """A session's slot (refreshing its LRU position), or None."""
+        with self._lock:
+            slot = self._slots.get(session)
+            if slot is not None:
+                self._slots.move_to_end(session)
+            return slot
+
+    def pop(self, session: Any) -> int | None:
+        """Free a session's slot (``K_CLOSE``); returns it, or None."""
+        with self._lock:
+            return self._slots.pop(session, None)
 
     def clear(self) -> None:
         with self._lock:
-            self._caches.clear()
+            self._slots.clear()
 
     def __len__(self) -> int:
         with self._lock:
-            return len(self._caches)
+            return len(self._slots)
 
     def keys(self) -> list[Any]:
         with self._lock:
-            return list(self._caches)
+            return list(self._slots)
 
 
 def generate_tokens(dispatcher, prompt: Sequence[int],
@@ -141,7 +181,7 @@ def generate_tokens(dispatcher, prompt: Sequence[int],
 
     The generator's ``finally`` closes the session: it unregisters from
     the dispatcher and sends a best-effort ``K_CLOSE`` frame down the
-    chain so every stage evicts its caches promptly (LRU would get them
+    chain so every stage frees its slot promptly (LRU would reclaim it
     eventually; close keeps the stores tight — and lets the test
     harness assert eviction on session end).
     """

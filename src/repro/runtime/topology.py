@@ -47,9 +47,12 @@ class StageSpec:
     / ``shape_buckets`` / ``max_batch_cap`` override the engine-wide
     defaults for this stage only (None = inherit).
 
-    ``session_capacity`` bounds each replica's resident decode-session KV
-    caches (LRU eviction past it — an evicted session re-prefills, so this
-    is a memory ceiling, not a correctness knob; None = runtime default).
+    ``session_capacity`` bounds each replica's resident decode sessions
+    (LRU eviction past it — an evicted session re-prefills, so this is
+    not a correctness knob) and reserves device memory: the replica's KV
+    slab of ``session_capacity + 1`` rows, each one session's caches, is
+    allocated at its first open, however few sessions then live (ROADMAP
+    speed 2: budgeting it by bytes is open).  None = runtime default.
     """
 
     layers: tuple[int, int]                 # [lo, hi) over graph.nodes

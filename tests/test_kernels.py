@@ -69,6 +69,30 @@ def test_decode_attention_sweep(B, H, kv, hd, C, window, dtype):
                                np.asarray(expect, np.float32), atol=tol)
 
 
+@pytest.mark.parametrize("H,kv,hd", [(8, 2, 64), (4, 4, 128)])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_decode_attention_reads_rows_by_slot(H, kv, hd, dtype):
+    """Query rows pick their cache rows out of a larger buffer by slot
+    (repeated slots included), as a serving replica's KV slab is read:
+    the same result as the oracle on the gathered rows."""
+    N, C = 5, 1024
+    slots = jnp.asarray([3, 0, 4, 4], jnp.int32)
+    q = rand((4, 1, H, hd), dtype)
+    k = rand((N, C, kv, hd), dtype)
+    v = rand((N, C, kv, hd), dtype)
+    fill = jnp.asarray(RNG.integers(1, C, (N, 1)))
+    kpos = jnp.where(jnp.arange(C)[None] < fill, jnp.arange(C)[None], -1
+                     ).astype(jnp.int32)
+    pos = jnp.asarray([700, 20, 1000, 1000], jnp.int32)
+    scale = 1.0 / np.sqrt(hd)
+    out = ops.decode_attention(q, k, v, kpos, pos, None, scale, slots)
+    expect = ref.decode_attention_ref(q, k[slots], v[slots], kpos[slots],
+                                      pos, None, scale)
+    tol = 2e-2 if dtype == jnp.bfloat16 else 1e-5
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(expect, np.float32), atol=tol)
+
+
 def test_decode_attention_masks_everything_empty():
     """All-empty cache: softmax denominator guard must not NaN."""
     B, H, kv, hd, C = 1, 2, 2, 64, 128

@@ -78,9 +78,9 @@ def test_oneshot_chain_totals_match_the_traffic():
 
 def test_decode_chain_totals_match_the_traffic():
     """One session through a 2-stage decoder: one prefill per stage, a
-    step row per further token, the KV cache gathered and scattered once
-    per step, bytes each way from the shapes, and the client's ``next``
-    spans (an argmax per token, a submit per step)."""
+    step row per further token, the session's slab slot gathered and
+    written back once per step, bytes each way from the shapes, and the
+    client's ``next`` spans (an argmax per token, a submit per step)."""
     g = lm_graph()
     eng = InferenceEngine(g, TopologySpec.chain(g, 2), RAW, max_batch=4)
     eng.configure(g.init(jax.random.PRNGKey(0)))
@@ -90,8 +90,7 @@ def test_decode_chain_totals_match_the_traffic():
     eng.reset_window()
     it = eng.generate(prompt, m, session_id="s0")
     toks = [next(it)]
-    kv = [sum(a.nbytes for a in jax.tree_util.tree_leaves(
-        n.sessions.get("s0"))) for n in nodes]
+    kv = [n.kv_slot_bytes() for n in nodes]     # one slot of each slab
     toks += list(it)
     rep = eng.report()
     eng.shutdown()

@@ -94,3 +94,77 @@ def test_ssd_scan_compiles(one_chip):
         ((B, nc, Q, H), jnp.float32), ((H,), jnp.float32),
         ((B, nc, Q, N), jnp.bfloat16), ((B, nc, Q, N), jnp.bfloat16),
         ((B, H, P, N), jnp.float32))
+
+
+def _slab_programs(one_chip, monkeypatch):
+    """A compute node's slot write and 8-row decode step, compiled at
+    starcoder2-3b's KV widths (2 KV heads of 128, 4096 positions, 65 slab
+    rows) with the ``decode_attention`` kernel, and the slab's bytes."""
+    import numpy as np
+
+    from repro.kernels import ops
+    from repro.models.lm_graph import decode_lm_graph
+    from repro.runtime.node import ComputeNode
+    from repro.runtime.wire import WireCodec
+
+    monkeypatch.setattr(ops, "interpret_mode", lambda: False)
+    g = decode_lm_graph(vocab=256, d_model=3072, n_layers=1, num_heads=24,
+                        kv_heads=2, head_dim=128, d_ff=256, cache_len=4096,
+                        use_kernel=True)
+    node = ComputeNode(0, WireCodec("raw", "none"), session_capacity=64)
+    node._graph = g
+    node._set_range(0, len(g.nodes))
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
+                                                 sharding=one_chip)
+    node._params = {n.name: jax.tree_util.tree_map(
+        lambda s: sds(s.shape, s.dtype), n.param_spec) for n in g.nodes}
+    node._make_apply()
+    _, caches = jax.eval_shape(node._prefill_apply,
+                               jax.ShapeDtypeStruct((1, 8), jnp.int32))
+    caches = jax.tree_util.tree_map(lambda c: sds(c.shape, c.dtype), caches)
+    slab = jax.tree_util.tree_map(
+        lambda c: sds((65,) + c.shape[1:], c.dtype), caches)
+    slab_bytes = sum(np.prod(a.shape) * a.dtype.itemsize
+                     for a in jax.tree_util.tree_leaves(slab))
+    step, rows = node._decode_apply, sds((8,), jnp.int32)
+    write = node._write_slot.lower(slab, caches, sds((), jnp.int32))
+    step = step.func.lower(*step.args, slab, rows, sds((8, 1), jnp.int32),
+                           rows)
+    return write.compile(), step.compile(), slab_bytes
+
+
+def test_decode_step_keeps_the_kv_slab_in_place(one_chip, monkeypatch):
+    """The donated slab aliases its output in the slot write and the step,
+    and every op of the slab's shape keeps the slab's one layout, so no
+    program converts (copies) the whole slab."""
+    import re
+
+    write, step, slab_bytes = _slab_programs(one_chip, monkeypatch)
+    for compiled, kernel in ((write, False), (step, True)):
+        text = compiled.as_text()
+        assert ("tpu_custom_call" in text) == kernel
+        # aliased bytes count the tiles' padding too
+        assert compiled.memory_analysis().alias_size_in_bytes >= slab_bytes
+        full = re.findall(r"= \(?f32\[65,4096,2,128\]\{([^}]*)\} ([a-z-]+)\(",
+                          text)
+        assert full and len({layout for layout, _ in full}) == 1, full
+        assert "copy" not in {op for _, op in full}
+
+
+def test_decode_kernel_reads_the_wave_rows_in_the_slab(one_chip,
+                                                       monkeypatch):
+    """The step hands ``decode_attention`` the slab itself, as a free
+    reshape, and the kernel picks the wave's rows by slot: no op of the
+    step gathers the 8 rows' caches, in any layout, so all the KV the
+    kernel reads is read inside the kernel."""
+    import re
+
+    _, step, _ = _slab_programs(one_chip, monkeypatch)
+    text = step.as_text()
+    call = re.search(r"%decode_attention[.\d]* = .*", text)
+    assert call and "f32[65,8192,128]" in call.group(0)
+    ops = {op for op in re.findall(
+        r"= \(?f32\[65,8192,128\]\{[^}]*\} ([a-z-]+)\(", text)}
+    assert ops == {"bitcast"}, ops
+    rows = re.findall(r"f32\[8,(?:4096,2,128|2,4096,128|8192,128)\]", text)
+    assert not rows, rows
