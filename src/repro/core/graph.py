@@ -40,7 +40,8 @@ def tree_params(tree: Any) -> int:
 
 @dataclasses.dataclass
 class LayerDecode:
-    """Autoregressive view of a stateful layer (attention with a KV cache).
+    """Autoregressive view of a stateful layer (attention with a KV cache,
+    or a recurrent layer with a fixed-size state).
 
     ``prefill_fn(params, x)`` runs the layer over a full prompt
     ``[B, S, ...]`` and returns ``(y, cache)`` — the cache pytree holds
@@ -62,6 +63,11 @@ class LayerDecode:
 
     prefill_fn: Callable[..., Any]         # (params, x) -> (y, cache)
     step_fn: Callable[..., Any]            # (params, cache, x, pos) -> (y, new_cache)
+    # True for a fixed-size recurrent state that every step reads and
+    # writes whole (Mamba2's conv and SSD state), False for a KV cache a
+    # step appends one position to; the serving replica counts the two
+    # kinds' bytes apart
+    recurrent: bool = False
 
 
 class SlabRows(NamedTuple):

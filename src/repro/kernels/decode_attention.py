@@ -15,6 +15,11 @@ KV slab.  The
 position-validity mask (ring-buffer slots, window) is computed from the
 ``kpos`` sidecar, so sliding-window ring caches need no host-side compaction.
 
+A cache of heads narrower than the 128 lanes is kept packed instead,
+``[N, C, kv*hd]`` (:func:`decode_attention_packed`): a block is
+``(BLOCK_C, kv*hd)``, one position a row, and each query head is widened
+to ``kv*hd`` lanes, zero outside its own KV head's.
+
 Block shape: (BLOCK_C * KV, head_dim) with BLOCK_C=512 — 512x2x128 f32 =
 512 kB per K and V block, double-buffered well inside VMEM; the H x
 BLOCK_C*KV logits tile computes KV times the logits a head needs (the rest
@@ -57,11 +62,16 @@ def _decode_attn_kernel(slots_ref, pos_ref, q_ref, k_ref, v_ref, kpos_ref,
     valid = (kpos >= 0) & (delta >= 0)
     if window is not None:
         valid &= delta < window
-    # cache row j holds KV head j % kv; query head i reads KV head i // g
-    shape = logits.shape
-    head = jax.lax.broadcasted_iota(jnp.int32, shape, 1) % kv
-    group = jax.lax.broadcasted_iota(jnp.int32, shape, 0) // g
-    logits = jnp.where(valid & (head == group), logits, NEG_INF)
+    if kv is None:
+        # packed rows: each position's KV heads side by side in one row,
+        # each query head zero outside its own KV head's lanes
+        logits = jnp.where(valid, logits, NEG_INF)
+    else:
+        # cache row j holds KV head j % kv; query head i reads KV head i // g
+        shape = logits.shape
+        head = jax.lax.broadcasted_iota(jnp.int32, shape, 1) % kv
+        group = jax.lax.broadcasted_iota(jnp.int32, shape, 0) // g
+        logits = jnp.where(valid & (head == group), logits, NEG_INF)
 
     m_prev = m_ref[...]                                    # [H, 1]
     m_cur = jnp.maximum(m_prev, logits.max(axis=1, keepdims=True))
@@ -76,6 +86,58 @@ def _decode_attn_kernel(slots_ref, pos_ref, q_ref, k_ref, v_ref, kpos_ref,
     def _done():
         denom = jnp.maximum(l_ref[...], 1e-30)
         o_ref[0] = (acc_ref[...] / denom).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("window", "scale", "block_c",
+                                             "interpret"))
+def decode_attention_packed(q, k, v, kpos, pos, window, scale, slots,
+                            block_c: int = BLOCK_C, interpret: bool = False):
+    """:func:`decode_attention` over packed caches: q [B,1,H,hd]; k/v
+    [N,C,kv*hd] (each position's KV heads side by side, as a cache of
+    heads narrower than 128 lanes is kept); kpos [N,C]; pos, slots [B]
+    -> [B,1,H,hd].  The kernel reads blocks ``[block_c, kv*hd]`` of the
+    rows by slot, the cache's own layout; each query head is widened to
+    ``kv*hd`` lanes, zero outside its KV head's, and its output is read
+    back from those lanes."""
+    B, _, H, hd = q.shape
+    N, C, width = k.shape
+    kv = width // hd
+    g = H // kv
+    block_c = min(block_c, C)
+    assert C % block_c == 0, f"cache len {C} % block {block_c} != 0"
+    blocks = C // block_c
+    own = (jnp.arange(H)[:, None] // g) == jnp.arange(kv)[None, :]  # [H,kv]
+    qw = jnp.where(own[None, :, :, None], q.reshape(B, H, 1, hd), 0.0)
+    kp = kpos[slots][:, None, :]                           # [B, 1, C]
+
+    kernel = functools.partial(_decode_attn_kernel, scale=scale,
+                               window=window, blocks=blocks, kv=None, g=None)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,                           # slots, pos
+            grid=(B, blocks),
+            in_specs=[
+                pl.BlockSpec((1, H, width), lambda b, c, s, p: (b, 0, 0)),
+                pl.BlockSpec((None, block_c, width),
+                             lambda b, c, s, p: (s[b], c, 0)),
+                pl.BlockSpec((None, block_c, width),
+                             lambda b, c, s, p: (s[b], c, 0)),
+                pl.BlockSpec((1, 1, block_c), lambda b, c, s, p: (b, 0, c)),
+            ],
+            out_specs=pl.BlockSpec((1, H, width),
+                                   lambda b, c, s, p: (b, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((H, 1), jnp.float32),     # running max
+                pltpu.VMEM((H, 1), jnp.float32),     # running denom
+                pltpu.VMEM((H, width), jnp.float32),  # weighted-value acc
+            ]),
+        out_shape=jax.ShapeDtypeStruct((B, H, width), q.dtype),
+        interpret=interpret,
+    )(slots.astype(jnp.int32), pos.astype(jnp.int32),
+      qw.reshape(B, H, width).astype(q.dtype), k, v, kp)
+    out = jnp.where(own[None, :, :, None], out.reshape(B, H, kv, hd), 0.0)
+    return out.sum(axis=2).reshape(B, 1, H, hd)
 
 
 @functools.partial(jax.jit,

@@ -16,6 +16,7 @@ from repro.kernels import block_quant as _bq
 from repro.kernels import decode_attention as _da
 from repro.kernels import interpret_mode
 from repro.kernels import ssd_scan as _ssd
+from repro.kernels import ssm_step as _ssm
 
 
 # -- block quantization (wire compression for the DEFER pipeline) ---------------
@@ -50,19 +51,35 @@ def quant_bytes(shape, dtype=jnp.bfloat16) -> tuple[int, int]:
 # -- decode attention ------------------------------------------------------------
 
 def decode_attention(q, k, v, kpos, pos, window, scale, slots=None):
-    """q [B,1,H,hd]; k/v [N,C,kv,hd]; kpos [N,C]; pos [B]; slots [B] (the
-    cache row each query row reads, default its own) -> [B,1,H,hd].  A
-    cache whose C is not a multiple of the kernel's block is padded here,
-    a copy of the whole of it."""
+    """q [B,1,H,hd]; k/v [N,C,kv,hd], or packed [N,C,kv*hd] (read by
+    slot: ``slots`` required); kpos [N,C]; pos [B]; slots [B] (the cache
+    row each query row reads, default its own) -> [B,1,H,hd].  A cache
+    whose C is not a multiple of the kernel's block is padded here, a
+    copy of the whole of it."""
     C = k.shape[1]
     block = min(_da.BLOCK_C, C)
     pad = (-C) % block
     if pad:
-        k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        widths = ((0, 0), (0, pad)) + ((0, 0),) * (k.ndim - 2)
+        k = jnp.pad(k, widths)
+        v = jnp.pad(v, widths)
         kpos = jnp.pad(kpos, ((0, 0), (0, pad)), constant_values=-1)
+    if k.ndim == 3:
+        return _da.decode_attention_packed(q, k, v, kpos, pos, window, scale,
+                                           slots, block_c=block,
+                                           interpret=interpret_mode())
     return _da.decode_attention(q, k, v, kpos, pos, window, scale, slots,
                                 block_c=block, interpret=interpret_mode())
+
+
+# -- Mamba2 decode step --------------------------------------------------------
+
+def ssm_step(state, slots, x, dt, A, B, C):
+    """One Mamba2 step of a decode wave against the state rows ``slots`` of
+    a slab ``state [R,H,P,N]`` -> (y [Bw,H,P] without the skip term, new
+    state), the slab aliased to the new state (see ``ssm_step.py``)."""
+    return _ssm.ssm_step(state, slots, x, dt, A, B, C,
+                         interpret=interpret_mode())
 
 
 # -- SSD scan ----------------------------------------------------------------------

@@ -29,6 +29,24 @@ class AttnSpec:
     window: int | None = None        # sliding window (tokens), None = global
     causal: bool = True
     q_chunk: int = 1024              # chunking for memory-bounded attention
+    rope: bool = True                # False: no position embedding ("nope")
+    scale: float | None = None       # softmax scale; None = 1/sqrt(head_dim)
+    residual: float = 1.0            # multiplier of the block's output on
+                                     # the residual stream
+
+    @property
+    def softmax_scale(self) -> float:
+        return 1.0 / np.sqrt(self.head_dim) if self.scale is None \
+            else self.scale
+
+
+def _rope(s: AttnSpec, x, positions):
+    return apply_rope(x, positions, s.rope_theta) if s.rope else x
+
+
+def _residual(s: AttnSpec, x, y):
+    """``x + y`` on the residual stream, ``y`` scaled by ``s.residual``."""
+    return x + (y if s.residual == 1.0 else s.residual * y)
 
 
 def init_attn(key, s: AttnSpec, dtype) -> dict:
@@ -47,9 +65,7 @@ def _project_qkv(p, s: AttnSpec, x, positions):
     q = linear(p["wq"], x).reshape(B, S, s.num_heads, s.head_dim)
     k = linear(p["wk"], x).reshape(B, S, s.kv_heads, s.head_dim)
     v = linear(p["wv"], x).reshape(B, S, s.kv_heads, s.head_dim)
-    q = apply_rope(q, positions, s.rope_theta)
-    k = apply_rope(k, positions, s.rope_theta)
-    return q, k, v
+    return _rope(s, q, positions), _rope(s, k, positions), v
 
 
 def _sdpa(q, k, v, mask, scale):
@@ -76,22 +92,31 @@ def attention(p: dict, s: AttnSpec, x: jax.Array, positions: jax.Array,
     B, S, _ = x.shape
     h = rmsnorm(p["ln"], x, eps)
     q, k, v = _project_qkv(p, s, h, positions)
-    scale = 1.0 / np.sqrt(s.head_dim)
+    scale = s.softmax_scale
 
+    banded = s.window is not None and s.window < S
     C = min(s.q_chunk, S)
-    if S % C != 0:  # small/smoke shapes: single chunk
-        C = S
-    nq = S // C
+    pad = 0
+    if S % C != 0:
+        if banded:          # the band's key chunks need S % C == 0
+            C = S
+        else:               # pad the queries (their rows are dropped),
+            pad = C - S % C     # so live logits stay [B,H,C,S]
+    nq = (S + pad) // C
+    pos_q = positions if positions.ndim == 2 else \
+        jnp.broadcast_to(positions[None], (B, S))
+    if pad:
+        q = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        pos_q = jnp.pad(pos_q, ((0, 0), (0, pad)), mode="edge")
     qs = q.reshape(B, nq, C, s.num_heads, s.head_dim)
-    pos_q = positions.reshape(B, nq, C) if positions.ndim == 2 else \
-        jnp.broadcast_to(positions.reshape(nq, C)[None], (B, nq, C))
+    pos_q = pos_q.reshape(B, nq, C)
 
-    if s.window is not None and s.window < S:
+    if banded:
         out = _banded_attention(qs, k, v, pos_q, positions, s, scale, C)
     else:
         out = _chunked_attention(qs, k, v, pos_q, positions, s, scale, C)
-    out = out.reshape(B, S, s.num_heads * s.head_dim)
-    return x + linear(p["wo"], out)
+    out = out.reshape(B, S + pad, s.num_heads * s.head_dim)[:, :S]
+    return _residual(s, x, linear(p["wo"], out))
 
 
 def _chunked_attention(qs, k, v, pos_q, pos_k, s, scale, C):
@@ -162,8 +187,8 @@ def cross_attention(p: dict, s: AttnSpec, x: jax.Array, enc: jax.Array,
     v = linear(p["wv"], enc).reshape(B, Se, s.kv_heads, s.head_dim)
     mask = jnp.ones((B, S, Se), bool) if enc_mask is None else \
         jnp.broadcast_to(enc_mask[:, None, :], (B, S, Se))
-    out = _sdpa(q, k, v, mask, 1.0 / np.sqrt(s.head_dim))
-    return x + linear(p["wo"], out.reshape(B, S, -1))
+    out = _sdpa(q, k, v, mask, s.softmax_scale)
+    return _residual(s, x, linear(p["wo"], out.reshape(B, S, -1)))
 
 
 # -- cached decode ----------------------------------------------------------------
@@ -219,8 +244,7 @@ def _decode_qkv(p: dict, s: AttnSpec, x: jax.Array, pos: jax.Array,
     q = linear(p["wq"], h).reshape(B, 1, s.num_heads, s.head_dim)
     k = linear(p["wk"], h).reshape(B, 1, s.kv_heads, s.head_dim)
     v = linear(p["wv"], h).reshape(B, 1, s.kv_heads, s.head_dim)
-    return (apply_rope(q, pos[:, None], s.rope_theta),
-            apply_rope(k, pos[:, None], s.rope_theta), v)
+    return _rope(s, q, pos[:, None]), _rope(s, k, pos[:, None]), v
 
 
 def attention_decode(p: dict, s: AttnSpec, x: jax.Array, pos: jax.Array,
@@ -255,13 +279,13 @@ def attention_decode(p: dict, s: AttnSpec, x: jax.Array, pos: jax.Array,
         cv_f = cv = cache["v"].at[bidx, slot].set(v[:, 0])
         new_cache = {"k": ck, "v": cv}
 
-    scale = 1.0 / np.sqrt(s.head_dim)
+    scale = s.softmax_scale
     if use_kernel:
         from repro.kernels import ops as kops
         out = kops.decode_attention(q, ck_f, cv_f, nkpos, pos, s.window, scale)
     else:
         out = decode_attention_ref(q, ck_f, cv_f, nkpos, pos, s.window, scale)
-    out = x + linear(p["wo"], out.reshape(B, 1, -1))
+    out = _residual(s, x, linear(p["wo"], out.reshape(B, 1, -1)))
     return out, new_cache, nkpos
 
 
@@ -270,7 +294,8 @@ def attention_decode_slots(p: dict, s: AttnSpec, x: jax.Array,
                            eps: float = 1e-5, use_kernel: bool = False):
     """One decode step against many sessions' caches held in one buffer.
 
-    ``slab`` holds ``k``/``v`` ``[N,C,kv,hd]`` and ``kpos`` ``[N,C]`` (f32
+    ``slab`` holds ``k``/``v`` ``[N,C,kv,hd]``, or packed as
+    ``[N,C,kv*hd]`` (:func:`packed_cache`), and ``kpos`` ``[N,C]`` (f32
     caches); row ``b`` of ``x [B,1,d]`` continues the session in cache
     row ``slots[b]``.  Only each row's new position is written, one
     dynamic update per row in row order (rows that share a slot, such as
@@ -284,24 +309,41 @@ def attention_decode_slots(p: dict, s: AttnSpec, x: jax.Array,
     B = x.shape[0]
     q, k, v = _decode_qkv(p, s, x, pos, eps)
     ck, cv, kp = slab["k"], slab["v"], slab["kpos"]
+    packed = ck.ndim == 3
+    if packed:
+        k = k.reshape(B, 1, -1)
+        v = v.reshape(B, 1, -1)
+    at = (0,) * (ck.ndim - 2)
     slot = (pos % ck.shape[1]).astype(jnp.int32)     # ring for window layers
     for i in range(B):
         ck = jax.lax.dynamic_update_slice(ck, k[i:i + 1],
-                                          (slots[i], slot[i], 0, 0))
+                                          (slots[i], slot[i]) + at)
         cv = jax.lax.dynamic_update_slice(cv, v[i:i + 1],
-                                          (slots[i], slot[i], 0, 0))
+                                          (slots[i], slot[i]) + at)
         kp = jax.lax.dynamic_update_slice(kp, pos[i:i + 1, None],
                                           (slots[i], slot[i]))
-    scale = 1.0 / np.sqrt(s.head_dim)
+    scale = s.softmax_scale
     if use_kernel:
         from repro.kernels import ops as kops
         out = kops.decode_attention(q, ck, cv, kp, pos, s.window, scale,
                                     slots)
     else:
-        out = decode_attention_ref(q, ck[slots], cv[slots], kp[slots], pos,
+        rows = (B, ck.shape[1], s.kv_heads, s.head_dim)
+        out = decode_attention_ref(q, ck[slots].reshape(rows),
+                                   cv[slots].reshape(rows), kp[slots], pos,
                                    s.window, scale)
-    out = x + linear(p["wo"], out.reshape(B, 1, -1))
+    out = _residual(s, x, linear(p["wo"], out.reshape(B, 1, -1)))
     return out, {"k": ck, "v": cv, "kpos": kp}
+
+
+def packed_cache(s: AttnSpec) -> bool:
+    """Whether a decode cache holds each position's K (and V) heads as one
+    ``kv * head_dim`` row, ``[B, C, kv*hd]``, rather than ``[B, C, kv,
+    hd]``: where a head is narrower than the TPU's 128 lanes, the 4-D
+    form would pad every head to 128 lanes (twice the memory and reads
+    at 64), and the ``decode_attention`` kernel's view of it could not
+    be had without a copy of the whole cache."""
+    return s.head_dim < 128
 
 
 def attn_flops(s: AttnSpec, tokens: int, kv_len: int) -> float:

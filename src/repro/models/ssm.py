@@ -150,24 +150,35 @@ def ssd_chunked(x, dt, A, B_mat, C_mat, chunk: int, init_state=None,
     return y, final
 
 
-def mamba_block(p: dict, s: MambaSpec, x: jax.Array, eps: float = 1e-5,
-                use_kernel: bool = False) -> jax.Array:
-    """Full Mamba2 block (train/prefill).  x [B,S,d] -> [B,S,d]."""
+def mamba_mixer(p: dict, s: MambaSpec, x: jax.Array, eps: float = 1e-5,
+                use_kernel: bool = False):
+    """The Mamba2 mixer over a whole sequence, without the block's residual
+    add (train/prefill).  x [B,S,d] -> (out [B,S,d], cache): ``cache``
+    holds what a decode continues from position S, the conv's last
+    ``w - 1`` inputs ``conv [B,w-1,ch]`` and the state ``ssd
+    [B,H,P,N]`` f32."""
     B, S, _ = x.shape
     di, N, H, P = s.d_inner, s.ssm.state_dim, s.n_heads, s.ssm.head_dim
     h = rmsnorm(p["ln"], x, eps)
     z, xBC, dt_raw = _split_proj(s, h @ p["in_proj"])
-    xBC, _ = _causal_conv(xBC, p["conv_w"], p["conv_b"])
+    xBC, conv = _causal_conv(xBC, p["conv_w"], p["conv_b"])
     xs = xBC[..., :di].reshape(B, S, H, P)
     Bm = xBC[..., di:di + N]
     Cm = xBC[..., di + N:]
     dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + p["dt_bias"])
     A = -jnp.exp(p["A_log"])
-    y, _ = ssd_chunked(xs, dt, A, Bm, Cm, s.ssm.chunk, use_kernel=use_kernel)
+    y, ssd = ssd_chunked(xs, dt, A, Bm, Cm, s.ssm.chunk,
+                         use_kernel=use_kernel)
     y = y + xs * p["D"].astype(x.dtype)[None, None, :, None]
     y = y.reshape(B, S, di)
     y = rmsnorm(p["norm"], y * jax.nn.silu(z), eps)
-    return x + y @ p["out_proj"]
+    return y @ p["out_proj"], {"conv": conv, "ssd": ssd}
+
+
+def mamba_block(p: dict, s: MambaSpec, x: jax.Array, eps: float = 1e-5,
+                use_kernel: bool = False) -> jax.Array:
+    """Full Mamba2 block (train/prefill).  x [B,S,d] -> [B,S,d]."""
+    return x + mamba_mixer(p, s, x, eps, use_kernel)[0]
 
 
 # -- decode -------------------------------------------------------------------
@@ -180,27 +191,86 @@ def init_mamba_cache(s: MambaSpec, batch: int, dtype) -> dict:
     }
 
 
-def mamba_decode(p: dict, s: MambaSpec, x: jax.Array, cache: dict,
-                 eps: float = 1e-5):
-    """One token.  x [B,1,d] -> ([B,1,d], new_cache).  O(1) in history."""
+def _step_inputs(p: dict, s: MambaSpec, x: jax.Array, conv: jax.Array,
+                 eps: float):
+    """One token's projections: (z, x [B,H,P], dt [B,H], A [H], B and C
+    [B,N] f32, the new conv state)."""
     B = x.shape[0]
     di, N, H, P = s.d_inner, s.ssm.state_dim, s.n_heads, s.ssm.head_dim
     h = rmsnorm(p["ln"], x, eps)
     z, xBC, dt_raw = _split_proj(s, h @ p["in_proj"])
-    xBC, new_conv = _causal_conv(xBC, p["conv_w"], p["conv_b"], cache["conv"])
+    xBC, new_conv = _causal_conv(xBC, p["conv_w"], p["conv_b"], conv)
     xs = xBC[:, 0, :di].reshape(B, H, P)
     Bm = xBC[:, 0, di:di + N].astype(jnp.float32)
     Cm = xBC[:, 0, di + N:].astype(jnp.float32)
     dt = jax.nn.softplus(dt_raw[:, 0].astype(jnp.float32) + p["dt_bias"])  # [B,H]
     A = -jnp.exp(p["A_log"])
+    return z, xs, dt, A, Bm, Cm, new_conv
+
+
+def _ssd_update(state, xs, dt, A, Bm, Cm):
+    """The recurrence for one token: (y [B,H,P] = S C, new S [B,H,P,N])."""
     a = jnp.exp(dt * A)                                            # [B,H]
-    S_new = cache["ssd"] * a[:, :, None, None] + jnp.einsum(
+    S_new = state * a[:, :, None, None] + jnp.einsum(
         "bhp,bn->bhpn", xs.astype(jnp.float32) * dt[..., None], Bm)
-    y = jnp.einsum("bhpn,bn->bhp", S_new, Cm)
+    return jnp.einsum("bhpn,bn->bhp", S_new, Cm), S_new
+
+
+def _step_output(p: dict, s: MambaSpec, x, z, xs, y, eps: float):
+    """The skip term, the gated norm and the output projection."""
     y = y + xs.astype(jnp.float32) * p["D"][None, :, None]
-    y = y.reshape(B, 1, di).astype(x.dtype)
+    y = y.reshape(x.shape[0], 1, s.d_inner).astype(x.dtype)
     y = rmsnorm(p["norm"], y * jax.nn.silu(z), eps)
-    return x + y @ p["out_proj"], {"conv": new_conv, "ssd": S_new}
+    return y @ p["out_proj"]
+
+
+def mamba_mixer_step(p: dict, s: MambaSpec, x: jax.Array, cache: dict,
+                     eps: float = 1e-5):
+    """The mixer for one token, without the residual add.  x [B,1,d] ->
+    (out [B,1,d], new_cache).  O(1) in history."""
+    z, xs, dt, A, Bm, Cm, conv = _step_inputs(p, s, x, cache["conv"], eps)
+    y, S_new = _ssd_update(cache["ssd"], xs, dt, A, Bm, Cm)
+    return _step_output(p, s, x, z, xs, y, eps), {"conv": conv, "ssd": S_new}
+
+
+def mamba_decode(p: dict, s: MambaSpec, x: jax.Array, cache: dict,
+                 eps: float = 1e-5):
+    """One token.  x [B,1,d] -> ([B,1,d], new_cache).  O(1) in history."""
+    out, cache = mamba_mixer_step(p, s, x, cache, eps)
+    return x + out, cache
+
+
+def mamba_mixer_slots(p: dict, s: MambaSpec, x: jax.Array, slab: dict,
+                      slots: jax.Array, eps: float = 1e-5,
+                      use_kernel: bool = False):
+    """:func:`mamba_mixer_step` against many sessions' states held in one
+    buffer: ``slab`` holds ``conv [R,w-1,ch]`` and ``ssd [R,H,P,N]``; row
+    ``b`` of ``x [B,1,d]`` continues the session in slab row
+    ``slots[b]``.  The conv rows move by one dynamic slice and one dynamic
+    update each, in row order (rows that share a slot, such as padding,
+    leave the last row's write), so XLA keeps the slab in its layout;
+    with ``use_kernel`` the ``ssm_step`` kernel reads and writes the
+    state rows in place by slot, else they are gathered and written back
+    as the conv rows are.  Returns (out, new_slab), with the same values
+    as :func:`mamba_mixer_step` on the gathered rows."""
+    B = x.shape[0]
+    conv, ssd = slab["conv"], slab["ssd"]
+    rows = jnp.concatenate([jax.lax.dynamic_slice_in_dim(conv, slots[i], 1)
+                            for i in range(B)])
+    z, xs, dt, A, Bm, Cm, new_conv = _step_inputs(p, s, x, rows, eps)
+    if use_kernel:
+        from repro.kernels import ops as kops
+        y, ssd = kops.ssm_step(ssd, slots, xs, dt, A, Bm, Cm)
+    else:
+        y, states = _ssd_update(ssd[slots], xs, dt, A, Bm, Cm)
+        for i in range(B):
+            ssd = jax.lax.dynamic_update_slice_in_dim(ssd, states[i:i + 1],
+                                                      slots[i], 0)
+    for i in range(B):
+        conv = jax.lax.dynamic_update_slice_in_dim(conv, new_conv[i:i + 1],
+                                                   slots[i], 0)
+    return (_step_output(p, s, x, z, xs, y, eps),
+            {"conv": conv, "ssd": ssd})
 
 
 def mamba_flops(s: MambaSpec, tokens: int) -> float:

@@ -42,6 +42,7 @@ from collections import defaultdict, deque
 from concurrent.futures import Future, InvalidStateError
 from typing import Any, Iterable, Sequence
 
+import jax
 import numpy as np
 
 from repro.core.graph import LayerGraph
@@ -429,8 +430,10 @@ class Dispatcher:
         per replica (each replica holds the full stage)."""
         # the dispatcher owns the full model (paper setting): retained so a
         # live repartition can ship the weight DIFF of shifted layers only,
-        # and so scale() can configure freshly spawned replicas
-        self._params = params
+        # and so scale() can configure freshly spawned replicas.  It keeps
+        # them on the host: the wire ships bytes anyway, and a device copy
+        # beside the replicas' own would hold the weights twice on the chip
+        self._params = jax.device_get(params)
         for group, (lo, hi) in zip(self.stages, self.partition.ranges()):
             arch_blob, weights_blob = self._stage_blobs(group.index, lo, hi)
             for node in group.replicas:

@@ -54,6 +54,7 @@ import queue
 import threading
 import time
 import traceback
+import weakref
 from typing import Any
 
 import jax
@@ -79,24 +80,73 @@ from repro.runtime.wire import (_RETIRE, _STOP, K_CLOSE,  # noqa: F401
 
 
 # one replica's running totals (see repro.runtime.spans): spans, waits at
-# its hand-offs, and counters.  ``n`` counts requests computed (extents),
-# ``rows`` the rows they carried, ``step_rows`` decode-step rows; bytes are
-# host<->device copies of activations (a prefill's included) and the step
-# waves' KV slab rows, counted once read and once written (the step
-# reads them in place and writes only their new position);
-# ``kv_slots_sum`` is the live slots summed over step waves (occupancy =
-# kv_slots_sum / apply_n) and ``kv_evictions`` the slots LRU reclaimed
-# from a live session.
+# its hand-offs, counters and gauges.  ``n`` counts requests computed
+# (extents), ``rows`` the rows they carried, ``step_rows`` decode-step
+# rows; bytes are host<->device copies of activations (a prefill's
+# included) and the step waves' slab rows, each counted once read and once
+# written: ``kv_bytes`` those of attention KV caches (the step reads them
+# in place and writes only their new position), ``state_bytes`` those of
+# recurrent (Mamba2) state, which the step reads and writes whole — both
+# definitions, not measurements; ``kv_slots_sum`` is the live slots summed
+# over step waves (occupancy = kv_slots_sum / apply_n) and
+# ``kv_evictions`` the slots LRU reclaimed from a live session.  The
+# gauges ``slab_rows`` and ``slab_bytes`` are the slab the replica holds
+# (0 before its first open), the byte budget's outcome.
 def stage_stats(index: int, lock: threading.Lock | None = None) -> Spans:
     return Spans(f"stage{index}",
                  ("deserialize", "h2d", "apply", "d2h", "prefill",
                   "kv_gather", "kv_scatter", "serialize"),
                  ("inbox", "compute", "egress"),
                  ("n", "waves", "rows", "prefills", "step_rows", "h2d_bytes",
-                  "d2h_bytes", "kv_bytes", "kv_slots_sum", "kv_evictions",
-                  "payload_bytes", "encodes", "busy_compute_s", "depth_sum",
-                  "depth_count"),
-                 lock)
+                  "d2h_bytes", "kv_bytes", "state_bytes", "kv_slots_sum",
+                  "kv_evictions", "payload_bytes", "encodes",
+                  "busy_compute_s", "depth_sum", "depth_count"),
+                 lock, gauges=("slab_rows", "slab_bytes"))
+
+
+# The share of a device's free memory (its ``bytes_limit`` less
+# ``bytes_in_use`` and the slabs other replicas on it have sized but not
+# yet allocated) that a replica's slab may take when its rows are sized:
+# the rest stays free for prefill temporaries, which grow with the prompt.
+SLAB_MEMORY_SHARE = 0.25
+_SLAB_LOCK = threading.Lock()
+# replicas whose slab is sized but not allocated -> (device, bytes)
+_SLAB_PENDING: "weakref.WeakKeyDictionary[Any, tuple[Any, int]]" = \
+    weakref.WeakKeyDictionary()
+
+
+class SlabBudgetError(MemoryError):
+    """Even the smallest slab a replica can serve its waves from does not
+    fit its share of the device's free memory."""
+
+
+def free_memory(device) -> int | None:
+    """A device's free bytes by its own figures (``bytes_limit`` less
+    ``bytes_in_use``), or None where its backend keeps none (the CPU)."""
+    stats = device.memory_stats() if device is not None else None
+    if not stats or "bytes_limit" not in stats:
+        return None
+    return stats["bytes_limit"] - stats["bytes_in_use"]
+
+
+def slab_rows(capacity: int, min_rows: int, row_bytes: int,
+              free_bytes: int | None) -> int:
+    """A replica's slab rows: ``capacity + 1`` (a slot per resident
+    session and the scratch row) where :data:`SLAB_MEMORY_SHARE` of
+    ``free_bytes`` holds them, else as many as it holds, never fewer than
+    ``min_rows`` (a full wave's sessions and the scratch row).  With no
+    memory figure (``free_bytes`` None: a backend without memory stats)
+    ``capacity + 1``."""
+    if free_bytes is None:
+        return capacity + 1
+    fit = int(SLAB_MEMORY_SHARE * max(0, free_bytes)) // max(1, row_bytes)
+    rows = min(capacity + 1, fit)
+    if rows < min_rows:
+        raise SlabBudgetError(
+            f"a slab of {min_rows} rows of {row_bytes} bytes (a full wave "
+            f"and the scratch row) exceeds {SLAB_MEMORY_SHARE:.0%} of the "
+            f"device's {free_bytes} free bytes")
+    return rows
 
 
 def stage_view(totals: dict) -> dict:
@@ -215,15 +265,19 @@ class ComputeNode:
         self._required: list[str] = []
         self._exported: list[str] = []
         self._apply = None
-        # decode-session state: the KV slab (per decode layer, one pytree of
-        # the prefill cache's structure with ``session_capacity + 1`` rows:
-        # a slot per resident session and a scratch row for padded wave
-        # rows), allocated at the first open and owned by the compute
+        # decode-session state: the slab (per decode layer, one pytree of
+        # the prefill cache's structure — KV caches and recurrent states —
+        # with ``session_capacity + 1`` rows, fewer where the byte budget
+        # says so: a slot per resident session and a scratch row for padded
+        # wave rows), allocated at the first open and owned by the compute
         # thread; the session -> slot map (LRU-bounded, see SessionStore);
         # the jitted prefill / slot write / step built only when the graph
         # is decode-capable
         self.sessions = SessionStore(session_capacity)
+        self._capacity = self.sessions.capacity     # as configured
         self._slab: dict | None = None
+        self._slab_rows: int | None = None          # sized, for this slice
+        self._row_bytes = (0, 0)        # one slab row: (KV, recurrent state)
         self._prefill_apply = None
         self._write_slot = None
         self._decode_apply = None
@@ -357,20 +411,31 @@ class ComputeNode:
         self._prefill_apply = None
         self._write_slot = None
         self._decode_apply = None
+        self._unsize_slab()
         graph = self._graph
         if (graph is None or not graph.decode_capable or not nodes
                 or len(self._required) != 1 or len(exported) != 1):
             return
 
+        # the tail ships one position of logits per open, so past the
+        # slice's last stateful layer, where only token-wise layers
+        # follow, its prefill keeps the last position alone: the head then
+        # computes one row of logits, not one per prompt token
+        last = max((i for i, n in enumerate(nodes) if n.decode is not None),
+                   default=-1)
+        trim = self._is_tail and all(n.pad_safe for n in nodes[last + 1:])
+
         def prefill_fn(params, x):
-            acts = x
+            acts = x[:, -1:] if trim and last < 0 else x
             caches = {}
-            for node in nodes:
+            for i, node in enumerate(nodes):
                 p = params.get(node.name, {})
                 if node.decode is not None:
                     acts, caches[node.name] = node.decode.prefill_fn(p, acts)
                 else:
                     acts = node.fn(p, acts)
+                if trim and i == last:
+                    acts = acts[:, -1:]
             return acts, caches
 
         def write_fn(slab, caches, slot):
@@ -404,14 +469,52 @@ class ComputeNode:
         and re-prefill, and the slab's device memory can be freed."""
         self.sessions.clear()
         self._slab = None
+        self.stats.set(slab_rows=0, slab_bytes=0)
 
     def kv_slot_bytes(self) -> int:
-        """Device bytes of one slab row: one session's caches across this
-        slice's decode layers (0 before the first open)."""
-        if self._slab is None:
-            return 0
-        return sum(a.nbytes for a in jax.tree_util.tree_leaves(self._slab)
-                   ) // (self.sessions.capacity + 1)
+        """Device bytes of one slab row: one session's caches and states
+        across this slice's decode layers (0 before they are sized)."""
+        return sum(self._row_bytes)
+
+    def _size_slab(self, caches: Any) -> int:
+        """The slab's rows for this slice, sized once from one session's
+        ``caches`` (arrays or shapes, leading axis 1) and the device's
+        memory at the time (:func:`slab_rows`); the session store's
+        capacity follows, a slot per row but the scratch row."""
+        if self._slab_rows is not None:
+            return self._slab_rows
+        nbytes = lambda tree: sum(
+            int(np.prod(a.shape)) * np.dtype(a.dtype).itemsize
+            for a in jax.tree_util.tree_leaves(tree))
+        state = {n.name for n in self._nodes
+                 if n.decode is not None and n.decode.recurrent}
+        self._row_bytes = (
+            nbytes({k: v for k, v in caches.items() if k not in state}),
+            nbytes({k: v for k, v in caches.items() if k in state}))
+        row = sum(self._row_bytes)
+        leaves = jax.tree_util.tree_leaves(self._params or {})
+        device = None
+        if leaves and isinstance(leaves[0], jax.Array):
+            device = next(iter(leaves[0].devices()))   # where the slab goes
+        with _SLAB_LOCK:
+            free = free_memory(device)
+            if free is not None:
+                free -= sum(b for node, (d, b) in _SLAB_PENDING.items()
+                            if node is not self and d == device)
+            rows = slab_rows(self._capacity, self.max_batch_cap + 1, row,
+                             free)
+            _SLAB_PENDING[self] = (device, rows * row)
+        self._slab_rows = rows
+        self.sessions.capacity = rows - 1
+        return rows
+
+    def _unsize_slab(self) -> None:
+        """Forget the slab's size (a new slice sizes its own)."""
+        with _SLAB_LOCK:
+            _SLAB_PENDING.pop(self, None)
+        self._slab_rows = None
+        self._row_bytes = (0, 0)
+        self.sessions.capacity = self._capacity
 
     def precompile(self) -> None:
         """Trace/compile every power-of-two padded batch specialization this
@@ -465,7 +568,7 @@ class ComputeNode:
                 else self._graph[name].out_spec)
         _, caches = jax.eval_shape(self._prefill_apply,
                                    sds(spec.shape, spec.dtype))
-        n = self.sessions.capacity + 1
+        n = self._size_slab(caches)
         slab = jax.tree_util.tree_map(
             lambda c: sds((n,) + c.shape[1:], c.dtype), caches)
         self._write_slot.lower(slab, caches, sds((), np.int32)).compile()
@@ -968,10 +1071,10 @@ class ComputeNode:
             self._slab = slab
             resident = len(self.sessions)
         # device bytes: every row's slot, counted read and written
+        kv_row, state_row = self._row_bytes
         stats.add(rows=b, step_rows=b, h2d_bytes=xs.nbytes + pos.nbytes,
-                  d2h_bytes=y.nbytes,
-                  kv_bytes=2 * target * self.kv_slot_bytes(),
-                  kv_slots_sum=resident)
+                  d2h_bytes=y.nbytes, kv_bytes=2 * target * kv_row,
+                  state_bytes=2 * target * state_row, kv_slots_sum=resident)
         for i, (e, _, _) in enumerate(live):
             outs.append(([e], {out_name: y[i:i + 1]}))
         return outs, failures
@@ -979,13 +1082,17 @@ class ComputeNode:
     def _park(self, session: Any, caches: Any) -> bool:
         """Write a session's prefill caches into its slot, allocating the
         slab at the replica's first open (the caches' fixed capacity makes
-        every prompt length give the same rows).  Returns whether LRU
-        evicted a live session for the slot."""
+        every prompt length give the same rows; their number is budgeted
+        by bytes, :meth:`_size_slab`).  Returns whether LRU evicted a
+        live session for the slot."""
         if self._slab is None:
-            n = self.sessions.capacity + 1
+            n = self._size_slab(caches)
             self._slab = jax.tree_util.tree_map(
                 lambda c: jax.numpy.zeros((n,) + c.shape[1:], c.dtype),
                 caches)
+            with _SLAB_LOCK:
+                _SLAB_PENDING.pop(self, None)
+            self.stats.set(slab_rows=n, slab_bytes=n * self.kv_slot_bytes())
         slot, evicted = self.sessions.claim(session)
         self._slab = self._write_slot(self._slab, caches, np.int32(slot))
         return evicted

@@ -81,7 +81,8 @@ class SessionStore:
     capacity is not a correctness knob; it is a reservation of device
     memory: the node allocates the slab's ``capacity + 1`` rows, each one
     session's caches, at the replica's first open, however few sessions
-    then live (ROADMAP speed 2 leaves budgeting the slab by bytes open).
+    then live; where the device's memory holds fewer rows the node lowers
+    ``capacity`` to match (the slab budgeted by bytes).
     All methods are thread-safe (the compute stage claims and steps;
     fences and thread exits clear)."""
 

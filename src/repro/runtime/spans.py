@@ -19,6 +19,8 @@ owner already uses for its stats.
   that crossed a process boundary carries no stamp; its wait is missing,
   not zero.
 * A **counter** is a plain running sum (rows, bytes, waves).
+* A **gauge** is a value set, not summed, that the window's reset keeps:
+  what the owner holds rather than what it did (a slab's rows and bytes).
 """
 from __future__ import annotations
 
@@ -32,7 +34,8 @@ _tracing = TraceAnnotation.is_enabled
 
 class Spans:
     def __init__(self, owner: str, spans: tuple = (), waits: tuple = (),
-                 counters: tuple = (), lock: threading.Lock | None = None):
+                 counters: tuple = (), lock: threading.Lock | None = None,
+                 gauges: tuple = ()):
         # (annotation name, seconds key, count key): formatted once here
         self._spans = {w: (f"defer.{owner}.{w}", f"{w}_s", f"{w}_n")
                        for w in spans}
@@ -40,7 +43,8 @@ class Spans:
         self.lock = lock if lock is not None else threading.Lock()
         keys = [k for v in self._spans.values() for k in v[1:]]
         keys += [k for v in self._waits.values() for k in v]
-        self.zero = dict.fromkeys(keys + list(counters), 0)
+        self.gauges = tuple(gauges)
+        self.zero = dict.fromkeys(keys + list(counters) + list(gauges), 0)
         self.totals = dict(self.zero)
 
     def span(self, what: str, **meta) -> "_Span":
@@ -69,9 +73,15 @@ class Spans:
             self.totals[ks] += dt
             self.totals[kn] += n
 
+    def set(self, **values) -> None:
+        """Set gauges."""
+        with self.lock:
+            self.totals.update(values)
+
     def reset(self) -> None:
         with self.lock:
-            self.totals = dict(self.zero)
+            self.totals = {**self.zero,
+                           **{g: self.totals[g] for g in self.gauges}}
 
     def snapshot(self) -> dict:
         with self.lock:

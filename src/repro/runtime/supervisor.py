@@ -147,7 +147,8 @@ class WorkerHandle:
         # engine report and the controller read a worker exactly like an
         # in-process node
         self._stats_lock = threading.Lock()
-        self._zero = stage_stats(stage).zero
+        zero = stage_stats(stage)
+        self._zero, self._gauges = zero.zero, zero.gauges
         self._depth_max = 0.0
         self.config_records: list = []
         self._nodes: list = []
@@ -370,6 +371,8 @@ class WorkerHandle:
             last, base = self._last_snap, self._base_snap
             totals = {k: (last.get(k, 0) or 0) - (base.get(k, 0) or 0)
                       for k in self._zero}
+            # gauges are what the worker holds now, not window deltas
+            totals.update({g: last.get(g, 0) or 0 for g in self._gauges})
             snap = stage_view(totals)
             snap["depth_max"] = self._depth_max
             snap.update(node=self.index, replica=self.replica,

@@ -49,10 +49,12 @@ class StageSpec:
 
     ``session_capacity`` bounds each replica's resident decode sessions
     (LRU eviction past it — an evicted session re-prefills, so this is
-    not a correctness knob) and reserves device memory: the replica's KV
-    slab of ``session_capacity + 1`` rows, each one session's caches, is
-    allocated at its first open, however few sessions then live (ROADMAP
-    speed 2: budgeting it by bytes is open).  None = runtime default.
+    not a correctness knob) and reserves device memory: the replica's
+    slab of ``session_capacity + 1`` rows, each one session's KV caches
+    and recurrent states, is allocated at its first open, however few
+    sessions then live — fewer rows where a quarter of the device's free
+    memory cannot hold them, and the capacity falls with them (see
+    :func:`repro.runtime.node.slab_rows`).  None = runtime default.
     """
 
     layers: tuple[int, int]                 # [lo, hi) over graph.nodes

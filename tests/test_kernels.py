@@ -93,6 +93,28 @@ def test_decode_attention_reads_rows_by_slot(H, kv, hd, dtype):
                                np.asarray(expect, np.float32), atol=tol)
 
 
+@pytest.mark.parametrize("H,kv,hd,window", [(32, 8, 64, None), (8, 2, 64, 300),
+                                             (4, 4, 32, None)])
+def test_packed_decode_attention_reads_rows_by_slot(H, kv, hd, window):
+    """Packed caches ``[N, C, kv*hd]`` (heads narrower than the lanes, as
+    Granite-4.0-H's 8 KV heads of 64 are kept), read by slot with repeated
+    slots: the oracle's result on the gathered rows, unpacked."""
+    N, C = 5, 1024
+    slots = jnp.asarray([3, 0, 4, 4], jnp.int32)
+    q = rand((4, 1, H, hd), jnp.float32)
+    k = rand((N, C, kv, hd), jnp.float32)
+    v = rand((N, C, kv, hd), jnp.float32)
+    fill = jnp.asarray(RNG.integers(1, C, (N, 1)))
+    kpos = jnp.where(jnp.arange(C)[None] < fill, jnp.arange(C)[None], -1
+                     ).astype(jnp.int32)
+    pos = jnp.asarray([700, 20, 1000, 1000], jnp.int32)
+    out = ops.decode_attention(q, k.reshape(N, C, -1), v.reshape(N, C, -1),
+                               kpos, pos, window, 0.015625, slots)
+    expect = ref.decode_attention_ref(q, k[slots], v[slots], kpos[slots],
+                                      pos, window, 0.015625)
+    np.testing.assert_allclose(out, expect, atol=1e-5)
+
+
 def test_decode_attention_masks_everything_empty():
     """All-empty cache: softmax denominator guard must not NaN."""
     B, H, kv, hd, C = 1, 2, 2, 64, 128

@@ -98,9 +98,10 @@ def test_decode_chain_totals_match_the_traffic():
     d_model, vocab, p = 16, 32, len(prompt)
     steps = m - 1
     # (input, output) bytes per position: tokens in, activations between
-    # the stages, logits out of the tail
-    io = [(4, d_model * 4), (d_model * 4, vocab * 4)]
-    for pn, (i_b, o_b), kv_b in zip(rep.per_node, io, kv):
+    # the stages, logits out of the tail, and the positions a prefill
+    # ships out: the whole prompt's activations, the last one's logits
+    io = [(4, d_model * 4, p), (d_model * 4, vocab * 4, 1)]
+    for pn, (i_b, o_b, out_p), kv_b in zip(rep.per_node, io, kv):
         t = pn["totals"]
         assert t["compute_s"] == t["prefill_s"] + t["apply_s"] + t["d2h_s"]
         assert (t["prefills"], t["prefill_n"]) == (1, 1)
@@ -108,7 +109,7 @@ def test_decode_chain_totals_match_the_traffic():
         assert t["rows"] == 1 + steps
         assert t["n"] == 1 + steps + 1          # the open, steps, a close
         assert t["h2d_bytes"] == p * i_b + steps * (i_b + 4)   # + position
-        assert t["d2h_bytes"] == p * o_b + steps * o_b
+        assert t["d2h_bytes"] == out_p * o_b + steps * o_b
         assert t["kv_bytes"] == 2 * steps * kv_b > 0
     assert rep.session["next_n"] == m + steps
 

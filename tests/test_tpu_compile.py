@@ -168,3 +168,103 @@ def test_decode_kernel_reads_the_wave_rows_in_the_slab(one_chip,
     assert ops == {"bitcast"}, ops
     rows = re.findall(r"f32\[8,(?:4096,2,128|2,4096,128|8192,128)\]", text)
     assert not rows, rows
+
+
+def test_ssm_step_compiles_and_aliases_the_slab(one_chip):
+    """Granite-4.0-H-Micro's Mamba2 (64 heads of 64, state 128): a 16-row
+    wave against a 17-row slab; the slab is the new state's buffer."""
+    from repro.kernels import ssm_step
+
+    R, B, H, P, N = 17, 16, 64, 64, 128
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+        ((R, H, P, N), jnp.float32), ((B,), jnp.int32), ((B, H, P),
+                                                         jnp.float32),
+        ((B, H), jnp.float32), ((H,), jnp.float32), ((B, N), jnp.float32),
+        ((B, N), jnp.float32))]
+    compiled = jax.jit(ssm_step.ssm_step, donate_argnums=0).lower(
+        *args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().alias_size_in_bytes >= R * H * P * N * 4
+
+
+def test_packed_decode_attention_reads_the_cache_in_its_layout(one_chip):
+    """8 KV heads of 64 in f32, packed ``[N, C, 512]``: the kernel reads
+    the 16384-position cache as it lies, with no copy of it."""
+    import re
+
+    R, C, B = 17, 16384, 16
+    fn = lambda q, k, v, kpos, pos, slots: \
+        decode_attention.decode_attention_packed(q, k, v, kpos, pos, None,
+                                                 0.015625, slots)
+    text = compile_text(fn, one_chip, ((B, 1, 32, 64), jnp.float32),
+                        ((R, C, 512), jnp.float32), ((R, C, 512), jnp.float32),
+                        ((R, C), jnp.int32), ((B,), jnp.int32),
+                        ((B,), jnp.int32))
+    assert "tpu_custom_call" in text
+    ops = set(re.findall(r"= \(?f32\[17,16384,512\]\{[^}]*\} ([a-z-]+)\(",
+                         text))
+    assert ops <= {"parameter"}, ops
+
+
+def _hybrid_step(one_chip, monkeypatch, rows=17, wave=16):
+    """Stage 1 of granite-4.0-h-micro's 2-stage chain (its attention layer,
+    four Mamba2 layers and the head) at published widths and a 16384-slot
+    cache: the decode step for a ``wave``-row wave against a ``rows``-row
+    slab, compiled, and the slab's shapes."""
+    import numpy as np
+
+    from bench import spec
+    from bench.models import hybrid_lm
+    from repro.kernels import ops
+    from repro.runtime.node import ComputeNode
+    from repro.runtime.wire import WireCodec
+
+    monkeypatch.setattr(ops, "interpret_mode", lambda: False)
+    cfg = spec.load_config(spec.load_benchmark(), "granite-4.0-h-micro")
+    g = hybrid_lm.build_graph(cfg)
+    node = ComputeNode(1, WireCodec("raw", "none"), max_batch=wave)
+    node._graph = g
+    node._set_range(11, len(g.nodes))
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
+                                                 sharding=one_chip)
+    node._params = {n.name: jax.tree_util.tree_map(
+        lambda s: sds(s.shape, s.dtype), n.param_spec) for n in node._nodes}
+    node._make_apply()
+    _, caches = jax.eval_shape(node._prefill_apply,
+                               jax.ShapeDtypeStruct((1, 8, 2048),
+                                                    jnp.float32))
+    slab = jax.tree_util.tree_map(
+        lambda c: sds((rows,) + c.shape[1:], c.dtype), caches)
+    step, idx = node._decode_apply, sds((wave,), jnp.int32)
+    compiled = step.func.lower(*step.args, slab, idx,
+                               sds((wave, 1, 2048), jnp.float32),
+                               idx).compile()
+    slab_bytes = sum(np.prod(a.shape) * a.dtype.itemsize
+                     for a in jax.tree_util.tree_leaves(slab))
+    return compiled, slab, slab_bytes
+
+
+def test_hybrid_decode_step_keeps_its_slab_in_place(one_chip, monkeypatch):
+    """The hybrid's step aliases its whole slab, KV cache and recurrent
+    state, from input to output; the two large leaves (KV ``[17, 16384,
+    512]``, state ``[17, 64, 64, 128]``) appear only as parameters, free
+    reshapes for the kernels and in-place writes of the wave's rows, and
+    no leaf is ever converted (copied) to another layout."""
+    import re
+
+    compiled, slab, slab_bytes = _hybrid_step(one_chip, monkeypatch)
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 5      # 1 attention, 4 ssm_step
+    assert compiled.memory_analysis().alias_size_in_bytes >= slab_bytes
+    shapes = {a.shape for a in jax.tree_util.tree_leaves(slab)}
+    assert (17, 16384, 512) in shapes and (17, 64, 64, 128) in shapes
+    for shape in shapes:
+        dims = ",".join(map(str, shape))
+        found = re.findall(r"= \(?[fs]32\[" + dims + r"\]\{([^}]*)\} "
+                           r"([a-z-]+)\(", text)
+        assert found and "copy" not in {op for _, op in found}, (shape, found)
+        hbm = {layout for layout, _ in found if "S(" not in layout}
+        assert len(hbm) == 1, (shape, hbm)
+        if shape in ((17, 16384, 512), (17, 64, 64, 128)):
+            assert {op for _, op in found} <= {
+                "parameter", "bitcast", "dynamic-update-slice"}, found
