@@ -51,7 +51,8 @@ from repro.runtime.node import _STOP, ComputeNode
 from repro.runtime.router import FenceTally, StageGroup
 from repro.runtime.spans import Spans
 from repro.runtime.topology import TopologySpec
-from repro.runtime.transport import Channel, ChannelClosed, get_transport
+from repro.runtime.transport import (Channel, ChannelClosed, InprocChannel,
+                                     get_transport)
 from repro.runtime.wire import (BatchEnvelope, NodePlan, ReconfigMarker,
                                 RowExtent, WireCodec, WireRecord, first_id,
                                 slice_parts, validate_client_id)
@@ -286,6 +287,7 @@ class Dispatcher:
                    else self.result_channel)
             for node in group.replicas:
                 node.next_inbox = nxt
+        self._wire_resident()
 
         self.config_records: list[WireRecord] = []
         self.admission = _WeightedAdmissionQueue(admission_depth)
@@ -361,6 +363,26 @@ class Dispatcher:
         ch = get_transport(transport).channel(capacity)
         self._channels.append(ch)
         return ch
+
+    def _wire_resident(self) -> None:
+        """Set each in-process replica's ``resident_out``: a non-tail stage
+        hands its outputs on the device where bytes never leave the
+        process — the next stage's input channel is in-process, every
+        replica listed for it (a joining one included, a draining one
+        until it is pruned) is a staged in-process :class:`ComputeNode`
+        with an in-process inbox, and the data codec is lossless.  A
+        lossy codec, a framing transport or a worker process keeps the
+        encoded path, byte for byte."""
+        for i, group in enumerate(self.stages):
+            nxt = self.stages[i + 1] if i + 1 < len(self.stages) else None
+            on = (self.codecs.data.lossless and nxt is not None
+                  and isinstance(self._stage_inputs[i + 1], InprocChannel)
+                  and all(isinstance(r, ComputeNode) and r.staged
+                          and isinstance(r.inbox, InprocChannel)
+                          for r in nxt.replicas))
+            for node in group.replicas:
+                if isinstance(node, ComputeNode):
+                    node.resident_out = on
 
     def _make_node(self, stage: int, replica: int) -> ComputeNode:
         """One replica of one stage, with the stage spec's overrides
@@ -1222,6 +1244,10 @@ class Dispatcher:
             group.replicas.extend(adds)     # stats/report see them at once
             for node in drops:
                 node.retiring = True
+            # before the fence: the upstream stage hands on resident only
+            # if every member, old or joining, takes it (work after the
+            # fence may reach a joiner); the newcomers wire their own hop
+            self._wire_resident()
 
             ev = threading.Event()
             self._reconfig_expect = epoch
@@ -1238,6 +1264,9 @@ class Dispatcher:
                 for node in drops:
                     node.join()
                 group.live_replicas()
+            # the drained are pruned once they exit, and only then may the
+            # upstream stage hand on resident again
+            self._wire_resident()
             self.topology = self.topology.with_replicas(stage, replicas)
             self.partition = partition(
                 self.graph, len(self.stages), link=self.link,

@@ -26,6 +26,15 @@ output ONCE — batch-level wire encoding with row-extent framing in the
 :class:`BatchEnvelope` — instead of one codec pass per request, so fixed
 codec cost amortizes across the batch and the next hop decodes once.
 
+Where bytes never leave the process — the next stage's channel and every
+replica behind it are in-process and the data codec is lossless — a
+non-tail stage skips the codec and the host altogether: it hands its
+outputs on as the ``jax.Array`` values its jitted call returned
+(:class:`ResidentRows`, in a resident :class:`BatchEnvelope`), and the next
+stage's call consumes them as device futures.  The dispatcher decides
+this at wiring time (``resident_out``); the spans ``d2h``, ``serialize``,
+``deserialize`` and ``h2d`` then time the hand-off alone.
+
 Failure isolation: an exception in any stage's decode/apply/encode is
 caught per batch; the affected requests' extents travel on as an ``error``
 envelope (formatted traceback) that downstream stages relay untouched, the
@@ -91,7 +100,9 @@ from repro.runtime.wire import (_RETIRE, _STOP, K_CLOSE,  # noqa: F401
 # over step waves (occupancy = kv_slots_sum / apply_n) and
 # ``kv_evictions`` the slots LRU reclaimed from a live session.  The
 # gauges ``slab_rows`` and ``slab_bytes`` are the slab the replica holds
-# (0 before its first open), the byte budget's outcome.
+# (0 before its first open), the byte budget's outcome.  ``encodes``
+# counts envelopes framed by the codec, ``resident`` those handed
+# downstream on the device instead.
 def stage_stats(index: int, lock: threading.Lock | None = None) -> Spans:
     return Spans(f"stage{index}",
                  ("deserialize", "h2d", "apply", "d2h", "prefill",
@@ -99,7 +110,7 @@ def stage_stats(index: int, lock: threading.Lock | None = None) -> Spans:
                  ("inbox", "compute", "egress"),
                  ("n", "waves", "rows", "prefills", "step_rows", "h2d_bytes",
                   "d2h_bytes", "kv_bytes", "state_bytes", "kv_slots_sum",
-                  "kv_evictions", "payload_bytes", "encodes",
+                  "kv_evictions", "payload_bytes", "encodes", "resident",
                   "busy_compute_s", "depth_sum", "depth_count"),
                  lock, gauges=("slab_rows", "slab_bytes"))
 
@@ -164,20 +175,55 @@ def stage_view(totals: dict) -> dict:
                                  else 0.0)}
 
 
+@dataclasses.dataclass(eq=False)
+class ResidentRows:
+    """A resident payload, held by reference across an in-process hop:
+    rows ``[start, start + rows)`` of one jitted call's outputs, still on
+    the device (padding rows included past the call's real rows; a
+    close's pass-through payload stays as it came).  A decode step's rows
+    also carry ``wide``, the same output padded to the producer's widest
+    wave, so rows of several waves gather in one op of one shape
+    (:func:`_gather_wide`)."""
+
+    out: dict[str, jax.Array]
+    start: int = 0
+    rows: int = 1
+    wide: jax.Array | None = None
+
+    def host(self) -> dict[str, np.ndarray]:
+        """The rows on the host: what the encoded path ships."""
+        end = self.start + self.rows
+        return {k: np.asarray(v)[self.start:end] for k, v in self.out.items()}
+
+
 @dataclasses.dataclass
 class _Decoded:
-    """Ingress -> compute: one inbound envelope, decoded once."""
+    """Ingress -> compute: one inbound envelope, decoded once.  A resident
+    envelope's ``boundary`` is its outputs on the device, of which ``src``
+    names the envelope's rows."""
 
     extents: list[RowExtent]
-    boundary: dict[str, np.ndarray]      # stacked over the envelope's extents
+    boundary: dict[str, Any]             # stacked over the envelope's extents
     t_enq: float = 0.0                   # put on the compute queue
+    src: ResidentRows | None = None
+
+    @property
+    def rows(self) -> int:
+        if self.src is not None:
+            return self.src.rows
+        return next(iter(self.boundary.values())).shape[0]
+
+    def host(self) -> dict[str, np.ndarray]:
+        return self.src.host() if self.src is not None else self.boundary
 
 
 @dataclasses.dataclass
 class _Computed:
-    """Compute -> egress: one merged batch's bucket outputs."""
+    """Compute -> egress: one merged batch's bucket outputs, on the host or
+    (a resident hop) on the device."""
 
-    buckets: list[tuple[list[RowExtent], dict[str, np.ndarray]]]
+    buckets: list[tuple[list[RowExtent],
+                        "dict[str, np.ndarray] | ResidentRows"]]
     n: int                               # requests in the merged batch
     t_enq: float = 0.0                   # put on the egress queue
 
@@ -190,6 +236,33 @@ def _bucket_rows(n: int) -> int:
     return p
 
 
+def _leading(tree: dict[str, Any]) -> int:
+    return next(iter(tree.values())).shape[0]
+
+
+def _middle_pads(shape: tuple) -> list[tuple[int, int]]:
+    """Zero padding of every middle axis up to the next power of two (none
+    for rank <= 2)."""
+    if len(shape) <= 2:
+        return [(0, 0)] * len(shape)
+    return ([(0, 0)] + [(0, _bucket_rows(s) - s) for s in shape[1:-1]]
+            + [(0, 0)])
+
+
+@jax.jit
+def _gather_wide(wides: tuple, idx) -> jax.Array:
+    """Rows ``idx`` of equally wide outputs laid end to end, in one device
+    op.  The caller passes a fixed number of them, so one program per
+    wave size serves every mix of upstream waves."""
+    return jax.numpy.take(jax.numpy.concatenate(wides, axis=0), idx, axis=0)
+
+
+@jax.jit
+def _pad_middles(tree: dict[str, jax.Array]) -> dict[str, jax.Array]:
+    return {k: jax.numpy.pad(v, _middle_pads(v.shape))
+            for k, v in tree.items()}
+
+
 def _signature(boundary: dict[str, np.ndarray]) -> tuple:
     """Bucket key: leaf names + trailing dims + dtypes.  Row counts are
     free to differ — ragged requests concatenate along axis 0."""
@@ -200,10 +273,7 @@ def _signature(boundary: dict[str, np.ndarray]) -> tuple:
 def _pad_middle(arr: np.ndarray) -> np.ndarray:
     """Zero-pad every middle axis up to the next power of two (no-op for
     rank <= 2 or already-pow2 sizes)."""
-    if arr.ndim <= 2:
-        return arr
-    pads = [(0, 0)] + [(0, _bucket_rows(s) - s) for s in arr.shape[1:-1]] \
-        + [(0, 0)]
+    pads = _middle_pads(arr.shape)
     if all(p == (0, 0) for p in pads):
         return arr
     return np.pad(arr, pads)
@@ -246,6 +316,12 @@ class ComputeNode:
         self.inbox: Channel = inbox if inbox is not None \
             else InprocChannel(queue_depth)
         self.next_inbox: Channel | None = None
+        # hand outputs downstream on the device (resident envelopes) rather
+        # than encoded: set by the dispatcher where the next stage's
+        # channel and every replica behind it are in-process and the data
+        # codec is lossless; read per wave, so a rewiring takes effect on
+        # the next one
+        self.resident_out = False
         self._egress_epoch = 0      # epoch stamp for outbound envelopes
         self._to_compute: queue.Queue = queue.Queue(maxsize=max(1, stage_depth))
         self._to_encode: queue.Queue = queue.Queue(maxsize=max(1, stage_depth))
@@ -444,6 +520,11 @@ class ComputeNode:
                                                                  0),
                 slab, caches)
 
+        # a non-tail step also returns its output padded to the widest
+        # wave, which the next stage gathers rows of several waves from
+        # when the hop is resident (ResidentRows.wide); the tail ships none
+        width = None if self._is_tail else _bucket_rows(self.max_batch_cap)
+
         def step_fn(params, slab, slots, x, pos):
             acts = x
             new = {}
@@ -455,7 +536,12 @@ class ComputeNode:
                     new[node.name] = rows.slab
                 else:
                     acts = node.fn(p, acts)
-            return acts, new
+            wide = None
+            if width is not None:
+                wide = jax.numpy.pad(
+                    acts, [(0, max(0, width - acts.shape[0]))]
+                    + [(0, 0)] * (acts.ndim - 1))
+            return acts, wide, new
 
         self._prefill_apply = functools.partial(jax.jit(prefill_fn), params)
         self._write_slot = jax.jit(write_fn, donate_argnums=0)
@@ -579,6 +665,13 @@ class ComputeNode:
             step.func.lower(*step.args, slab, rows,
                             sds((t, 1) + spec.shape[2:], spec.dtype),
                             rows).compile()
+            if self.index > 0 and self.data_codec.lossless:
+                # an in-process upstream may hand its rows on resident:
+                # the one gather a wave of them can need, per wave size
+                wide = sds((_bucket_rows(self.max_batch_cap), 1)
+                           + spec.shape[2:], spec.dtype)
+                _gather_wide.lower((wide,) * self.max_batch_cap,
+                                   rows).compile()
 
     # -- inference step (paper §III-C) ----------------------------------------
     def start(self) -> None:
@@ -742,6 +835,11 @@ class ComputeNode:
                     continue
                 with self.stats.span("deserialize", rid=first_id(env.extents),
                                      rows=env.n):
+                    if env.resident is not None:    # adopted as it is
+                        decoded.append(_Decoded(env.extents,
+                                                env.resident.out,
+                                                src=env.resident))
+                        continue
                     try:
                         flat, _ = self.data_codec.decode_tree(env.blob)
                         decoded.append(_Decoded(
@@ -839,44 +937,69 @@ class ComputeNode:
                 if v.ndim > 2}
         if len(mids) != 1:
             return d
-        padded = {k: _pad_middle(v) for k, v in d.boundary.items()}
-        if all(padded[k] is d.boundary[k] for k in padded):
-            return d
         orig_mid = next(iter(mids))
+        if all(s == _bucket_rows(s) for s in orig_mid):
+            return d
         extents = [e if e.pad_trim is not None
                    else dataclasses.replace(e, pad_trim=orig_mid)
                    for e in d.extents]
+        if d.src is not None:                   # on the device, one op
+            padded = _pad_middles(d.boundary)
+            return _Decoded(extents, padded, d.t_enq,
+                            dataclasses.replace(d.src, out=padded))
+        padded = {k: _pad_middle(v) for k, v in d.boundary.items()}
         return _Decoded(extents, padded, d.t_enq)
 
-    def _stack_apply(self, segments: list[dict[str, np.ndarray]],
-                     total: int, target: int,
-                     rid: int) -> dict[str, np.ndarray]:
-        """Concatenate per-leaf segments along axis 0, zero-pad to ``target``
-        rows, run the jitted partition apply once, trim back to ``total``.
-        Shared by the staged compute stage and the legacy per-request path.
-        ``rid`` (the first request's id) rides the spans' metadata."""
-        stats = self.stats
+    def _assemble(self, segs: list[_Decoded], total: int,
+                  target: int) -> tuple[dict[str, Any], int]:
+        """A bucket's ``target``-row input on the device, and the bytes
+        uploaded for it.  One upstream bucket, resident, takes no op: its
+        rows as computed, in order, padding rows riding along (every
+        layer is row-independent, so they touch no real row).  Encoded
+        segments, and resident ones of several upstream buckets, are
+        concatenated and zero-padded on the host and uploaded."""
+        if len(segs) == 1 and segs[0].src is not None:
+            src = segs[0].src
+            if src.start == 0 and _leading(src.out) == target:
+                return src.out, 0
+        parts = [d.host() for d in segs]
         stacked: dict[str, jax.Array] = {}
         up = 0
+        for key in parts[0]:
+            arrs = [p[key] for p in parts]
+            cat = np.concatenate(arrs, axis=0) if len(arrs) > 1 else arrs[0]
+            if target > total:
+                pad = np.zeros((target - total,) + cat.shape[1:], cat.dtype)
+                cat = np.concatenate([cat, pad], axis=0)
+            up += cat.nbytes
+            stacked[key] = jax.numpy.asarray(cat)
+        return stacked, up
+
+    def _stack_apply(self, segs: list[_Decoded], total: int, target: int,
+                     rid: int, keep: bool = False
+                     ) -> "dict[str, np.ndarray] | ResidentRows":
+        """Stack the segments' rows into ``target`` rows (:meth:`_assemble`),
+        run the jitted partition apply once, and pull the outputs to the
+        host trimmed back to ``total`` rows — or, with ``keep`` (a
+        resident hop), hand them on as they are.  Shared by the staged
+        compute stage and the legacy per-request path.  ``rid`` (the first
+        request's id) rides the spans' metadata."""
+        stats = self.stats
         with stats.span("h2d", rid=rid, rows=total):
-            for key in segments[0]:
-                arrs = [s[key] for s in segments]
-                cat = np.concatenate(arrs, axis=0) if len(arrs) > 1 else arrs[0]
-                if target > total:
-                    pad = np.zeros((target - total,) + cat.shape[1:],
-                                   cat.dtype)
-                    cat = np.concatenate([cat, pad], axis=0)
-                up += cat.nbytes
-                stacked[key] = jax.numpy.asarray(cat)
+            stacked, up = self._assemble(segs, total, target)
         # no span waits on the device: ``apply`` is the call, and ``d2h``'s
         # np.asarray waits for the upload, the computation and the copy out
         with stats.span("apply", rid=rid, rows=total):
             res = self._apply(stacked)
         with stats.span("d2h", rid=rid, rows=total):
-            res = {k: np.asarray(v) for k, v in res.items()}
-        stats.add(rows=total, h2d_bytes=up,
-                  d2h_bytes=sum(v.nbytes for v in res.values()))
-        return {k: v[:total] for k, v in res.items()}
+            if keep:
+                out, down = ResidentRows(res, 0, total), 0
+            else:
+                res = {k: np.asarray(v) for k, v in res.items()}
+                down = sum(v.nbytes for v in res.values())
+                out = {k: v[:total] for k, v in res.items()}
+        stats.add(rows=total, h2d_bytes=up, d2h_bytes=down)
+        return out
 
     def _compute_group(self, group: list[_Decoded]
                        ) -> tuple[_Computed | None, list[BatchEnvelope]]:
@@ -892,6 +1015,7 @@ class ComputeNode:
         of one bucket each; the original sizes ride the extents
         (``pad_trim``) and the tail collector trims them back out."""
         n = sum(len(d.extents) for d in group)
+        keep = self.resident_out and not self._is_tail
         # session frames (kind != K_PLAIN) take the decode path; plain
         # traffic keeps the stacked-apply path.  Both run inside the same
         # merged wave, so a chain can serve single-shot and decode traffic
@@ -901,10 +1025,10 @@ class ComputeNode:
         for d in group:
             (sess if any(e.kind != K_PLAIN for e in d.extents)
              else plain).append(d)
-        outs: list[tuple[list[RowExtent], dict[str, np.ndarray]]] = []
+        outs: list[tuple[list[RowExtent], Any]] = []
         failures: list[BatchEnvelope] = []
         if sess:
-            s_out, s_fail = self._decode_group(sess)
+            s_out, s_fail = self._decode_group(sess, keep)
             outs.extend(s_out)
             failures.extend(s_fail)
         if self.shape_buckets == "pow2" and self._pad_safe:
@@ -918,12 +1042,11 @@ class ComputeNode:
 
         for segs in buckets.values():
             extents = [e for d in segs for e in d.extents]
-            total = sum(next(iter(d.boundary.values())).shape[0]
-                        for d in segs)
+            total = sum(d.rows for d in segs)
             target = _bucket_rows(total) if self.pad_batches else total
             try:
-                res = self._stack_apply([d.boundary for d in segs], total,
-                                        target, first_id(extents))
+                res = self._stack_apply(segs, total, target,
+                                        first_id(extents), keep)
             except Exception:
                 failures.append(BatchEnvelope(extents, b"",
                                               error=traceback.format_exc()))
@@ -933,7 +1056,7 @@ class ComputeNode:
             return None, failures
         return _Computed(outs, n), failures
 
-    def _decode_group(self, group: list[_Decoded]
+    def _decode_group(self, group: list[_Decoded], keep: bool = False
                       ) -> tuple[list, list[BatchEnvelope]]:
         """Serve one merged wave's session traffic (kind != K_PLAIN).
 
@@ -963,13 +1086,16 @@ class ComputeNode:
         pin whole envelopes; a multi-session envelope could not route
         sticky), enforced here.
 
+        With ``keep`` (a resident hop) the outputs stay on the device: an
+        open's whole, each step row as a :class:`ResidentRows` of the wave's.
+
         Returns ``(outs, failures)``.
         """
         stats = self.stats
-        outs: list[tuple[list[RowExtent], dict[str, np.ndarray]]] = []
+        outs: list[tuple[list[RowExtent], Any]] = []
         failures: list[BatchEnvelope] = []
         out_name = self._exported[0] if self._exported else ""
-        steps: list[tuple[RowExtent, np.ndarray]] = []
+        steps: list[tuple[RowExtent, Any]] = []
         for d in group:
             if len(d.extents) != 1:
                 failures.append(BatchEnvelope(
@@ -980,7 +1106,7 @@ class ComputeNode:
             e = d.extents[0]
             if e.kind == K_CLOSE:
                 self.sessions.pop(e.session)
-                outs.append(([e], d.boundary))
+                outs.append(([e], d.src or d.boundary))
                 continue
             if self._prefill_apply is None:
                 failures.append(BatchEnvelope(
@@ -990,13 +1116,22 @@ class ComputeNode:
                           "LayerDecode nodes, or the slice is not a "
                           "single-boundary chain)"))
                 continue
-            x = next(iter(d.boundary.values()))
+            x = d.src if d.src is not None else next(iter(d.boundary.values()))
             if e.kind == K_OPEN:
-                with stats.span("prefill", rid=e.request_id,
-                                rows=x.shape[0]):
+                with stats.span("prefill", rid=e.request_id, rows=d.rows):
                     try:
-                        y, caches = self._prefill_apply(jax.numpy.asarray(x))
-                        y = np.asarray(y)
+                        if d.src is None:
+                            xin, up = jax.numpy.asarray(x), x.nbytes
+                        else:
+                            tree, up = self._assemble([d], d.rows, d.rows)
+                            xin = next(iter(tree.values()))
+                        y, caches = self._prefill_apply(xin)
+                        if keep:
+                            y, down = ResidentRows({out_name: y}, 0,
+                                                 y.shape[0]), 0
+                        else:
+                            y = np.asarray(y)
+                            down = y.nbytes
                     except Exception:
                         failures.append(BatchEnvelope(
                             [e], b"", error=traceback.format_exc()))
@@ -1011,8 +1146,11 @@ class ComputeNode:
                         failures.append(BatchEnvelope(
                             [e], b"", error=traceback.format_exc()))
                         continue
-                stats.add(prefills=1, rows=x.shape[0], h2d_bytes=x.nbytes,
-                          d2h_bytes=y.nbytes, kv_evictions=int(evicted))
+                stats.add(prefills=1, rows=d.rows, h2d_bytes=up,
+                          d2h_bytes=down, kv_evictions=int(evicted))
+                if keep:
+                    outs.append(([e], y))
+                    continue
                 if self._is_tail:
                     y = y[:, -1:]
                 outs.append(([e], {out_name: y}))
@@ -1026,7 +1164,7 @@ class ComputeNode:
             return outs, failures
         rid = steps[0][0].request_id
         with stats.span("kv_gather", rid=rid, rows=len(steps)):
-            live: list[tuple[RowExtent, np.ndarray, int]] = []
+            live: list[tuple[RowExtent, Any, int]] = []
             for e, x in steps:
                 slot = self.sessions.get(e.session)
                 if slot is None:
@@ -1050,15 +1188,22 @@ class ComputeNode:
         # rows are bit-identical to an unpadded apply
         rows = live + [live[-1]] * (target - b)
         with stats.span("h2d", rid=rid, rows=b):
-            xs = jax.numpy.asarray(
-                np.concatenate([x for _, x, _ in rows], axis=0))
+            xs, up = self._assemble_steps([x for _, x, _ in live], target)
             pos = jax.numpy.asarray(
                 np.asarray([e.pos for e, _, _ in rows], np.int32))
         try:
             with stats.span("apply", rid=rid, rows=b):
-                y, slab = self._decode_apply(self._slab, slots, xs, pos)
+                y, wide, slab = self._decode_apply(self._slab, slots, xs,
+                                                   pos)
             with stats.span("d2h", rid=rid, rows=b):    # as in _stack_apply
-                y = np.asarray(y)
+                if keep:
+                    tree = {out_name: y}
+                    ys = [ResidentRows(tree, i, 1, wide) for i in range(b)]
+                    down = 0
+                else:
+                    y = np.asarray(y)
+                    ys = [{out_name: y[i:i + 1]} for i in range(b)]
+                    down = y.nbytes
         except Exception:
             # the call may have consumed the donated slab: no step may run
             # on it again, so every session here re-prefills
@@ -1072,12 +1217,43 @@ class ComputeNode:
             resident = len(self.sessions)
         # device bytes: every row's slot, counted read and written
         kv_row, state_row = self._row_bytes
-        stats.add(rows=b, step_rows=b, h2d_bytes=xs.nbytes + pos.nbytes,
-                  d2h_bytes=y.nbytes, kv_bytes=2 * target * kv_row,
+        stats.add(rows=b, step_rows=b, h2d_bytes=up + pos.nbytes,
+                  d2h_bytes=down, kv_bytes=2 * target * kv_row,
                   state_bytes=2 * target * state_row, kv_slots_sum=resident)
-        for i, (e, _, _) in enumerate(live):
-            outs.append(([e], {out_name: y[i:i + 1]}))
+        outs.extend(([e], y) for (e, _, _), y in zip(live, ys))
         return outs, failures
+
+    def _assemble_steps(self, xs: list, target: int) -> tuple[Any, int]:
+        """A step wave's ``target``-row input on the device, and the bytes
+        uploaded for it; padding rows repeat the last row.  Resident rows
+        take no op where they are one upstream wave's rows as computed (in
+        order, its padding rows riding along), else ONE gather from their
+        waves' wide outputs (:func:`_gather_wide`, its arity fixed at
+        ``max_batch_cap`` so one program per wave size serves any mix).
+        Encoded rows, or resident rows without one width, go through the
+        host."""
+        b = len(xs)
+        if all(isinstance(x, ResidentRows) for x in xs):
+            first = xs[0]
+            y = next(iter(first.out.values()))
+            if y.shape[0] == target and all(
+                    x.out is first.out and x.start == i
+                    for i, x in enumerate(xs)):
+                return y, 0
+            wides = list({id(x.wide): x.wide for x in xs}.values())
+            if (all(x.wide is not None for x in xs)
+                    and len(wides) <= self.max_batch_cap
+                    and len({w.shape for w in wides}) == 1):
+                at = {id(w): j * w.shape[0] for j, w in enumerate(wides)}
+                idx = [at[id(x.wide)] + x.start for x in xs]
+                idx += [idx[-1]] * (target - b)
+                wides += [wides[0]] * (self.max_batch_cap - len(wides))
+                return _gather_wide(tuple(wides),
+                                    np.asarray(idx, np.int32)), 0
+        rows = [next(iter(x.host().values())) if isinstance(x, ResidentRows)
+                else x for x in xs]
+        cat = np.concatenate(rows + [rows[-1]] * (target - b), axis=0)
+        return jax.numpy.asarray(cat), cat.nbytes
 
     def _park(self, session: Any, caches: Any) -> bool:
         """Write a session's prefill caches into its slot, allocating the
@@ -1154,12 +1330,22 @@ class ComputeNode:
             # only codec time is encode busy (the serialize span); the relay
             # puts can block on the next node's bounded inbox (backpressure)
             self.stats.waited("egress", item.t_enq, item.n)
-            payload = encodes = 0
+            payload = encodes = resident = 0
             out_envs: list[BatchEnvelope] = []
             for extents, res in item.buckets:
                 with self.stats.span("serialize", rid=extents[0].request_id,
                                      rows=len(extents)):
+                    if self.resident_out:       # by reference, no bytes
+                        if not isinstance(res, ResidentRows):
+                            res = ResidentRows(res, 0, _leading(res))
+                        out_envs.append(BatchEnvelope(
+                            extents, b"", epoch=self._egress_epoch,
+                            resident=res))
+                        resident += 1
+                        continue
                     try:
+                        if isinstance(res, ResidentRows):  # rewired since
+                            res = res.host()
                         blob, rec = self.data_codec.encode_tree(
                             res, "data", request_id=extents[0].request_id,
                             client_id=extents[0].client_id)
@@ -1174,7 +1360,8 @@ class ComputeNode:
                 out_envs.append(env)
             with self._stats_lock:
                 self.stats.add_locked(n=item.n, waves=1,
-                                      payload_bytes=payload, encodes=encodes)
+                                      payload_bytes=payload, encodes=encodes,
+                                      resident=resident)
                 self._inflight_n -= sum(len(e.extents) for e in out_envs)
             for env in out_envs:
                 self._relay(env)
@@ -1285,8 +1472,9 @@ class ComputeNode:
             target = _bucket_rows(total) if self.pad_batches else total
             t0 = time.perf_counter()
             try:
-                outs = self._stack_apply([b for _, b in bucket], total,
-                                         target, bucket[0][0].request_id)
+                outs = self._stack_apply(
+                    [_Decoded([ext], b) for ext, b in bucket], total,
+                    target, bucket[0][0].request_id)
             except Exception:
                 tb = traceback.format_exc()
                 out_envs.extend(BatchEnvelope([ext], b"", error=tb)

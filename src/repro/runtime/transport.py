@@ -485,6 +485,9 @@ class TcpTransport(Transport):
             sock = socket.create_connection(self.address, timeout=10.0)
             sock.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
             sock.sendall(struct.pack("<I", cid))
+            # the credit loop blocks on this socket: a timeout left on it
+            # would read an idle channel as a dead peer
+            sock.settimeout(None)
             ch._open_send_side(sock)
             if not ch._attached.wait(10.0):
                 raise ChannelClosed("tcp accept timed out")

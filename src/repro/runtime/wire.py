@@ -192,6 +192,12 @@ class BatchEnvelope:
     # envelope that crossed a process boundary arrives without one.
     t_enq: float | None = dataclasses.field(default=None, compare=False,
                                             repr=False)
+    # the payload in resident form, in place of ``blob``: rows of a
+    # stage's outputs still on the device (``node.ResidentRows``), handed
+    # to an in-process next stage by reference.  Never framed: :func:`frame`
+    # refuses an envelope that holds one.
+    resident: Any = dataclasses.field(default=None, compare=False,
+                                      repr=False)
 
     @property
     def n(self) -> int:
@@ -334,6 +340,12 @@ class WireCodec:
     def label(self) -> str:
         comp = "LZ4" if self.compression == "lz4" else "Uncompressed"
         return f"{self.serializer.upper()}/{comp}"
+
+    @property
+    def lossless(self) -> bool:
+        """Decoding returns exactly the arrays encoded (the ``raw``
+        serializer; LZ4 compression is lossless too)."""
+        return self.serializer == "raw"
 
     def error_bound(self, absmax: float) -> float:
         """Worst-case absolute error for one encode/decode pass over values
@@ -660,6 +672,10 @@ def frame(item: Any, version: int = FRAME_VERSION) -> bytes:
     if item is _RETIRE:
         return head(_F_RETIRE)
     if isinstance(item, BatchEnvelope):
+        if item.resident is not None:
+            raise WireFormatError(
+                "a resident envelope holds device arrays, not bytes: only "
+                "an in-process hop may carry it (encode it to frame it)")
         err = (struct.pack("<I", _NONE_U32) if item.error is None
                else _pack_bytes(item.error.encode()))
         if version >= 3:

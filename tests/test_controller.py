@@ -196,13 +196,36 @@ def test_priority_submit_end_to_end():
 
 # -- bucketed pad-to-shape (heterogeneous trailing shapes) -------------------
 
-def test_pow2_buckets_merge_near_miss_shapes():
+def _hand_offs(snap: dict, transport: str) -> int:
+    """A replica's envelopes handed downstream, all of one kind: encoded
+    over tcp, on the device (resident) in process."""
+    on, off = (("resident", "encodes") if transport == "inproc"
+               else ("encodes", "resident"))
+    assert snap[off] == 0
+    return snap[on]
+
+
+def _framed_stage1(d, stage, replica):
+    """Stage 1's replica behind a tcp inbox: stage 0's hop frames, while
+    stage 0 itself is fed as in process (its waves form alike)."""
+    if stage != 1:
+        return None
+    node = d._make_node(stage, replica)
+    node.inbox = d._open_channel("tcp", 8)
+    return node
+
+
+@pytest.mark.parametrize("transport", ["inproc", "tcp"])
+def test_pow2_buckets_merge_near_miss_shapes(transport):
     """(1, 5, D) and (1, 7, D) pad to (1, 8, D), merge into ONE apply and
-    ONE encode, and come back trimmed to their original shapes with
+    ONE hand-off, and come back trimmed to their original shapes with
     per-request reference numerics."""
     g = mlp_graph(6, rank3=True)
     params = g.init(jax.random.PRNGKey(0))
-    eng = InferenceEngine(g, 2, RAW, max_batch=8, shape_buckets="pow2")
+    eng = InferenceEngine(g, TopologySpec.chain(g, 2), RAW, max_batch=8,
+                          shape_buckets="pow2",
+                          replica_factory=(_framed_stage1 if transport == "tcp"
+                                           else None))
     eng.configure(params)
     gate = threading.Event()
     node0 = eng.dispatcher.nodes[0]
@@ -223,14 +246,16 @@ def test_pow2_buckets_merge_near_miss_shapes():
         np.testing.assert_allclose(out, ref, atol=1e-5)
     snap = node0.snapshot()
     assert snap["n"] == 2 and snap["waves"] == 1
-    assert snap["encodes"] == 1                    # one bucket, one pass
+    assert _hand_offs(snap, transport) == 1        # one bucket, one pass
 
 
-def test_exact_buckets_keep_shapes_separate():
+@pytest.mark.parametrize("transport", ["inproc", "tcp"])
+def test_exact_buckets_keep_shapes_separate(transport):
     """Default mode: near-miss shapes stay in their own buckets."""
     g = mlp_graph(4, rank3=True)
     params = g.init(jax.random.PRNGKey(0))
-    eng = InferenceEngine(g, 2, RAW, max_batch=8)
+    eng = InferenceEngine(g, TopologySpec.chain(g, 2, transport=transport),
+                          RAW, max_batch=8)
     eng.configure(params)
     gate = threading.Event()
     node0 = eng.dispatcher.nodes[0]
@@ -243,7 +268,7 @@ def test_exact_buckets_keep_shapes_separate():
         f.result(timeout=60)
     eng.shutdown()
     snap = node0.snapshot()
-    assert snap["n"] == 2 and snap["encodes"] == snap["n"]
+    assert snap["n"] == 2 and _hand_offs(snap, transport) == snap["n"]
 
 
 # -- live repartition: zero loss, FIFO preserved -----------------------------

@@ -137,12 +137,16 @@ def test_decode_tcp_replicated_multi_session_bit_identical():
         eng.shutdown()
 
 
-def test_step_payload_is_10x_smaller_than_full_sequence_resend():
+@pytest.mark.parametrize("transport", ["tcp", "link"])
+def test_step_payload_is_10x_smaller_than_full_sequence_resend(transport):
     """THE decode contract: after prefill, each hop ships one token's
     activations — O(d_model) — not the growing sequence.  Measured on the
-    stage-0 replica's outbound wire bytes, against what resending the
-    full sequence through the same codec would cost per step."""
-    g, params, eng = build()
+    stage-0 replica's outbound wire bytes, where they really cross a
+    framing channel, against what resending the full sequence through the
+    same codec would cost per step."""
+    g0 = lm_graph()
+    g, params, eng = build(
+        topology=TopologySpec.chain(g0, 2, transport=transport), graph=g0)
     prompt, m = [1, 2, 3, 4, 5, 6, 7, 8], 30
     try:
         eng.start()
@@ -152,7 +156,9 @@ def test_step_payload_is_10x_smaller_than_full_sequence_resend():
         node.reset_stats()
         for _ in range(m - 1):
             next(gen)
-        per_step = node.snapshot()["payload_bytes"] / (m - 1)
+        snap = node.snapshot()
+        assert snap["resident"] == 0 and snap["encodes"] == m - 1
+        per_step = snap["payload_bytes"] / (m - 1)
         gen.close()
         # the full-sequence alternative: the final-prefix boundary
         # activations through the SAME codec

@@ -9,7 +9,7 @@ import numpy as np
 import pytest
 
 from repro.core.graph import LayerGraph
-from repro.runtime import AdmissionFull, InferenceEngine
+from repro.runtime import AdmissionFull, InferenceEngine, TopologySpec
 from repro.runtime.dispatcher import DispatcherCodecs
 from repro.runtime.wire import WireCodec
 
@@ -34,10 +34,11 @@ RAW = DispatcherCodecs(data=WireCodec("raw", "none"),
                        weights=WireCodec("raw", "none"))
 
 
-def make_engine(num_nodes=4, **kw):
+def make_engine(num_nodes=4, transport="inproc", **kw):
     g = mlp_graph()
     params = g.init(jax.random.PRNGKey(0))
-    eng = InferenceEngine(g, num_nodes, RAW, **kw)
+    eng = InferenceEngine(
+        g, TopologySpec.chain(g, num_nodes, transport=transport), RAW, **kw)
     eng.configure(params)
     return g, params, eng
 
@@ -150,14 +151,17 @@ def test_clean_shutdown_with_inflight_requests():
         eng.submit(sample(0))
 
 
-def test_continuous_batching_actually_batches():
+@pytest.mark.parametrize("transport", ["inproc", "tcp"])
+def test_continuous_batching_actually_batches(transport):
     """Stall the head node's compute stage, pile requests up, release: the
     next merge must compute >1 request in one apply (fewer waves than
     requests), and
-    the staged egress must encode the merged batch in fewer codec passes
-    than it has requests (batch-level wire encoding)."""
-    g, params, eng = make_engine(num_nodes=2, max_batch=8,
-                                 admission_depth=64, queue_depth=8)
+    the staged egress must hand the merged batch on in fewer envelopes
+    than it has requests (batch-level hand-off): codec passes over tcp,
+    resident envelopes in process."""
+    g, params, eng = make_engine(num_nodes=2, transport=transport,
+                                 max_batch=8, admission_depth=64,
+                                 queue_depth=8)
     gate = threading.Event()
     node0 = eng.dispatcher.nodes[0]
     orig_apply = node0._apply
@@ -175,8 +179,10 @@ def test_continuous_batching_actually_batches():
     snap = node0.snapshot()
     assert snap["n"] == 6
     assert snap["waves"] < snap["n"]          # some wave merged >1 request
-    assert snap["encodes"] == snap["waves"]   # one encode per bucket, not
-                                              # per request
+    on, off = (("resident", "encodes") if transport == "inproc"
+               else ("encodes", "resident"))
+    assert snap[on] == snap["waves"]          # one hand-off per bucket,
+    assert snap[off] == 0                     # not per request
     for i, out in enumerate(outs):
         ref = np.asarray(g.apply(params, jnp.asarray(sample(i))))
         np.testing.assert_allclose(out, ref, atol=1e-5)
@@ -255,9 +261,10 @@ def test_error_propagation_fails_future_keeps_node_alive():
 
 def test_error_propagation_codec_failure():
     """A decode failure mid-chain also fails the future instead of
-    stranding it (corrupt blob injected at the head node's outbox)."""
+    stranding it (corrupt blob injected at the head node's outbox).  The
+    hop frames (tcp), so the next node's codec runs on it."""
     from repro.runtime import NodeError
-    g, params, eng = make_engine(num_nodes=2, max_batch=1)
+    g, params, eng = make_engine(num_nodes=2, transport="tcp", max_batch=1)
     node1 = eng.dispatcher.nodes[1]
     state = {"boom": True}
 
@@ -329,9 +336,11 @@ def test_unstaged_mode_parity():
     for i, out in enumerate(outs):
         ref = np.asarray(g.apply(params, jnp.asarray(sample(i))))
         np.testing.assert_allclose(out, ref, atol=1e-5)
-    # per-request wire: one encode per request, not per bucket
+    # per-request wire: one encode per request, not per bucket; the
+    # legacy path never hands on resident, so every hop encodes
     snaps = [n.snapshot() for n in eng.dispatcher.nodes]
     assert all(s["encodes"] == s["n"] == 8 for s in snaps)
+    assert all(s["resident"] == 0 for s in snaps)
 
 
 # -- per-request deadlines (the reliability layer's reaper) -------------------

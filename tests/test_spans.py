@@ -41,13 +41,16 @@ def test_spans_keep_totals_and_build_names_once():
     assert s.snapshot() == s.zero
 
 
-def test_oneshot_chain_totals_match_the_traffic():
+@pytest.mark.parametrize("transport", ["inproc", "tcp"])
+def test_oneshot_chain_totals_match_the_traffic(transport):
     """Per replica of a 2-stage chain: compute_s is prefill + apply + d2h,
     and requests, rows and the bytes copied each way follow from the
     shapes sent (each request alone in its wave, padded to a power of
-    two rows)."""
+    two rows).  In process the inner hop stays on the device: stage 0
+    copies nothing out and stage 1 nothing in; over tcp both do."""
     g = mlp_graph(4)
-    eng = InferenceEngine(g, 2, RAW, max_batch=4)
+    eng = InferenceEngine(g, TopologySpec.chain(g, 2, transport=transport),
+                          RAW, max_batch=4)
     eng.configure(g.init(jax.random.PRNGKey(0)))
     rows = [1, 3, 2, 1]
     eng.start()
@@ -58,31 +61,45 @@ def test_oneshot_chain_totals_match_the_traffic():
     rep = eng.report()
     eng.shutdown()
     padded = sum(_pow2(r) for r in rows)
+    resident = transport == "inproc"
     for pn in rep.per_node:
         t = pn["totals"]
+        inner_out = resident and pn["stage"] == 0
+        inner_in = resident and pn["stage"] == 1
         assert t["compute_s"] == t["prefill_s"] + t["apply_s"] + t["d2h_s"]
         assert pn["compute_s"] == pytest.approx(t["compute_s"] / len(rows))
         assert t["prefill_n"] == 0 and t["apply_n"] == len(rows)
         assert (t["n"], t["waves"], t["rows"]) == (len(rows),) * 2 + (
             sum(rows),)
         assert t["prefills"] == t["step_rows"] == t["kv_bytes"] == 0
-        assert t["h2d_bytes"] == t["d2h_bytes"] == padded * D * 4
-        for h in ("inbox", "compute", "egress"):
-            assert t[f"wait_{h}_n"] == len(rows) and t[f"wait_{h}_s"] >= 0
+        assert t["h2d_bytes"] == (0 if inner_in else padded * D * 4)
+        assert t["d2h_bytes"] == (0 if inner_out else padded * D * 4)
+        assert (t["resident"], t["encodes"]) == (
+            (len(rows), 0) if inner_out else (0, len(rows)))
+        # an item that crossed a framing channel carries no enqueue
+        # stamp: its wait is missing, not zero
+        for h, framed in (("inbox", not resident), ("compute", False),
+                          ("egress", False)):
+            assert t[f"wait_{h}_n"] == (0 if framed else len(rows))
+            assert t[f"wait_{h}_s"] >= 0
     d = rep.dispatcher
     assert d["serialize_n"] == d["collect_n"] == len(rows)
     for h in ("admission", "route0", "route1", "result"):
-        assert d[f"wait_{h}_n"] == len(rows)
+        framed = not resident and h != "admission"
+        assert d[f"wait_{h}_n"] == (0 if framed else len(rows))
     assert rep.session["next_n"] == 0
 
 
-def test_decode_chain_totals_match_the_traffic():
+@pytest.mark.parametrize("transport", ["inproc", "tcp"])
+def test_decode_chain_totals_match_the_traffic(transport):
     """One session through a 2-stage decoder: one prefill per stage, a
     step row per further token, the session's slab slot gathered and
     written back once per step, bytes each way from the shapes, and the
-    client's ``next`` spans (an argmax per token, a submit per step)."""
+    client's ``next`` spans (an argmax per token, a submit per step).  In
+    process the activations between the stages stay on the device."""
     g = lm_graph()
-    eng = InferenceEngine(g, TopologySpec.chain(g, 2), RAW, max_batch=4)
+    eng = InferenceEngine(g, TopologySpec.chain(g, 2, transport=transport),
+                          RAW, max_batch=4)
     eng.configure(g.init(jax.random.PRNGKey(0)))
     prompt, m = [1, 5, 9, 2, 7], 6
     nodes = eng.dispatcher.nodes
@@ -101,6 +118,8 @@ def test_decode_chain_totals_match_the_traffic():
     # the stages, logits out of the tail, and the positions a prefill
     # ships out: the whole prompt's activations, the last one's logits
     io = [(4, d_model * 4, p), (d_model * 4, vocab * 4, 1)]
+    if transport == "inproc":       # the hop's activations: no copy
+        io = [(4, 0, p), (0, vocab * 4, 1)]
     for pn, (i_b, o_b, out_p), kv_b in zip(rep.per_node, io, kv):
         t = pn["totals"]
         assert t["compute_s"] == t["prefill_s"] + t["apply_s"] + t["d2h_s"]

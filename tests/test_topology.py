@@ -510,13 +510,24 @@ def _stalled_pair(eng, node, shapes):
     return futs
 
 
-def test_pad_unsafe_layer_falls_back_to_exact_buckets():
+def _hand_offs(snap: dict, transport: str) -> int:
+    """A replica's envelopes handed downstream, all of one kind: encoded
+    over tcp, on the device (resident) in process."""
+    on, off = (("resident", "encodes") if transport == "inproc"
+               else ("encodes", "resident"))
+    assert snap[off] == 0
+    return snap[on]
+
+
+@pytest.mark.parametrize("transport", ["inproc", "tcp"])
+def test_pad_unsafe_layer_falls_back_to_exact_buckets(transport):
     """A segment containing a pad-unsafe layer must NOT pow2-pad: the
-    near-miss shapes stay in separate buckets (two encodes), numerics are
-    exact, while a safe segment of the same graph still merges."""
+    near-miss shapes stay in separate buckets (two hand-offs), numerics
+    are exact, while a safe segment of the same graph still merges."""
     g = mlp_graph(6, rank3=True, unsafe={1})   # fc1 is stage 0's layer
     params = g.init(jax.random.PRNGKey(0))
-    eng = InferenceEngine(g, TopologySpec.chain(g, 2, cuts=(3,)), RAW,
+    eng = InferenceEngine(g, TopologySpec.chain(g, 2, cuts=(3,),
+                                                transport=transport), RAW,
                           max_batch=8, shape_buckets="pow2")
     eng.configure(params)
     node0 = eng.dispatcher.stages[0].replicas[0]
@@ -532,23 +543,26 @@ def test_pad_unsafe_layer_falls_back_to_exact_buckets():
         ref = np.asarray(g.apply(params, jnp.asarray(sample(
             40 + xs.index(shape), shape))))
         np.testing.assert_allclose(out, ref, atol=1e-5)
-    # unsafe segment: one codec pass PER REQUEST (no bucket merge)
+    # unsafe segment: one hand-off PER REQUEST (no bucket merge)
     # (the plug's wave, then the pair's)
     snap0 = node0.snapshot()
     assert snap0["n"] == 3 and snap0["waves"] == 2
-    assert snap0["encodes"] == snap0["n"]
+    assert _hand_offs(snap0, transport) == snap0["n"]
 
 
-def test_pad_safe_graph_still_merges():
+@pytest.mark.parametrize("transport", ["inproc", "tcp"])
+def test_pad_safe_graph_still_merges(transport):
     g = mlp_graph(6, rank3=True)
     params = g.init(jax.random.PRNGKey(0))
-    eng = InferenceEngine(g, 2, RAW, max_batch=8, shape_buckets="pow2")
+    eng = InferenceEngine(g, TopologySpec.chain(g, 2, transport=transport),
+                          RAW, max_batch=8, shape_buckets="pow2")
     eng.configure(params)
     node0 = eng.dispatcher.stages[0].replicas[0]
     futs = _stalled_pair(eng, node0, [(1, 5, D), (1, 7, D)])
     for f in futs:
         f.result(timeout=60)
     eng.shutdown()
-    # the plug's wave and encode, then the pair's in one bucket
+    # the plug's wave and hand-off, then the pair's in one bucket
     snap = node0.snapshot()
-    assert snap["n"] == 3 and snap["waves"] == 2 and snap["encodes"] == 2
+    assert snap["n"] == 3 and snap["waves"] == 2
+    assert _hand_offs(snap, transport) == 2

@@ -244,7 +244,8 @@ def test_decode_array_corrupt_blob(codec):
 def test_truncated_blob_fails_only_the_affected_batch():
     """Regression (ISSUE 5): a corrupt wire payload mid-chain — now
     reachable via a dropped socket — must fail exactly the affected batch
-    with NodeError and leave the chain serving."""
+    with NodeError and leave the chain serving.  The hop frames (tcp), so
+    the head's codec runs on it."""
     class TruncatingCodec:
         def __init__(self, inner):
             self._inner = inner
@@ -260,8 +261,8 @@ def test_truncated_blob_fails_only_the_affected_batch():
         def __getattr__(self, name):
             return getattr(self._inner, name)
 
-    g, params, eng = make_engine(TopologySpec.chain(mlp_graph(), 2),
-                                 max_batch=1)
+    g, params, eng = make_engine(
+        TopologySpec.chain(mlp_graph(), 2, transport="tcp"), max_batch=1)
     eng.start()
     node0 = eng.dispatcher.stages[0].replicas[0]
     node0.data_codec = TruncatingCodec(node0.data_codec)
